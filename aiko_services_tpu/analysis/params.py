@@ -122,8 +122,6 @@ PIPELINE_PARAMETERS: dict[str, ParamSpec] = {
     "blackbox_limit": ParamSpec(
         "black-box dump files kept (oldest pruned)",
         number=True, minimum=1),
-    "compile_cache_dir": ParamSpec(
-        "persistent XLA compile cache directory"),
     "fault_plan": ParamSpec(
         "chaos FaultPlan armed at startup (rules list / JSON)",
         kind="json"),
@@ -249,6 +247,11 @@ PIPELINE_PARAMETERS: dict[str, ParamSpec] = {
     "fleet_definition": ParamSpec(
         "definition path spawned peers load (absent = this "
         "pipeline's definition, controller/gateway stripped)"),
+    "fleet_devices": ParamSpec(
+        "where each spawned peer process runs: 'cpu', or a list with "
+        "one entry per peer ('cpu' or a TPU chip index); required "
+        "when fleet_max > 1 -- no child's device is chosen by default",
+        kind="json"),
     "canary_watch_ticks": ParamSpec(
         "controller ticks a swapped replica's SLO burn is watched "
         "before the next replica swaps", number=True, minimum=1),

@@ -171,6 +171,12 @@ def pipeline_create(definition_pathname, transport, name, stream_id,
     instance = create_pipeline(
         definition_pathname, name=name, runtime=runtime,
         preflight="strict" if strict_preflight else None)
+    # Which device this process was started with -- a supervised peer's
+    # log shows its launcher's assignment arrived (backend-free: the
+    # environment IS the assignment, see controller.device_env).
+    click.echo("devices: " + " ".join(
+        f"{key}={os.environ.get(key, '(unset)')}"
+        for key in ("JAX_PLATFORMS", "TPU_VISIBLE_CHIPS")))
     if fault_plan:
         instance.arm_faults(fault_plan)
     if hook_names:

@@ -31,6 +31,7 @@ Here serving is native to the framework:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import queue
 import threading
@@ -41,6 +42,7 @@ import jax
 from ..models import llama
 from ..models.batching import ContinuousBatcher, Request
 from ..models.checkpoint import maybe_restore as _restore
+from ..models.paged import is_paged
 from ..models.tokenizer import ByteTokenizer, load_tokenizer
 from ..pipeline import PipelineElement, StreamEvent
 from ..services import Actor
@@ -229,8 +231,9 @@ class LLM(PipelineElement):
 
     ASYNC by default: each frame parks and its request hops to the
     element's device WORKER THREAD, which owns the model and the shared
-    :class:`ContinuousBatcher` -- model build (minutes of jit compiles
-    for a 1B model through a congested link), admission, the decode
+    :class:`ContinuousBatcher` -- model build (weight init plus the
+    first jit compiles of a 1B model take tens of seconds from a cold
+    compile cache), admission, the decode
     loop, and the retire fetches all run OFF the event loop, so they
     never block other stages' frames (detect of frame k+1 proceeds
     while the LLM compiles or decodes).  Requests from many in-flight
@@ -238,6 +241,21 @@ class LLM(PipelineElement):
     batching across frames, not per-frame drains); completions post
     back through the engine's thread-safe continuation.  Set parameter
     ``synchronous: true`` for the blocking per-frame path.
+
+    PLACEMENT: a definition ``placement`` block on this element
+    (``{"mesh": {"tp": 2}}``, ``{"devices": 1}``) is where the model
+    LIVES -- parameters are put with ``llama.partition_specs``
+    (``quant.quantize_specs`` for an int8 tree), the KV cache with
+    ``llama.cache_specs``, and every leaf of both sits inside the
+    stage's submesh (``model_devices()``); unplaced, they sit on the
+    process's default device.  On a submesh of more than one chip the
+    Pallas kernels do not apply (jit refuses to partition a Mosaic
+    kernel): the decode and matmul probes resolve ``reference`` there,
+    and ``attention: flash`` is refused at model build rather than left
+    to fail inside the first prefill.  The placed configuration that
+    runs on a four-chip v5e host (``chip_smoke.py --placed``):
+    ``placement: {"mesh": {"tp": 2}}``, ``attention: dense``, the rest
+    of the serving parameters unchanged (device loop, paged KV, int8).
     """
 
     is_async = True
@@ -346,26 +364,59 @@ class LLM(PipelineElement):
             config = dataclasses.replace(
                 config,
                 decode_attention=kernel_to_attention[decode_kernel])
-        params = _restore(
-            llama.init_params(
-                jax.random.PRNGKey(int(settings.get("seed", 0))), config),
-            settings.get("checkpoint"))
+        plan = self._stage_plan()
+        if plan is not None and plan.mesh.size > 1 \
+                and config.attention == "flash":
+            # Found on the four-chip v5e host (PR 21): with operands on
+            # a two-chip mesh -- head-sharded OR replicated -- jit
+            # refuses the kernel ("Mosaic kernels cannot be
+            # automatically partitioned. Please wrap the call in a
+            # shard_map."), which would otherwise surface inside the
+            # first prefill and be replayed as a device loss.
+            raise ValueError(
+                f"attention=flash on a {plan.mesh.size}-chip placement "
+                f"{dict(plan.mesh.shape)}: Mosaic kernels cannot be "
+                f"automatically partitioned; use attention: dense with "
+                f"a multi-chip placement (or place the LLM on one "
+                f"chip)")
         quantize = settings.get("quantize", False)
         normalized = str(quantize).strip().lower()
-        if parse_bool(quantize) or normalized == "int8":
-            # Weight-only int8 (models/quant.py): halves decode's HBM
-            # stream; activations/cache stay bf16.
-            from ..models.quant import quantize_params
-            params = quantize_params(params)
-        elif normalized not in ("false", "0", "no", "off", "none", ""):
+        int8 = parse_bool(quantize) or normalized == "int8"
+        if not int8 and normalized not in ("false", "0", "no", "off",
+                                           "none", ""):
             # A typo must not silently serve bf16 at half the promised
             # decode rate.
             raise ValueError(
                 f"quantize={quantize!r}: use true/false or int8")
+        # Both callers (the worker, the blocking path) hold
+        # ``_device_scope``: the build's transients land on this
+        # element's own chips.
+        params = _restore(
+            llama.init_params(
+                jax.random.PRNGKey(int(settings.get("seed", 0))), config),
+            settings.get("checkpoint"))
+        specs = llama.partition_specs(config)
+        if int8:
+            # Weight-only int8 (models/quant.py): halves decode's
+            # HBM stream; activations/cache stay bf16.
+            from ..models.quant import quantize_params, quantize_specs
+            params = quantize_params(params)
+            specs = quantize_specs(specs)
+        cache_put = None
+        if plan is not None:
+            # The model lives on THIS stage's submesh, not on the
+            # process's default device (which may belong to another
+            # stage): weights by the Megatron layout, cache by
+            # llama.cache_specs, kept there by donation.
+            params = plan.put(params, specs)
+
+            def cache_put(cache):
+                return plan.put(cache, llama.cache_specs(
+                    config, paged=is_paged(cache)))
         # Requests beyond max_slots queue (sizing rationale: class
         # docstring).  The pipeline TransferLedger counts the one
-        # explicit host fetch each retired device-loop block pays; the
-        # chaos probe arms the ``decode_block`` injection point.
+        # explicit host fetch each retired device-loop block pays;
+        # the chaos probe arms the ``decode_block`` injection point.
         ledger = self._ledger()
         kv_pages = settings.get("kv_pages")
         self._batcher = ContinuousBatcher(
@@ -387,7 +438,40 @@ class LLM(PipelineElement):
             fetch=None if ledger is None
             else (lambda tree: ledger.fetch(tree, label="llm_block")),
             fault_probe=self._fault_probe,
-            on_block=self._note_block)
+            on_block=self._note_block,
+            cache_put=cache_put)
+
+    def _stage_plan(self):
+        """The MeshPlan of this element's placed stage (its definition
+        ``placement`` block), or None when unplaced / outside a
+        pipeline."""
+        placements = getattr(getattr(self, "pipeline", None),
+                             "stage_placement", None)
+        return None if placements is None \
+            else placements.plans.get(self.name)
+
+    def _device_scope(self):
+        """Default-device scope for everything this element allocates:
+        the first chip of its placed stage, so neither the model build's
+        transients nor the decode loop's small host uploads land on
+        another stage's chip.  A no-op when unplaced."""
+        plan = self._stage_plan()
+        if plan is None:
+            return contextlib.nullcontext()
+        return jax.default_device(plan.mesh.devices.flat[0])
+
+    def model_devices(self) -> dict:
+        """Where the built model lives: the device sets holding any
+        parameter leaf and any KV-cache leaf (``chip_smoke.py`` and the
+        config-4 tests assert both sit inside the stage's submesh)."""
+        def devices(tree):
+            found = set()
+            for leaf in jax.tree_util.tree_leaves(tree):
+                found |= set(leaf.sharding.device_set)
+            return found
+        batcher = self._batcher
+        return {"params": devices(batcher.params),
+                "cache": devices(batcher.cache)}
 
     def _note_block(self, phase: str, slots: int) -> None:
         """Flight-recorder tap (ISSUE 10): every decode-block dispatch/
@@ -700,7 +784,7 @@ class LLM(PipelineElement):
         they join the live device batch."""
         while True:
             item = work.get()
-            with self._device_lock:
+            with self._device_lock, self._device_scope():
                 try:
                     self._handle(item)
                     self._drain_work(work)
@@ -738,7 +822,7 @@ class LLM(PipelineElement):
         """Blocking path (``synchronous: true`` or direct invocation):
         drains the batcher inline, serialized against the async worker
         through the device lock."""
-        with self._device_lock:
+        with self._device_lock, self._device_scope():
             self._ensure_model()
             request, collected = self._make_request(
                 str(stream.stream_id), text, self._resolve_request_params())

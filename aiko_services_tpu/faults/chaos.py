@@ -62,6 +62,13 @@ _STAGE_MODULE = "aiko_services_tpu.elements.common"
 
 CHAOS_MODES = ("kill", "rolling", "controller")
 
+#: The device every chaos child (registrar, pipelines, pilot, the
+#: pilot's own peers) is TOLD: the walk's pipelines are synthetic
+#: ``StageWork`` stages checking process-level recovery, so they run on
+#: the CPU backend whatever the driver process holds -- said here and
+#: passed per child (``device_env``), not left to a default.
+CHAOS_CHILD_DEVICE = "cpu"
+
 
 def _definition(name: str, journal_dir: str, busy_ms: float) -> dict:
     def stage(stage_name, factor):
@@ -99,6 +106,7 @@ def _pilot_definition(name: str, journal_dir: str, busy_ms: float,
                        "cooldown_ms": cooldown_ms,
                        "action_budget": 8, "budget_window_s": 10,
                        "fence_s": 1.0, "fleet_max": fleet_max,
+                       "fleet_devices": CHAOS_CHILD_DEVICE,
                        "spawn_burn": 1.0}})
     return base
 
@@ -136,7 +144,7 @@ def run_chaos(pipelines: int = 2, frames: int = 12,
     when the fleet cannot come up (no compiler for the broker, ...)."""
     from ..gateway.client import GatewayClient
     from ..gateway.server import GatewayServer
-    from ..orchestration.controller import FleetSupervisor
+    from ..orchestration.controller import FleetSupervisor, device_env
     from ..runtime import init_process, reset_process
     from ..transport.broker import BrokerProcess
 
@@ -153,12 +161,13 @@ def run_chaos(pipelines: int = 2, frames: int = 12,
     result = {"ok": False, "mode": mode, "workdir": workdir}
     try:
         broker = BrokerProcess(port=0, export_env=True).start()
-        env = dict(os.environ)
-        env["JAX_PLATFORMS"] = "cpu"
+        env = {**os.environ, **device_env(CHAOS_CHILD_DEVICE)}
         env.setdefault(
             "XLA_FLAGS",
             "--xla_force_host_platform_device_count=8")
-        echo(f"broker :{broker.port}; journals in {journal_dir}")
+        result["child_device"] = CHAOS_CHILD_DEVICE
+        echo(f"broker :{broker.port}; journals in {journal_dir}; "
+             f"every child runs on {CHAOS_CHILD_DEVICE!r}")
 
         registrar_log = open(os.path.join(workdir, "registrar.log"),
                              "w")

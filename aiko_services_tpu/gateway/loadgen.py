@@ -25,6 +25,11 @@ from . import ws
 
 __all__ = ["run_loadgen", "LoadSpec"]
 
+#: A session's receiver gives up after this long without any message --
+#: and says so in the report's ``errors``.  Warm every compile shape
+#: before pointing the generator at a model-hosting pipeline.
+RECV_SILENCE_S = 30.0
+
 
 class LoadSpec:
     """One tenant's traffic: ``rate`` frames/s open-loop for
@@ -89,8 +94,19 @@ def _drive(host: str, port: int, spec: LoadSpec, bucket: dict,
     def receive():
         while True:
             try:
-                message = client.recv(timeout=30.0)
-            except (ws.WsClosed, OSError):
+                message = client.recv(timeout=RECV_SILENCE_S)
+            except (ws.WsClosed, OSError) as error:
+                # A receiver that gives up with results still owed is a
+                # failed run, not a short report (``socket.timeout`` is
+                # an OSError too: a cold compile of the first frame can
+                # outlast the silence window).
+                with lock:
+                    owed = outstanding["count"]
+                if owed > 0:
+                    errors.append(
+                        f"{spec.tenant}: receiver gave up with {owed} "
+                        f"frame(s) unanswered: "
+                        f"{type(error).__name__}: {error}")
                 return
             op = message.get("op")
             with lock:
@@ -128,7 +144,10 @@ def _drive(host: str, port: int, spec: LoadSpec, bucket: dict,
             errors.append(f"{spec.tenant}: send failed: {error}")
             break
     done.set()
-    receiver.join(timeout=60.0)
+    receiver.join(timeout=RECV_SILENCE_S + 30.0)
+    if receiver.is_alive():
+        errors.append(f"{spec.tenant}: receiver still waiting after "
+                      f"the send schedule ended")
     client.close()
 
 
@@ -147,6 +166,9 @@ def run_loadgen(host: str, port: int, specs: list) -> dict:
         thread.start()
     for thread in threads:
         thread.join(timeout=300.0)
+        if thread.is_alive():
+            errors.append(f"{thread.name}: still running after 300 s; "
+                          f"its counts are incomplete")
     wall_s = max(1e-9, time.monotonic() - started)
 
     def aggregate(group_of) -> dict:

@@ -22,17 +22,13 @@ import time
 from typing import Any
 
 import jax
-
-try:
-    import orbax.checkpoint as ocp
-    _HAVE_ORBAX = True
-except ImportError:                                # pragma: no cover
-    _HAVE_ORBAX = False
+import orbax.checkpoint as ocp
 
 from ..parallel.mesh import MeshPlan
 
 __all__ = ["Checkpointer", "save_pytree", "restore_pytree",
            "maybe_restore"]
+
 
 class Checkpointer:
     """Step-numbered checkpoints under a root directory.
@@ -44,8 +40,6 @@ class Checkpointer:
     """
 
     def __init__(self, directory: str | pathlib.Path, keep: int = 3):
-        if not _HAVE_ORBAX:
-            raise RuntimeError("orbax-checkpoint is not installed")
         self.directory = pathlib.Path(directory).absolute()
         self.directory.mkdir(parents=True, exist_ok=True)
         options = ocp.CheckpointManagerOptions(

@@ -103,8 +103,7 @@ def init_params(key: jax.Array, config: DetectorConfig) -> dict:
         # "Focus" stem (pack 2x2 -> 12 channels, stride 1) was
         # implemented and MEASURED SLOWER on v5e (3.52 vs 2.05 ms for
         # the batch-8 backbone): the input relayout costs more than the
-        # deeper contraction saves at these widths.  See BASELINE.md's
-        # YOLO-n breakdown.
+        # deeper contraction saves at these widths.
         "stage1": stage(w, w * 2),        # /4
         "stage2": stage(w * 2, w * 2),    # /8  -> P3
         "stage3": stage(w * 2, w * 4),    # /16 -> P4

@@ -13,6 +13,8 @@ import os
 
 __all__ = ["ByteTokenizer", "load_tokenizer"]
 
+_UNKNOWN = "\ufffd".encode("utf-8")
+
 
 class ByteTokenizer:
     """Byte-level: token = byte value; specials above 255."""
@@ -28,7 +30,18 @@ class ByteTokenizer:
         return ([self.BOS] + tokens) if add_bos else tokens
 
     def decode(self, tokens) -> str:
-        data = bytes(t for t in tokens if 0 <= int(t) < 256)
+        """Bytes decode; the specials vanish; an id PAST the specials
+        (a model whose vocabulary is wider than this tokenizer's -- a
+        llama3 config at 128,256 serving random weights) renders as
+        U+FFFD instead of vanishing too, so generated text is never
+        silently empty."""
+        data = bytearray()
+        for token in tokens:
+            token = int(token)
+            if 0 <= token < 256:
+                data.append(token)
+            elif token > self.EOS:
+                data += _UNKNOWN
         return data.decode("utf-8", errors="replace")
 
     @property

@@ -14,11 +14,12 @@ import jax
 from .layers import (rms_norm, rope_frequencies, apply_rope, swiglu,
                      repeat_kv, attention_prefill, attention_decode,
                      attention_decode_append)
+from .tiles import on_tpu
 
 __all__ = ["rms_norm", "rope_frequencies", "apply_rope", "swiglu",
            "repeat_kv", "attention_prefill", "attention_decode",
            "attention_decode_append", "decode_backend",
-           "matmul_backend", "topk", "DECODE_BACKENDS"]
+           "matmul_backend", "topk", "on_tpu", "DECODE_BACKENDS"]
 
 #: every value :func:`decode_backend` can return, in preference order.
 DECODE_BACKENDS = ("paged-kernel", "dense-flash", "reference")
@@ -38,36 +39,31 @@ def decode_backend(requested: str = "auto", *, paged: bool = False,
     (dense|flash|auto); ``distributed`` forces the reference path
     (pallas_call has no GSPMD partitioning rules -- the caller decides
     whether an explicit 'flash' request on a sharded cache is an
-    error); under ``auto`` the kernels engage once ``extent`` reaches
-    ``threshold`` and the structure fits (dense: block-alignable
-    extent; paged: sublane-aligned ``page_tokens``).  Pure and
-    jax-free-cheap, so in-jit callers can resolve on static structure.
+    error); under ``auto`` the kernels engage on the TPU backend
+    (:func:`on_tpu`) once ``extent`` reaches ``threshold`` and the
+    structure fits (dense: block-alignable extent; paged:
+    sublane-aligned ``page_tokens``).
     """
     if requested in ("dense", "reference") or distributed:
         return "reference"
-    if paged:
-        if requested == "flash":
-            return "paged-kernel"
-        if (extent or 0) >= threshold and page_tokens \
-                and page_tokens % 8 == 0:
-            return "paged-kernel"
-        return "reference"
     if requested == "flash":
-        return "dense-flash"
-    if (extent or 0) >= threshold and (extent or 0) % 128 == 0:
-        return "dense-flash"
-    return "reference"
+        return "paged-kernel" if paged else "dense-flash"
+    if (extent or 0) < threshold or not on_tpu():
+        return "reference"
+    if paged:
+        return "paged-kernel" \
+            if page_tokens and page_tokens % 8 == 0 else "reference"
+    return "dense-flash" if (extent or 0) % 128 == 0 else "reference"
 
 
 def matmul_backend(requested: str = "auto") -> str:
     """Capability probe for the fused int8 dequant-matmul
     (ops/pallas_matmul.py): ``pallas-int8`` or ``reference`` (the
     cast-into-the-dot XLA path).  ``auto`` engages the kernel on TPU
-    backends only -- interpret mode would trade a fused HLO pair for an
-    emulated grid loop."""
+    backends only (:func:`on_tpu`)."""
     if requested == "pallas":
         return "pallas-int8"
-    if requested == "auto" and jax.default_backend() == "tpu":
+    if requested == "auto" and on_tpu():
         return "pallas-int8"
     return "reference"
 
@@ -76,11 +72,11 @@ def topk(x, k: int, *, kernel: bool | None = None):
     """Top-k over the last axis: ``(values, indices)`` with
     ``jax.lax.top_k``'s ordering contract (descending values, ties to
     the lowest index).  ``kernel=None`` resolves to the Pallas kernel
-    (ops/pallas_topk.py) on TPU and ``lax.top_k`` elsewhere; pass
-    True/False to force (the equivalence tests force True under
-    interpret mode)."""
+    (ops/pallas_topk.py) on TPU and ``lax.top_k`` elsewhere
+    (:func:`on_tpu`); pass True/False to ask by name (the equivalence
+    tests ask for True off the chip, which runs interpret mode)."""
     if kernel is None:
-        kernel = jax.default_backend() == "tpu"
+        kernel = on_tpu()
     if kernel:
         from .pallas_topk import topk as pallas_topk
         return pallas_topk(x, int(k))

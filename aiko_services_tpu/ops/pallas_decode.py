@@ -51,8 +51,12 @@ cache is read once per verify, not once per drafted token).  Backend
 choice lives in ``aiko_services_tpu.ops.decode_backend`` (capability
 probe, not try/except).
 
-On non-TPU backends the kernel runs in interpret mode, so tests exercise
-the identical code path on the CPU mesh (SURVEY.md section 4 strategy).
+Off the TPU the kernels run in interpret mode when asked for by name
+(``decode_attention: flash``), so tier-1 checks the kernel bodies on
+the CPU mesh; ``auto`` never routes here off the chip
+(``ops.on_tpu``), and Mosaic itself is checked on the chip by
+``chip_smoke.py`` (``interpret=False``; all of flat, stacked, paged at
+page sizes 8..256 and the verify chunk compile on v5e, bf16 and int8).
 """
 
 from __future__ import annotations
@@ -64,12 +68,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:                               # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
-from .tiles import pad_to as _pad_to, round_up as _round_up
+from .tiles import (interpret_off_chip, pad_to as _pad_to,
+                    round_up as _round_up)
 
 __all__ = ["flash_decode_attention", "flash_decode_append",
            "flash_decode_attention_stacked", "flash_decode_append_stacked",
@@ -295,8 +297,7 @@ def flash_decode_attention(q_pad, k_flat, v_flat, k_scale_t, v_scale_t,
     running max, l [B, H] f32 denominator) -- merge the current token's
     self term with :func:`flash_decode_append`'s combine step.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_off_chip(interpret)
     quantized = k_scale_t is not None
     b, h, c = q_pad.shape
     t = k_flat.shape[1]
@@ -405,8 +406,7 @@ def flash_decode_attention_stacked(q_pad, k_flat, v_flat, k_scale_t,
     -- padding a stacked cache would copy it).  ``qrow_period``: see
     :func:`flash_verify_append` (the [S*H]-row multi-query layout).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_off_chip(interpret)
     quantized = k_scale_t is not None
     b, h, c = q_pad.shape
     t = k_flat.shape[2]
@@ -510,8 +510,7 @@ def flash_decode_attention_paged(q_pad, k_pool, v_pool, k_scale_t,
     a short slot reads only its own extent.  Returns the same partial
     (acc, m, l) stats as the flat kernel.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_off_chip(interpret)
     quantized = k_scale_t is not None
     b, h, c = q_pad.shape
     page_tokens = k_pool.shape[2]

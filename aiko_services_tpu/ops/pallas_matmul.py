@@ -16,9 +16,15 @@ unembed projection (``models/llama.py:_finish`` -- the single largest
 serving matmul, and scan-invariant, so no per-layer slice materializes
 in front of the pallas call) dispatches here for quantized trees,
 which also covers the int8 self-draft decode steps of speculative
-serving.  On non-TPU backends the kernel runs in interpret mode for
-the equivalence tests; ``matmul_backend("auto")`` keeps XLA's fused
-path there.
+serving.  Off the TPU the kernel runs in interpret mode for the
+equivalence tests (asked for by name); ``matmul_backend("auto")`` keeps
+XLA's fused path there.
+
+Known cost, left for ROADMAP S2: llama3's vocabulary (128,256) is not a
+multiple of ``block_f`` (512), so the ``[2048, 128256]`` int8 unembed
+and its scales are padded INSIDE the jitted call -- a full weight copy
+on every step unless XLA hoists it.  It compiles and matches on v5e
+(``chip_smoke.py``); it has not been measured.
 """
 
 from __future__ import annotations
@@ -29,12 +35,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:                               # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
-from .tiles import pad_to as _pad_to, round_up as _round_up
+from .tiles import (interpret_off_chip, pad_to as _pad_to,
+                    round_up as _round_up)
 
 __all__ = ["int8_matmul"]
 
@@ -91,8 +95,7 @@ def int8_matmul(x, w_int8, scale, *, block_m: int = 256,
     tolerance (exactly, for exactly-representable inputs -- the
     equivalence test pins both).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_off_chip(interpret)
     m, d = x.shape
     d2, f = w_int8.shape
     if d2 != d:

@@ -8,20 +8,29 @@ ANN search over an HBM-resident index wants exactly the same primitive
 over similarity scores): one streaming pass, O(V * k) VPU work, nothing
 but the [B, k] result leaving the chip.
 
-Shape of the kernel: the grid is (B/8 row groups, V blocks).  Each
-step loads one [8, block_v] tile, extracts ITS top-k by k masked
-max-passes, and folds them into a running [8, k] (value, index) state
-in VMEM scratch -- one insertion per candidate against the current
-weakest entry, ordered lexicographically by (value desc, index asc) so
-ties resolve to the LOWEST index, matching ``lax.top_k``'s stable
-contract (the equivalence test pins both, ties included).  The last
-block sorts the k survivors and writes them out.  k is a static trace
-constant <= 128 (one lane tile); sampling uses k in the single digits.
+Shape of the kernel: the grid is (row groups, V blocks).  Each step
+loads one [rows, block_v] tile, extracts ITS top-k by k masked
+max-passes, and folds them into a running [rows, 128] (value, index)
+state in VMEM scratch -- one insertion per candidate against the
+current weakest entry, ordered lexicographically by (value desc, index
+asc) so ties resolve to the LOWEST index, matching ``lax.top_k``'s
+stable contract (the equivalence test pins both, ties included).  The
+last block sorts the k survivors and writes them out.  k is a static
+trace constant <= 128 (one lane tile); sampling uses k in the single
+digits.
 
-On non-TPU backends the kernel runs in interpret mode, so the
-equivalence tests exercise the identical code path on the CPU mesh;
-the dispatching interface (``aiko_services_tpu.ops.topk``) keeps
-``lax.top_k`` there and reserves the kernel for TPU.
+Written for Mosaic, not for the interpreter (ISSUE 21): the state is
+always a whole [rows, 128] lane tile with the first k lanes live under
+an iota mask -- nothing is sliced or concatenated at a lane width that
+is not a multiple of 128 -- the k passes are ``fori_loop``s, a row
+group is one native tile of the operand's dtype (8 rows of f32, 16 of
+bf16), and column indices ride as float32 (exact below 2**24, far
+past any vocabulary) so every lane reduction is a float reduction.
+
+Off the TPU the kernel runs in interpret mode when asked for by name
+(the equivalence tests); the dispatching interface
+(``aiko_services_tpu.ops.topk``) keeps ``lax.top_k`` there and
+reserves the kernel for TPU.
 """
 
 from __future__ import annotations
@@ -32,12 +41,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:                               # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
-from .tiles import pad_to as _pad_to, round_up as _round_up
+from .tiles import (interpret_off_chip, pad_to as _pad_to,
+                    round_up as _round_up)
 
 __all__ = ["topk"]
 
@@ -48,9 +55,12 @@ KERNEL_EQUIVALENCE_TESTS = {
 }
 
 _NEG_INF = float("-inf")
-_BIG = 2 ** 30
-_ROWS = 8          # batch rows per grid step (one f32 sublane tile)
+_POS_INF = float("inf")
 _LANES = 128       # scratch lane width (k <= _LANES)
+# Index sentinel, as float32: above every real column (V < 2**24 is
+# checked), and 2**24 + 2 * lane stays exactly representable, so the
+# running state's k sentinels are DISTINCT.
+_BIG = float(2 ** 24)
 
 
 def _extract_max(s, col):
@@ -69,25 +79,27 @@ def _extract_max(s, col):
     return m, idx, jnp.where(at, _NEG_INF, s), jnp.where(at, _BIG, col)
 
 
-def _insert(vals, idx, cand_v, cand_i, k: int):
+def _insert(vals, idx, live, cand_v, cand_i):
     """Replace the weakest of the k live entries when the candidate
-    ranks higher under (value desc, index asc)."""
-    weak_v = jnp.min(vals[:, :k], axis=1, keepdims=True)
-    weak_hit = vals[:, :k] == weak_v
-    weak_i = jnp.max(jnp.where(weak_hit, idx[:, :k], -1), axis=1,
+    ranks higher under (value desc, index asc).  ``live`` masks the
+    first k lanes of the [R, 128] state."""
+    weak_v = jnp.min(jnp.where(live, vals, _POS_INF), axis=1,
+                     keepdims=True)
+    weak_hit = live & (vals == weak_v)
+    weak_i = jnp.max(jnp.where(weak_hit, idx, -1.0), axis=1,
                      keepdims=True)
     better = (cand_v > weak_v) | ((cand_v == weak_v) & (cand_i < weak_i))
-    at = weak_hit & (idx[:, :k] == weak_i) & better
-    new_v = jnp.where(at, cand_v, vals[:, :k])
-    new_i = jnp.where(at, cand_i, idx[:, :k])
-    return (jnp.concatenate([new_v, vals[:, k:]], axis=1),
-            jnp.concatenate([new_i, idx[:, k:]], axis=1))
+    at = weak_hit & (idx == weak_i) & better
+    return jnp.where(at, cand_v, vals), jnp.where(at, cand_i, idx)
 
 
 def _topk_kernel(x_ref, ov_ref, oi_ref, vals_scr, idx_scr, *,
-                 k: int, block_v: int, v_len: int, out_dtype):
+                 k: int, rows: int, block_v: int, v_len: int,
+                 out_dtype):
     vi = pl.program_id(1)
     nv = pl.num_programs(1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1)
+    live = lane < k
 
     @pl.when(vi == 0)
     def _init():
@@ -97,48 +109,53 @@ def _topk_kernel(x_ref, ov_ref, oi_ref, vals_scr, idx_scr, *,
         # _insert matches several slots at once and the state
         # degenerates to k copies of one entry.  Real candidates carry
         # column indices < _BIG, so sentinels always lose ties.
-        idx_scr[...] = _BIG + jax.lax.broadcasted_iota(
-            jnp.int32, idx_scr.shape, 1)
+        idx_scr[...] = _BIG + 2.0 * lane.astype(jnp.float32)
 
-    col = vi * block_v + jax.lax.broadcasted_iota(
-        jnp.int32, (_ROWS, block_v), 1)
-    s = jnp.where(col < v_len, x_ref[...].astype(jnp.float32), _NEG_INF)
+    col_i = vi * block_v + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, block_v), 1)
+    s = jnp.where(col_i < v_len, x_ref[...].astype(jnp.float32),
+                  _NEG_INF)
 
-    vals = vals_scr[...]
-    idx = idx_scr[...]
     # k masked max-passes pull the block's own top-k in order; each
     # candidate then displaces the running state's weakest entry (or
-    # nothing).  Everything is [8, <=128] VPU work on VMEM-resident
-    # tiles -- the HBM traffic is the single streaming read of x.
-    for _ in range(k):
+    # nothing).  Everything is [rows, <= block_v] VPU work on
+    # VMEM-resident tiles -- the HBM traffic is the single streaming
+    # read of x.
+    def fold(_, carry):
+        s, col, vals, idx = carry
         cand_v, cand_i, s, col = _extract_max(s, col)
-        vals, idx = _insert(vals, idx, cand_v, cand_i, k)
+        vals, idx = _insert(vals, idx, live, cand_v, cand_i)
+        return s, col, vals, idx
+
+    _, _, vals, idx = jax.lax.fori_loop(
+        0, k, fold, (s, col_i.astype(jnp.float32), vals_scr[...],
+                     idx_scr[...]))
     vals_scr[...] = vals
     idx_scr[...] = idx
 
     @pl.when(vi == nv - 1)
     def _finalize():
-        vals = vals_scr[...][:, :k]
-        idx = idx_scr[...][:, :k]
-        out_v, out_i = [], []
-        for _ in range(k):
-            m = jnp.max(vals, axis=1, keepdims=True)
-            hit = vals == m
+        def emit(j, carry):
+            vals, idx, out_v, out_i = carry
+            m = jnp.max(jnp.where(live, vals, _NEG_INF), axis=1,
+                        keepdims=True)
+            hit = live & (vals == m)
             pick = jnp.min(jnp.where(hit, idx, _BIG), axis=1,
                            keepdims=True)
-            out_v.append(m)
-            out_i.append(pick)
             # Consume BOTH value and index (the _extract_max rule):
             # value-only masking leaves an already--inf entry's index
             # live and the next pass re-picks it.
             consumed = hit & (idx == pick)
-            vals = jnp.where(consumed, _NEG_INF, vals)
-            idx = jnp.where(consumed, _BIG, idx)
-        pad = jnp.zeros((_ROWS, _LANES - k), dtype=jnp.float32)
-        ov_ref[...] = jnp.concatenate(out_v + [pad], axis=1) \
-            .astype(out_dtype)
-        oi_ref[...] = jnp.concatenate(
-            out_i + [pad.astype(jnp.int32)], axis=1)
+            return (jnp.where(consumed, _NEG_INF, vals),
+                    jnp.where(consumed, _BIG, idx),
+                    jnp.where(lane == j, m, out_v),
+                    jnp.where(lane == j, pick, out_i))
+
+        zeros = jnp.zeros((rows, _LANES), dtype=jnp.float32)
+        _, _, out_v, out_i = jax.lax.fori_loop(
+            0, k, emit, (vals_scr[...], idx_scr[...], zeros, zeros))
+        ov_ref[...] = out_v.astype(out_dtype)
+        oi_ref[...] = out_i.astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_v",
@@ -148,36 +165,42 @@ def topk(x, k: int, *, block_v: int = 2048,
     """Top-k over the last axis of ``x`` [B, V] -> (values [B, k],
     indices [B, k] int32), descending, ties to the lowest index --
     ``lax.top_k``'s ordering contract, without the full sort."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_off_chip(interpret)
     b, v = x.shape
     if not 0 < k <= min(v, _LANES):
         raise ValueError(
             f"topk: k={k} must be in [1, min(V={v}, {_LANES})]")
-    b_pad = _round_up(max(b, _ROWS), _ROWS)
+    if v >= _BIG:
+        raise ValueError(f"topk: V={v} must be below 2**24 (column "
+                         f"indices ride as float32)")
+    # One native sublane tile of the operand's dtype per grid step:
+    # 8 rows of a 4-byte dtype, 16 of bf16, 32 of int8.
+    rows = 8 * max(1, 4 // x.dtype.itemsize)
+    b_pad = _round_up(max(b, rows), rows)
     block_v = min(block_v, _round_up(max(v, _LANES), _LANES))
     x_p = _pad_to(_pad_to(x, 0, b_pad), 1, block_v)
     v_pad = x_p.shape[1]
 
-    kernel = functools.partial(_topk_kernel, k=k, block_v=block_v,
-                               v_len=v, out_dtype=x.dtype)
+    kernel = functools.partial(_topk_kernel, k=k, rows=rows,
+                               block_v=block_v, v_len=v,
+                               out_dtype=x.dtype)
     values, indices = pl.pallas_call(
         kernel,
-        grid=(b_pad // _ROWS, v_pad // block_v),
+        grid=(b_pad // rows, v_pad // block_v),
         in_specs=[
-            pl.BlockSpec((_ROWS, block_v), lambda bi, vi: (bi, vi)),
+            pl.BlockSpec((rows, block_v), lambda bi, vi: (bi, vi)),
         ],
         out_specs=[
-            pl.BlockSpec((_ROWS, _LANES), lambda bi, vi: (bi, 0)),
-            pl.BlockSpec((_ROWS, _LANES), lambda bi, vi: (bi, 0)),
+            pl.BlockSpec((rows, _LANES), lambda bi, vi: (bi, 0)),
+            pl.BlockSpec((rows, _LANES), lambda bi, vi: (bi, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b_pad, _LANES), x.dtype),
             jax.ShapeDtypeStruct((b_pad, _LANES), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((_ROWS, _LANES), jnp.float32),
-            pltpu.VMEM((_ROWS, _LANES), jnp.int32),
+            pltpu.VMEM((rows, _LANES), jnp.float32),
+            pltpu.VMEM((rows, _LANES), jnp.float32),
         ],
         interpret=interpret,
     )(x_p)

@@ -63,8 +63,9 @@ from ..utils import get_logger
 from .process_manager import ProcessManager
 
 __all__ = ["FleetController", "FleetSupervisor", "ControllerSpec",
-           "controller_spec_error", "CONTROLLER_MODES",
-           "peer_definition"]
+           "controller_spec_error", "fleet_devices_error",
+           "CONTROLLER_MODES", "peer_definition", "device_env",
+           "default_spawner"]
 
 _logger = get_logger("aiko.controller")
 
@@ -103,6 +104,7 @@ _SPEC_FIELDS = {
     "fleet_min": (1.0, None),
     "fleet_max": (1.0, None),
     "fleet_definition": None,
+    "fleet_devices": None,
     "canary_watch_ticks": (1.0, None),
     "canary_burn_ratio": (1.0, None),
     "spawn_burn": (0.0, None),
@@ -157,7 +159,28 @@ def controller_spec_error(value) -> str | None:
     fleet_max = float(value.get("fleet_max", fleet_min))
     if fleet_max < fleet_min:
         return f"fleet_max={fleet_max:g} < fleet_min={fleet_min:g}"
-    return None
+    return fleet_devices_error(value.get("fleet_devices"))
+
+
+def fleet_devices_error(value) -> str | None:
+    """Why a ``fleet_devices`` assignment is malformed, or None.  The
+    assignment says where each spawned peer process runs: the string
+    ``cpu`` (every peer on the CPU backend) or a list with one entry
+    per peer, each ``cpu`` or a TPU chip index."""
+    if value is None or value == "cpu":
+        return None
+    if isinstance(value, (list, tuple)) and value and all(
+            entry == "cpu" or (isinstance(entry, int)
+                               and not isinstance(entry, bool)
+                               and entry >= 0)
+            for entry in value):
+        chips = [entry for entry in value if entry != "cpu"]
+        if len(chips) == len(set(chips)):
+            return None
+        return f"fleet_devices={value!r}: a chip belongs to one " \
+               f"process at a time"
+    return f"fleet_devices={value!r}: 'cpu', or a list with one " \
+           f"entry per peer ('cpu' or a chip index)"
 
 
 class ControllerSpec:
@@ -181,6 +204,9 @@ class ControllerSpec:
         self.fleet_min = 1
         self.fleet_max = 1
         self.fleet_definition = ""
+        # Where each spawned peer runs (see fleet_devices_error); None =
+        # not said, and then no peer is spawned (default_spawner).
+        self.fleet_devices = None
         self.canary_watch_ticks = CANARY_WATCH_TICKS_DEFAULT
         self.canary_burn_ratio = CANARY_BURN_RATIO_DEFAULT
         self.spawn_burn = FLEET_SPAWN_BURN_DEFAULT
@@ -204,6 +230,13 @@ class ControllerSpec:
             self.mode = mode
         elif key == "fleet_definition":
             self.fleet_definition = str(value or "")
+        elif key == "fleet_devices":
+            if isinstance(value, str) and value.strip().startswith("["):
+                value = json.loads(value)       # flat/CLI spelling
+            problem = fleet_devices_error(value)
+            if problem is not None:
+                raise ValueError(f"controller: {problem}")
+            self.fleet_devices = value
         else:
             try:
                 number = float(value)
@@ -246,6 +279,7 @@ class ControllerSpec:
             "fleet_max": (parameters or {}).get("fleet_max"),
             "fleet_definition":
                 (parameters or {}).get("fleet_definition"),
+            "fleet_devices": (parameters or {}).get("fleet_devices"),
             "canary_watch_ticks":
                 (parameters or {}).get("canary_watch_ticks"),
             "canary_burn_ratio":
@@ -299,7 +333,8 @@ def peer_definition(definition, name: str, journal_dir: str = "") \
         if key == "controller" or key.startswith("controller_") \
                 or key in ("gateway", "gateway_port", "fleet",
                            "fleet_min", "fleet_max",
-                           "fleet_definition", "metrics_port"):
+                           "fleet_definition", "fleet_devices",
+                           "metrics_port"):
             del parameters[key]
     parameters["controller"] = "off"
     parameters["gateway"] = "off"
@@ -426,29 +461,84 @@ class FleetSupervisor:
                 "retiring": sorted(self._retiring)}
 
 
+def device_env(device) -> dict:
+    """Environment overrides that give ONE child process the device it
+    was told: ``"cpu"`` -> the CPU backend; a chip index -> that one
+    TPU chip of this host and no other, through libtpu's per-process
+    chip bounds (established on the four-chip v5e host with libtpu
+    0.0.34, PR 21: the child sees exactly one device, and two such
+    children on different chips run at the same time) -- so several
+    one-chip children and the parent never contend for a chip.  The
+    only place a launcher writes ``JAX_PLATFORMS``: always from an
+    explicit per-child device argument, never as a default."""
+    if device == "cpu":
+        return {"JAX_PLATFORMS": "cpu"}
+    return {"JAX_PLATFORMS": "tpu",
+            "TPU_VISIBLE_CHIPS": str(int(device)),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1"}
+
+
 def default_spawner(definition, journal_dir: str = "",
-                    workdir: str = "", env: dict | None = None):
+                    workdir: str = "", env: dict | None = None,
+                    devices=None):
     """The production ``spawner``: write the peer's definition (via
     :func:`peer_definition`) and launch ``python -m aiko_services_tpu
     pipeline create`` against it, logs captured per peer -- exactly
-    the chaos driver's spawn, promoted."""
+    the chaos driver's spawn, promoted.
+
+    ``devices`` says where each child runs (``fleet_devices``: ``cpu``,
+    or a list with one entry per concurrently live child, each ``cpu``
+    or a chip index) and is REQUIRED: a chip belongs to one process at
+    a time, so a child whose device is left to a default either takes
+    the pilot's chip away or silently serves from the CPU.  A respawn
+    keeps its name's device; an exited child's device is free again.
+    The child's log opens with the assignment it was given, and the
+    child itself echoes what it found (``pipeline create``)."""
     import tempfile
+    problem = fleet_devices_error(devices)
+    if devices is None or problem is not None:
+        raise ValueError(
+            problem or "fleet peers need a device assignment: set "
+            "fleet_devices to 'cpu' or to a list with one entry per "
+            "peer ('cpu' or a chip index) -- no child's device is "
+            "chosen by default")
     workdir = workdir or tempfile.mkdtemp(prefix="aiko_fleet_")
     base_env = dict(os.environ)
     base_env.update(env or {})
-    base_env.setdefault("JAX_PLATFORMS", "cpu")
+    held: dict = {}                     # name -> (slot, Popen)
+
+    def slot_for(name: str) -> int:
+        """The ``devices`` entry this child runs on: its own again on a
+        respawn, else the first entry no LIVE child holds."""
+        if name in held:
+            return held[name][0]
+        busy = {slot for slot, process in held.values()
+                if process.poll() is None}
+        for slot in range(len(devices)):
+            if slot not in busy:
+                return slot
+        raise RuntimeError(
+            f"fleet_devices={devices!r}: no entry left for {name}")
 
     def spawn(name: str) -> subprocess.Popen:
+        slot = None if devices == "cpu" else slot_for(name)
+        device = "cpu" if slot is None else devices[slot]
         path = os.path.join(workdir, f"{name}.json")
         with open(path, "w") as stream:
             json.dump(peer_definition(definition, name, journal_dir),
                       stream)
         log = open(os.path.join(workdir, f"{name}.log"), "w")
-        return subprocess.Popen(
+        log.write(f"{name}: assigned device {device!r}\n")
+        log.flush()
+        process = subprocess.Popen(
             [sys.executable, "-m", "aiko_services_tpu", "pipeline",
              "create", path, "-t", "mqtt", "--name", name],
-            env=base_env, stdout=log, stderr=log,
-            start_new_session=True)
+            env={**base_env, **device_env(device)},
+            stdout=log, stderr=log, start_new_session=True)
+        if slot is not None:
+            held[name] = (slot, process)
+        return process
 
     return spawn
 

@@ -19,8 +19,6 @@ Axis conventions (the scaling-book recipe):
 
 from __future__ import annotations
 
-import inspect
-import os
 from typing import Sequence
 
 import jax
@@ -28,30 +26,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["device_inventory", "make_mesh", "MeshPlan", "submesh",
-           "inventory_tags", "shard_map", "donate_argnums_supported",
+           "inventory_tags", "donate_argnums_supported",
            "P", "NamedSharding"]
-
-# -- shard_map compatibility entry point ------------------------------------
-# The entry point and its replication-check keyword both moved across JAX
-# releases: jax >= 0.8 re-exports ``jax.shard_map`` taking ``check_vma``;
-# older releases ship ``jax.experimental.shard_map.shard_map`` taking
-# ``check_rep``.  Every shard_map call in this repo goes through this one
-# wrapper so the drift is absorbed in exactly one place.
-
-try:                                    # jax >= 0.8
-    from jax import shard_map as _shard_map
-except ImportError:                     # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_CHECK_KW = ("check_vma" if "check_vma"
-             in inspect.signature(_shard_map).parameters else "check_rep")
-
-
-def shard_map(fn, mesh, in_specs, out_specs, check: bool = True):
-    """Version-stable ``shard_map``: ``check`` maps onto whichever of
-    ``check_vma`` / ``check_rep`` the installed JAX understands."""
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_CHECK_KW: check})
 
 
 def donate_argnums_supported(argnums: tuple) -> tuple:
@@ -61,6 +37,7 @@ def donate_argnums_supported(argnums: tuple) -> tuple:
     TPU/GPU it is the free HBM win.  Returns ``argnums`` on backends that
     support donation, ``()`` on CPU."""
     return () if jax.default_backend() == "cpu" else tuple(argnums)
+
 
 AXIS_ORDER = ("pp", "dp", "fsdp", "ep", "sp", "tp")
 

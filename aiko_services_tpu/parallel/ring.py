@@ -31,7 +31,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from .mesh import P, shard_map as _shard_map
+from .mesh import P
 
 __all__ = ["ring_attention", "ulysses_attention", "blockwise_attention",
            "ring_attention_sharded"]
@@ -154,10 +154,10 @@ def ring_attention(q, k, v, q_positions, mesh, axis: str = "sp",
     spec_qkv = P(batch_axis, axis, head_axis, None)
     spec_pos = P(batch_axis, axis)
     inner = partial(_ring_inner, axis_name=axis, axis_size=n)
-    return _shard_map(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(spec_qkv, spec_qkv, spec_qkv, spec_pos, spec_pos),
-        out_specs=spec_qkv, check=False,
+        out_specs=spec_qkv, check_vma=False,
     )(q, k, v, q_positions, kv_positions)
 
 
@@ -206,10 +206,10 @@ def ulysses_attention(q, k, v, q_positions, mesh, axis: str = "sp",
     spec_qkv = P(batch_axis, axis, head_axis, None)
     spec_pos = P(batch_axis, axis)
     inner = partial(_ulysses_inner, axis_name=axis)
-    return _shard_map(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(spec_qkv, spec_qkv, spec_qkv, spec_pos, spec_pos),
-        out_specs=spec_qkv, check=False,
+        out_specs=spec_qkv, check_vma=False,
     )(q, k, v, q_positions, kv_positions)
 
 

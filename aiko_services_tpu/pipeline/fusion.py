@@ -29,10 +29,9 @@ computation; this module does that:
   the CPU backend (``donate_argnums_supported``), where XLA miscompiles
   the aliasing.
 - :func:`setup_compilation_cache` wires jax's persistent compilation
-  cache (env-gated: ``AIKO_COMPILE_CACHE_DIR``, or the
-  ``compile_cache_dir`` pipeline parameter) at Pipeline startup, so a
-  process restart replays compiled segments from disk instead of
-  re-tracing them.
+  cache at Pipeline startup (``JAX_COMPILATION_CACHE_DIR`` where set,
+  else one fixed directory inside the checkout), so a process restart
+  replays compiled programs from disk instead of re-compiling them.
 
 The ``fuse`` pipeline/stream parameter gates the whole path:
 ``auto`` (default) fuses where legal, ``off`` always walks per-element.
@@ -58,7 +57,8 @@ from ..parallel.mesh import donate_argnums_supported
 from ..utils import get_logger
 
 __all__ = ["DeviceFn", "FusedSegment", "FusionError", "partition",
-           "fusable", "setup_compilation_cache", "FUSE_MODES"]
+           "fusable", "setup_compilation_cache", "COMPILE_CACHE_DIR",
+           "FUSE_MODES"]
 
 _logger = get_logger("aiko.fusion")
 
@@ -274,6 +274,7 @@ class FusedSegment:
         self.steps: list[_Step] = []
         self.broken = False           # build/trace failed: run unfused
         self.calls = 0
+        self.donated_calls = 0      # dispatches that donated >= 1 buffer
         # donation is active off-CPU only; on CPU XLA miscompiles the
         # aliasing (see donate_argnums_supported) and d2h is zero-copy
         # anyway.
@@ -451,6 +452,7 @@ class FusedSegment:
         the compile probe honest per replica."""
         keep, donate = self._split(resolved, donated)
         self.calls += 1
+        self.donated_calls += bool(donate)
         start = time.perf_counter()
         try:
             return self._call(keep, donate, self._captures,
@@ -468,7 +470,9 @@ class FusedSegment:
             dispatch_p99 = self.dispatch_ms.quantile(0.99,
                                                      windowed=False)
         return {"elements": [node.name for node in self.nodes],
-                "calls": self.calls, "broken": self.broken,
+                "calls": self.calls,
+                "donated_calls": self.donated_calls,
+                "broken": self.broken,
                 "donation": self.donation, "stage": self.stage_context,
                 "dispatch_p50_ms": dispatch_p50,
                 "dispatch_p99_ms": dispatch_p99,
@@ -479,46 +483,38 @@ class FusedSegment:
 
 
 # ---------------------------------------------------------------------------
-# Persistent XLA compilation cache (env-gated, wired at Pipeline startup).
+# Persistent XLA compilation cache (wired at Pipeline startup).
 
-_CACHE_DIR_CONFIGURED: str | None = None
+#: Where compiled programs persist when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: ONE fixed, git-ignored directory at the checkout root.  The
+#: directory is part of every cache key's lookup, so it is never built
+#: from a temporary name, a pid or a time -- a cache that moves never
+#: hits.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
-def setup_compilation_cache(parameters: dict | None = None) -> str | None:
-    """Point jax's persistent compilation cache at a directory so
-    process restarts replay compiled segments from disk instead of
-    re-tracing + re-compiling them (cold-start kill).
+def setup_compilation_cache() -> str:
+    """Make jax's persistent compilation cache effective, so a process
+    restart (or the next chip-tool call) replays compiled programs from
+    disk instead of re-compiling llama3-1b and every detector bucket
+    from cold.  Returns the directory in effect.
 
-    Gated: the ``AIKO_COMPILE_CACHE_DIR`` environment variable wins,
-    else the ``compile_cache_dir`` pipeline parameter; absent both,
-    nothing is configured.  Returns the directory in effect (idempotent
-    across Pipelines -- the first configured directory stays; jax's
-    cache config is process-global)."""
-    global _CACHE_DIR_CONFIGURED
-    path = os.environ.get("AIKO_COMPILE_CACHE_DIR") \
-        or (parameters or {}).get("compile_cache_dir")
-    if not path:
-        return _CACHE_DIR_CONFIGURED
-    path = str(path)
-    if _CACHE_DIR_CONFIGURED is not None:
-        if path != _CACHE_DIR_CONFIGURED:
-            _logger.warning(
-                "compile cache already at %s; ignoring %s "
-                "(jax config is process-global)",
-                _CACHE_DIR_CONFIGURED, path)
-        return _CACHE_DIR_CONFIGURED
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    for option, value in (
-            # Cache every compile, however small/fast: pipeline segments
-            # are exactly the many-small-programs workload the default
-            # thresholds were tuned to exclude.
-            ("jax_persistent_cache_min_compile_time_secs", 0.0),
-            ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(option, value)
-        except AttributeError:        # pragma: no cover - jax drift
-            _logger.debug("jax config %s unavailable", option)
-    _CACHE_DIR_CONFIGURED = path
-    _logger.info("persistent XLA compile cache -> %s", path)
-    return path
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax's own reading of it
+    IS the cache directory and nothing here names another; where no
+    directory is configured, :data:`COMPILE_CACHE_DIR` is.  There is
+    deliberately no second way to name it (no parameter, no
+    framework-specific variable).  Idempotent; jax's cache config is
+    process-global."""
+    # Cache every compile, however small or fast: pipeline segments are
+    # exactly the many-small-programs workload the default thresholds
+    # were tuned to exclude.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    if jax.config.jax_compilation_cache_dir is None:
+        os.makedirs(COMPILE_CACHE_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+        _logger.info("persistent XLA compile cache -> %s",
+                     COMPILE_CACHE_DIR)
+    return jax.config.jax_compilation_cache_dir

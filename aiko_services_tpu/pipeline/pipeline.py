@@ -369,9 +369,9 @@ class Pipeline(Actor):
             # Fused device-segment compilation (pipeline/fusion.py): every
             # FusedSegment built for this pipeline's streams registers here
             # (jit_stats / bench counters); the persistent XLA compile
-            # cache is wired once per process, env-gated.
+            # cache is wired once per process.
             self.fused_segments: list[FusedSegment] = []
-            setup_compilation_cache(definition.parameters)
+            setup_compilation_cache()
             # Unified QoS admission (ISSUE 12, gateway/qos.py): the ONE
             # authority the four former admission planes consult --
             # DeviceWindow pacing, StageScheduler credits, ReplicaGroup
@@ -600,12 +600,18 @@ class Pipeline(Actor):
                     if self._controller_spec.fleet_definition:
                         spawn_definition = load_pipeline_definition(
                             self._controller_spec.fleet_definition)
-                    supervisor = FleetSupervisor(
-                        default_spawner(
+                    try:
+                        spawner = default_spawner(
                             spawn_definition,
                             str(definition.parameters.get(
-                                "journal_dir") or "")),
-                        engine=self.runtime.engine)
+                                "journal_dir") or ""),
+                            devices=self._controller_spec.fleet_devices)
+                    except ValueError as error:
+                        raise DefinitionError(
+                            f"pipeline {definition.name!r}: controller "
+                            f"with fleet_max > 1: {error}")
+                    supervisor = FleetSupervisor(
+                        spawner, engine=self.runtime.engine)
                 self.controller = FleetController(
                     self, self._controller_spec,
                     supervisor=supervisor)
@@ -1584,6 +1590,8 @@ class Pipeline(Actor):
                 "fused_elements": sum(len(s.nodes)
                                       for s in self.fused_segments),
                 "dispatches": sum(s.calls for s in self.fused_segments),
+                "donated": sum(s.donated_calls
+                               for s in self.fused_segments),
                 "broken": sum(1 for s in self.fused_segments if s.broken)}
 
     # -- binary data plane (ISSUE 9) ---------------------------------------

@@ -55,8 +55,8 @@ def probe_devices(devices: Sequence, prober: Callable | None = None,
 
     ``timeout=None`` uses :data:`PROBE_TIMEOUT`; pipelines plumb their
     ``health_probe_timeout`` parameter through here
-    (``Pipeline.check_device_health``), so deployments with slow links
-    (TPU tunnels) or tight failover SLOs tune it without patching."""
+    (``Pipeline.check_device_health``), so deployments with a loaded host or
+    tight failover SLOs tune it without patching."""
     prober = prober or default_prober
     timeout = PROBE_TIMEOUT if timeout is None else float(timeout)
     devices = list(devices)

@@ -1,4 +1,4 @@
-"""The speech models LEARN (VERDICT r2 item 6): a tiny ASR fitted on a
+"""The speech models LEARN: a tiny ASR fitted on a
 synthetic tone corpus transcribes held-out audio exactly, the KV-cached
 greedy decode is self-consistent with the teacher-forced decoder, and
 streaming transcription emits per-chunk text with exactly one compiled
@@ -235,7 +235,7 @@ def test_tts_fits_mel_targets():
 
 
 def test_subchunk_streaming_partial_latency(fitted_asr):
-    """VERDICT r3 item 6: with hop_seconds set, a live hypothesis is
+    """With hop_seconds set, a live hypothesis is
     produced every hop -- per-push latency is bounded by the HOP, not
     chunk_seconds -- and the finalized text still equals the whole-chunk
     decode exactly."""

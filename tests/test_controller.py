@@ -614,6 +614,111 @@ def test_peer_definition_strips_singleton_planes():
         == "StageWork"
 
 
+# -- no child's device is chosen by default (ISSUE 21) ----------------------
+
+def test_device_env_is_the_only_platform_assignment():
+    """``cpu`` -> the CPU backend; a chip index -> that one chip with
+    one-chip process bounds (how libtpu confines a process on a
+    multi-chip host)."""
+    from aiko_services_tpu.orchestration.controller import device_env
+    assert device_env("cpu") == {"JAX_PLATFORMS": "cpu"}
+    chip = device_env(2)
+    assert chip["JAX_PLATFORMS"] == "tpu"
+    assert chip["TPU_VISIBLE_CHIPS"] == "2"
+    assert chip["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+    assert chip["TPU_PROCESS_BOUNDS"] == "1,1,1"
+
+
+def test_fleet_devices_validation():
+    from aiko_services_tpu.orchestration.controller import \
+        fleet_devices_error
+    assert fleet_devices_error(None) is None
+    assert fleet_devices_error("cpu") is None
+    assert fleet_devices_error([1, 2, "cpu"]) is None
+    assert "one process" in fleet_devices_error([1, 1])
+    assert fleet_devices_error("tpu") is not None
+    assert fleet_devices_error([]) is not None
+    assert fleet_devices_error([True]) is not None
+    assert controller_spec_error(
+        {"mode": "act", "fleet_max": 2, "fleet_devices": "gpu"}) \
+        is not None
+    spec = ControllerSpec.parse({"mode": "act", "fleet_max": 3},
+                                {"fleet_devices": "[1, 2]"})
+    assert spec.fleet_devices == [1, 2]           # flat/CLI spelling
+
+
+def _pilot(extra):
+    return parse_pipeline_definition({
+        "version": 0, "name": "pilot", "runtime": "jax",
+        "graph": ["(work)"], "parameters": extra,
+        "elements": [{"name": "work", "input": [{"name": "x"}],
+                      "output": [{"name": "x"}],
+                      "deploy": {"local": {"module": COMMON,
+                                           "class_name":
+                                               "StageWork"}}}]})
+
+
+def test_default_spawner_refuses_without_an_assignment(tmp_path):
+    from aiko_services_tpu.orchestration.controller import \
+        default_spawner
+    with pytest.raises(ValueError, match="device assignment"):
+        default_spawner(_pilot({}), workdir=str(tmp_path))
+    with pytest.raises(ValueError, match="fleet_devices"):
+        default_spawner(_pilot({}), workdir=str(tmp_path),
+                        devices="tpu")
+
+
+def test_default_spawner_tells_each_child_its_device(tmp_path,
+                                                     monkeypatch):
+    """Each child gets the entry no LIVE child holds, keeps it across a
+    respawn, says so at the top of its log -- and a full list refuses
+    the next child instead of doubling up on a chip."""
+    from aiko_services_tpu.orchestration import controller
+
+    class FakeProcess:
+        def __init__(self, argv, env, **kwargs):
+            self.env, self.alive, self.pid = env, True, 1
+
+        def poll(self):
+            return None if self.alive else 0
+
+    monkeypatch.setattr(controller.subprocess, "Popen", FakeProcess)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")      # the pilot's own
+    spawn = controller.default_spawner(
+        _pilot({}), workdir=str(tmp_path), devices=[1, "cpu"])
+    first, second = spawn("p1"), spawn("p2")
+    assert first.env["TPU_VISIBLE_CHIPS"] == "1"
+    assert first.env["JAX_PLATFORMS"] == "tpu"      # not the pilot's cpu
+    assert second.env["JAX_PLATFORMS"] == "cpu"
+    assert "TPU_VISIBLE_CHIPS" not in second.env
+    assert (tmp_path / "p1.log").read_text().startswith(
+        "p1: assigned device 1")
+    with pytest.raises(RuntimeError, match="no entry left"):
+        spawn("p3")
+    first.alive = False                             # p1 died ...
+    assert spawn("p1").env["TPU_VISIBLE_CHIPS"] == "1"    # ... respawn
+    everywhere = controller.default_spawner(
+        _pilot({}), workdir=str(tmp_path), devices="cpu")
+    assert all(everywhere(f"q{i}").env["JAX_PLATFORMS"] == "cpu"
+               for i in range(5))
+
+
+def test_fleet_pilot_without_fleet_devices_is_definition_error(runtime):
+    """A pilot that would spawn peers must say where each runs: create
+    fails instead of defaulting the children onto the CPU."""
+    with pytest.raises(DefinitionError, match="fleet_devices"):
+        serving(runtime, "nodevices",
+                extra={"controller": {"mode": "act", "fleet_max": 2}})
+    pipeline = serving(runtime, "saiddevices", extra={
+        "controller": {"mode": "act", "fleet_max": 2,
+                       "fleet_devices": "cpu"}})
+    try:
+        assert pipeline.controller.supervisor is not None
+        assert pipeline.controller.spec.fleet_devices == "cpu"
+    finally:
+        pipeline.stop()
+
+
 # -- pipeline integration ---------------------------------------------------
 
 def stage(name, busy_ms=1.0, factor=2.0):

@@ -53,7 +53,7 @@ def test_post_from_thread():
 
 def test_latency_under_reference_tick():
     """The reference's 10 ms tick is its latency floor; ours must be far
-    below it (BASELINE.md: event-loop tick)."""
+    below it."""
     engine = EventEngine()
     stamps = {}
 

@@ -103,15 +103,24 @@ def test_decode_step_flash_int8_kv():
                                atol=0.15, rtol=0.15)
 
 
-def test_auto_threshold_resolves_at_trace_time():
-    """'auto' uses dense below the threshold and flash at/above it --
-    both must produce correct results on the same config object.
-    max_seq=128: the auto gate also requires a block-aligned extent
-    (cache_extent % 128 == 0), so 128 is the smallest extent where the
-    flash side actually takes the kernel path."""
+def test_auto_threshold_resolves_at_trace_time(monkeypatch):
+    """On the TPU backend 'auto' uses dense below the threshold and
+    flash at/above it -- both must produce correct results on the same
+    config object.  max_seq=128: the auto gate also requires a
+    block-aligned extent (cache_extent % 128 == 0), so 128 is the
+    smallest extent where the flash side actually takes the kernel
+    path.  Off the chip 'auto' is the reference path at ANY extent
+    (ISSUE 21: no interpreter in a serving loop that landed on CPU)."""
+    from aiko_services_tpu import ops
+    from aiko_services_tpu.models.llama import resolve_decode_backend
     config = llama.LlamaConfig.tiny(
         vocab_size=64, max_seq=128)
     small = dataclasses.replace(config, flash_decode_threshold=32)
+    assert resolve_decode_backend(
+        small, llama.init_cache(small, 2)) == "reference"
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)   # as on the chip
+    assert resolve_decode_backend(
+        small, llama.init_cache(small, 2)) == "dense-flash"
     dense_logits = _fixed_token_decode(config)      # 128 < 1024: dense
     flash_logits = _fixed_token_decode(small)       # 128 >= 32: flash
     np.testing.assert_allclose(np.asarray(flash_logits),
@@ -119,7 +128,7 @@ def test_auto_threshold_resolves_at_trace_time():
                                atol=5e-2, rtol=2e-2)
 
 
-def test_sharded_cache_never_reaches_flash():
+def test_sharded_cache_never_reaches_flash(monkeypatch):
     """ADVICE r4 (medium): pallas_call has no GSPMD partitioning rules,
     so a tp-sharded cache must never reach the flash kernel.  'auto'
     (the default) silently keeps dense for a distributed cache even at
@@ -127,8 +136,10 @@ def test_sharded_cache_never_reaches_flash():
     compiling a per-layer full-cache all-gather."""
     import pytest
 
+    from aiko_services_tpu import ops
     from aiko_services_tpu.parallel import MeshPlan, make_mesh
 
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)   # as on the chip
     base = dataclasses.replace(
         llama.LlamaConfig.tiny(vocab_size=64, max_seq=128),
         flash_decode_threshold=32)          # 128 is flash-eligible

@@ -722,22 +722,56 @@ def test_segment_hooks_flag_first_use_compile(tmp_path, runtime):
 # -- persistent compile cache --------------------------------------------
 
 
-def test_compilation_cache_env_gated(tmp_path, monkeypatch):
-    import jax as _jax
-    from aiko_services_tpu.pipeline import fusion as fusion_module
-    monkeypatch.setattr(fusion_module, "_CACHE_DIR_CONFIGURED", None)
-    # Absent the gate: nothing configured.
-    monkeypatch.delenv("AIKO_COMPILE_CACHE_DIR", raising=False)
-    assert fusion_module.setup_compilation_cache({}) is None
-    # Gated on: the directory is created and jax config points at it.
-    target = tmp_path / "xla_cache"
-    monkeypatch.setenv("AIKO_COMPILE_CACHE_DIR", str(target))
-    assert fusion_module.setup_compilation_cache({}) == str(target)
-    assert target.is_dir()
-    assert _jax.config.jax_compilation_cache_dir == str(target)
-    # Idempotent: a second pipeline with a different parameter dir does
-    # not re-point the process-global cache.
-    assert fusion_module.setup_compilation_cache(
-        {"compile_cache_dir": str(tmp_path / "other")}) == str(target)
-    monkeypatch.setattr(fusion_module, "_CACHE_DIR_CONFIGURED", None)
-    _jax.config.update("jax_compilation_cache_dir", None)
+_CACHE_PROBE = """
+import json, os, sys
+import jax
+updates = []
+_update = jax.config.update
+def recording(name, value):
+    updates.append(name)
+    _update(name, value)
+jax.config.update = recording
+from aiko_services_tpu.pipeline import fusion
+first = fusion.setup_compilation_cache()
+second = fusion.setup_compilation_cache()
+print(json.dumps({"dir": first, "again": second, "updates": updates,
+                  "fixed": fusion.COMPILE_CACHE_DIR,
+                  "config": jax.config.jax_compilation_cache_dir}))
+"""
+
+
+def _cache_probe(env_dir=None):
+    import json
+    import os
+    import subprocess
+    import sys
+    env = {key: value for key, value in os.environ.items()
+           if key != "JAX_COMPILATION_CACHE_DIR"}
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    result = subprocess.run([sys.executable, "-c", _CACHE_PROBE],
+                            capture_output=True, text=True, env=env,
+                            cwd=root, timeout=120)
+    assert result.returncode == 0, result.stderr[-2000:]
+    return json.loads(result.stdout.strip().splitlines()[-1]), root
+
+
+def test_compilation_cache_env_gated(tmp_path):
+    """ISSUE 21 section 7: with ``JAX_COMPILATION_CACHE_DIR`` set, jax's
+    own reading of it is the directory and the program sets none in
+    code; unset, the directory is ONE fixed path inside the checkout --
+    never a temporary name, a pid or a time."""
+    import os
+    import tempfile
+    # Env set: the directory is jax's, and no code path names one.
+    target = str(tmp_path / "xla_cache")
+    seen, _ = _cache_probe(target)
+    assert seen["dir"] == seen["again"] == seen["config"] == target
+    assert "jax_compilation_cache_dir" not in seen["updates"]
+    # Env unset: the fixed in-checkout path, the same on every call.
+    seen, root = _cache_probe(None)
+    assert seen["dir"] == seen["again"] == seen["fixed"] \
+        == os.path.join(root, ".jax_cache")
+    assert seen["updates"].count("jax_compilation_cache_dir") == 1
+    assert not seen["dir"].startswith(tempfile.gettempdir())

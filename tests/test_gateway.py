@@ -434,3 +434,23 @@ def test_loadgen_overload_sheds_batch_not_interactive(runtime):
     classes = report["classes"]
     assert classes["interactive"]["p99_ms"] > 0
     assert classes["interactive"]["goodput_fps"] > 0
+
+
+def test_loadgen_receiver_that_gives_up_is_an_error(runtime, monkeypatch):
+    """ISSUE 21: a receiver that stops waiting with frames still owed
+    reports it in ``errors`` -- it used to return silently
+    (``socket.timeout`` is an OSError), so a cold compile of the first
+    frame produced a short report and no failure."""
+    from aiko_services_tpu.gateway import loadgen
+    monkeypatch.setattr(loadgen, "RECV_SILENCE_S", 0.2)
+    pipeline = gateway_pipeline(runtime, busy_ms=1500.0)   # "compiling"
+    specs = [LoadSpec("alice", "interactive", rate=50.0, frames=2,
+                      data={"x": [1.0] * 4})]
+
+    thread, box = in_thread(
+        lambda: run_loadgen("127.0.0.1", pipeline.gateway.port, specs))
+    report = finish(runtime, thread, box, timeout=120.0)
+    assert report["tenants"]["alice"]["sent"] == 2
+    assert report["tenants"]["alice"]["ok"] < 2
+    assert any("receiver gave up" in error
+               for error in report["errors"]), report["errors"]

@@ -216,12 +216,23 @@ def test_decode_loop_paged_kernel_token_identical():
     assert np.array_equal(streams["kernel"][0], streams["reference"][0])
 
 
-def test_decode_backend_capability_probe():
+def test_decode_backend_capability_probe(monkeypatch):
     """The probe replaces the old raise: paged + explicit flash is the
-    paged kernel; auto follows extent/threshold/structure; distributed
-    and dense force the reference path."""
+    paged kernel; auto follows platform/extent/threshold/structure;
+    distributed and dense force the reference path.  Off the chip every
+    ``auto`` probe resolves ``reference`` (ISSUE 21) -- only a kernel
+    asked for by name runs there."""
+    from aiko_services_tpu import ops
     assert decode_backend("flash", paged=True,
                           page_tokens=64) == "paged-kernel"
+    assert decode_backend("flash") == "dense-flash"
+    assert decode_backend("auto", paged=True, extent=2048,
+                          threshold=1024, page_tokens=64) == "reference"
+    assert decode_backend("auto", extent=2048,
+                          threshold=1024) == "reference"
+    assert matmul_backend("auto") == "reference"
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)   # as on the chip
+    assert matmul_backend("auto") == "pallas-int8"
     assert decode_backend("auto", paged=True, extent=2048,
                           threshold=1024,
                           page_tokens=64) == "paged-kernel"

@@ -123,7 +123,7 @@ def test_batcher_serves_quantized_params():
     assert len(out) == 5
 
 
-# -- TP / fsdp composition (VERDICT r2 item 4) ---------------------------
+# -- TP / fsdp composition -----------------------------------------------
 
 
 def test_quantize_specs_mirror_quantized_tree():
@@ -176,7 +176,7 @@ def test_tp_decode_with_quantized_tree():
                                atol=2e-3)
 
 
-# -- int8 KV cache (VERDICT r2 item 4) -----------------------------------
+# -- int8 KV cache -------------------------------------------------------
 
 
 def test_kv_quantized_attention_matches_dequantized():
