@@ -1,0 +1,19 @@
+"""Reader kinds, one file each, found by file name: a per-layer metric's
+file (``benchmark/layer_metrics/<metric>.json``) names its ``kind`` and
+``args``; ``read(args, ctx)`` returns the number, or None when there
+is nothing to read (the harness then leaves the metric out).
+
+``ctx`` (``benchmark.run.Context``) offers: ``counters`` and
+``slice_counters`` (the program's counts over the window and over the
+traced slice), ``quantile(name, q, labels)`` (the telemetry registry
+since the window opened), ``label_values(name, label)``,
+``client_latencies_ms``, ``cut`` (the reduced trace, None untraced),
+``config``, ``workload``, ``peaks`` and ``metric(name)`` (another
+per-layer metric, by name)."""
+
+import importlib
+
+
+def load(kind: str):
+    """The reader module of a kind; an unknown kind is an error."""
+    return importlib.import_module(f"{__name__}.{kind}")
