@@ -11,6 +11,8 @@ overlay dict the reference's elements feed ImageOverlay
 
 from __future__ import annotations
 
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -195,12 +197,20 @@ class Detector(MicroBatchElement, TPUElement):
         boxes/scores/classes/valid rows land host-side together -- a
         single blocking copy for the whole micro-batch, not four syncs
         per frame) and complete each frame from its row."""
+        recorder = getattr(self.pipeline, "recorder", None)
+        if recorder is not None:
+            started = time.perf_counter()
         try:
             fetched = jax.device_get(dict(result))
         except Exception as error:            # pragma: no cover - defensive
             for complete, _ in entries:
                 complete(StreamEvent.ERROR, {"diagnostic": str(error)})
             return
+        if recorder is not None:
+            # The wait inside ``mb_finish`` (the rest of it is host
+            # work): a global ``fetch`` event on the host timeline.
+            recorder.record("fetch", None, None, self.name,
+                            (time.perf_counter() - started) * 1000.0)
         for row, (complete, image) in enumerate(entries):
             try:
                 outputs = self._postprocess(image, fetched, row)

@@ -286,6 +286,8 @@ class LLM(PipelineElement):
         self._published_drafted = 0
         self._published_prefix_hits = 0
         self._published_prefix_lookups = 0
+        # Where the worker thread's last ``llm_tick`` phase ended.
+        self._tick_mark = 0.0
 
     # Model-config parameters, resolved ON THE EVENT LOOP (stream
     # parameter precedence reads the pipeline's current-stream context,
@@ -438,7 +440,7 @@ class LLM(PipelineElement):
             fetch=None if ledger is None
             else (lambda tree: ledger.fetch(tree, label="llm_block")),
             fault_probe=self._fault_probe,
-            on_block=self._note_block,
+            trace=None if self._recorder() is None else self._trace_tick,
             cache_put=cache_put)
 
     def _stage_plan(self):
@@ -473,17 +475,32 @@ class LLM(PipelineElement):
         return {"params": devices(batcher.params),
                 "cache": devices(batcher.cache)}
 
-    def _note_block(self, phase: str, slots: int) -> None:
-        """Flight-recorder tap (ISSUE 10): every decode-block dispatch/
-        retire lands on the pipeline's event ring (global events --
-        no stream/frame: one block serves many), so serving cadence is
-        on the same timeline as the frames in a black-box dump.  Runs
-        on the element's decode worker thread; the ring is
-        thread-safe and a missing recorder costs one getattr."""
-        recorder = getattr(self.pipeline, "recorder", None)
+    def _recorder(self):
+        """The pipeline's flight recorder (None under ``recorder: off``
+        and outside a pipeline)."""
+        return getattr(getattr(self, "pipeline", None), "recorder", None)
+
+    def _trace_tick(self, name: str, ms: float, info=None) -> None:
+        """Host-timeline tap (ISSUE 26): one ``llm_tick`` duration
+        event per phase of the worker thread's time -- the batcher's
+        (``admit``, ``prefill``, ``fold``, ``dispatch``, ``retire_wait``,
+        ``demux``) and this element's own (``wait_work``, ``drain``,
+        ``publish``) -- global events (no stream/frame: one block
+        serves many), stamped at the phase's end on the worker thread,
+        so that serving cadence is on the frames' timeline in a
+        black-box dump and the benchmark can lay it beside the device
+        trace.  The next phase of the element's own starts here."""
+        recorder = self._recorder()
         if recorder is not None:
-            recorder.record("llm_block", None, None, phase,
-                            None, {"slots": slots})
+            recorder.record("llm_tick", None, None, name, ms, info)
+            self._tick_mark = time.perf_counter()
+
+    def _own_phase(self, name: str) -> None:
+        """One of the element's own phases ended now (it began where
+        the last phase of either kind ended)."""
+        if self._recorder() is not None:
+            self._trace_tick(
+                name, (time.perf_counter() - self._tick_mark) * 1000.0)
 
     def _make_request(self, stream_id, text,
                       request_params: dict) -> tuple[Request, list[int]]:
@@ -689,6 +706,7 @@ class LLM(PipelineElement):
             batcher.step()
         self._recover_streak = 0
         self._publish_serving_stats(batcher)
+        self._own_phase("publish")
 
     def _recover(self, batcher, error) -> bool:
         """Replay-from-last-emitted-block after a device-level failure:
@@ -730,6 +748,11 @@ class LLM(PipelineElement):
                     labels["cls"] = str(entry["cls"])
                 telemetry.registry.observe("llm_ttft_ms",
                                            entry["ttft_ms"], **labels)
+                telemetry.registry.observe("llm_queue_wait_ms",
+                                           entry["queue_ms"], **labels)
+                telemetry.registry.observe("llm_admit_to_first_ms",
+                                           entry["admit_to_first_ms"],
+                                           **labels)
                 if entry["tokens"] > 1:
                     telemetry.registry.observe("llm_tpot_ms",
                                                entry["tpot_ms"],
@@ -782,12 +805,15 @@ class LLM(PipelineElement):
         idle; while decoding, new queue items (requests from frames
         resumed meanwhile, stream cancels) are drained BETWEEN ticks so
         they join the live device batch."""
+        self._tick_mark = time.perf_counter()
         while True:
             item = work.get()
+            self._own_phase("wait_work")
             with self._device_lock, self._device_scope():
                 try:
                     self._handle(item)
                     self._drain_work(work)
+                    self._own_phase("drain")
                     batcher = self._batcher
                     while batcher is not None and (
                             batcher.active_count or batcher.queue_depth
@@ -802,6 +828,7 @@ class LLM(PipelineElement):
                             if not self._recover(batcher, error):
                                 raise
                         self._drain_work(work)
+                        self._own_phase("drain")
                 except Exception as error:
                     # A failing decode tick must FAIL the parked frames,
                     # not leave them parked forever -- the async

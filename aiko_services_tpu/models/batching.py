@@ -134,6 +134,8 @@ class Request:
     admit_seq: int = -1              # admission order (eviction picks
     #                                  the youngest victim)
     submit_time: float = 0.0         # llm_ttft_ms / llm_tpot_ms stamps
+    admit_time: float = 0.0          # first slot given (kept across an
+    #                                  eviction and re-admission)
     first_time: float = 0.0
     # Unified QoS admission (ISSUE 12, gateway/qos.py): the owning
     # frame's tenant/class, and the pre-computed class rank slot
@@ -203,7 +205,7 @@ class ContinuousBatcher:
                  kv_pages: int | None = None,
                  fetch: Callable | None = None,
                  fault_probe: Callable | None = None,
-                 on_block: Callable | None = None,
+                 trace: Callable | None = None,
                  sample_top_k: int = 0,
                  prefix_cache: bool | str = False,
                  prefix_min_tokens: int = 64,
@@ -331,12 +333,22 @@ class ContinuousBatcher:
         # Armed-chaos probe called before every device-loop block
         # dispatch (the ``decode_block`` injection point); None = cold.
         self._fault_probe = fault_probe
-        # Flight-recorder tap (ISSUE 10): ``on_block("dispatch" |
-        # "retire", occupied_slots)`` fires at every fused/loop block
-        # boundary.  The LLM element wires it to the pipeline's
-        # recorder so serving cadence shows up on the same timeline as
-        # the frames it serves; None (the default) costs one branch.
-        self.on_block = on_block
+        # Host-timeline tap (ISSUE 26): ``trace(name, ms, info)`` fires
+        # at the END of every phase of ``step()`` -- ``admit``,
+        # ``prefill``, ``fold`` (the device loop's eager fold-in of
+        # joiners, up to the block's enqueue), ``dispatch`` (the
+        # enqueue and the start of its host copies), ``retire_wait``
+        # (only the blocking fetch of the oldest block), ``demux`` --
+        # each phase starting where the last one ended, so they tile
+        # the tick.  ``prefill``, ``fold`` and ``dispatch`` launch
+        # device programs: where the runtime bounds the launches
+        # outstanding (32 on the v5e, PERF.md section 6) a launch
+        # blocks until the oldest finishes, so these phases hold the
+        # worker's waits for a busy chip as well as its work.  The LLM
+        # element wires the tap to the pipeline's flight recorder; None
+        # (the default) takes no stamp and costs one branch a phase.
+        self.trace = trace
+        self._mark = 0.0
         self.lengths = np.zeros(max_slots, dtype=np.int32)
         self.current = np.zeros(max_slots, dtype=np.int32)
         self.temperatures = np.zeros(max_slots, dtype=np.float32)
@@ -441,6 +453,8 @@ class ContinuousBatcher:
                     self.prefix_shared_tokens += shared
             request.admit_seq = self._admit_seq
             self._admit_seq += 1
+            if not request.admit_time:
+                request.admit_time = time.perf_counter()
             self.slots[slot] = request
             self.lengths[slot] = 0
             self._lengths_upper[slot] = 0
@@ -475,14 +489,15 @@ class ContinuousBatcher:
         back to per-slot dispatches for the flash-attention config.
         Synchronous path (decode_block == 1): at most ONE chunk total,
         preserving the one-chunk decode-stall bound (each chunk's
-        completion fetch blocks the host there)."""
+        completion fetch blocks the host there).  Returns the number
+        of chunks written."""
         pipelined = self.decode_block > 1 or self.device_loop
         if (pipelined and len(self._prefilling) > 1
                 and self.config.attention != "flash"):
-            self._prefill_tick_batched()
-            return
+            return self._prefill_tick_batched()
         budget = len(self._prefilling) if pipelined \
             else min(1, len(self._prefilling))
+        chunks = 0
         for _ in range(budget):
             if not self._prefilling:
                 break           # shrunk by a pressure eviction below
@@ -502,6 +517,8 @@ class ContinuousBatcher:
                 self.cache, jnp.int32(slot), jnp.int32(start))
             self._admission_advance(slot, request, start,
                                     len(chunk_tokens), logits)
+            chunks += 1
+        return chunks
 
     def _prefill_tick_batched(self):
         """One chunk for EVERY admitting slot in a single batched
@@ -528,7 +545,7 @@ class ContinuousBatcher:
         self._prefilling.extend(admitting[_ADMISSION_BURST_MAX:])
         admitting = admitting[:_ADMISSION_BURST_MAX]
         if not admitting:
-            return
+            return 0
         self._sync_page_table()
         n = len(admitting)
         rows = pad_to_bucket(admitting)
@@ -550,6 +567,7 @@ class ContinuousBatcher:
         for i, (slot, request, start, chunk_len) in enumerate(metas[:n]):
             self._admission_advance(slot, request, start, chunk_len,
                                     logits[i:i + 1])
+        return n
 
     def _admission_chunk(self, request: Request):
         """(start, chunk tokens) of the request's next prefill chunk.
@@ -626,14 +644,31 @@ class ContinuousBatcher:
         admitting slot, dispatch/retire decode work across all
         generating slots, emit tokens.  Returns the number of occupied
         slots (prefilling + decoding)."""
+        traced = self.trace is not None
+        if traced:
+            self._mark = time.perf_counter()
         self._admit()
-        self._prefill_tick()
+        if traced:
+            self._phase("admit")
+        chunks = self._prefill_tick()
+        if traced:
+            self._phase("prefill", {"chunks": chunks})
         decoding = [i for i in range(self.max_slots) if self.decoding[i]]
         if self.device_loop:
             if decoding or self._pending_first or self._loop_inflight:
+                blocks = 0
                 while len(self._loop_inflight) < self.inflight:
                     if not self._dispatch_loop_block():
                         break
+                    blocks += 1
+                if traced:
+                    new = [self._loop_inflight[-1 - index]
+                           for index in range(blocks)]
+                    self._phase("dispatch", {
+                        "blocks": blocks,
+                        "slots": len(new[0].snapshot) if new else 0,
+                        "joining": sum(len(block.firsts_meta)
+                                       for block in new)})
                 if self._loop_inflight:
                     self._retire_loop_block()
             return sum(1 for r in self.slots if r is not None)
@@ -649,16 +684,28 @@ class ContinuousBatcher:
                 remaining = max(
                     self.slots[i].max_new_tokens - self.slots[i].generated
                     for i in decoding if self.slots[i] is not None)
+                blocks = 0
                 while (len(self._inflight) < self.inflight
                        and len(self._inflight) * self.decode_block
                        < remaining):
                     if self._dispatch_block(decoding) is False:
                         break
+                    blocks += 1
+                if traced:
+                    self._phase("dispatch", {"blocks": blocks,
+                                             "slots": len(decoding)})
             if self._inflight:
                 self._retire_block()
         elif decoding:
             self._decode_tick(decoding)
         return sum(1 for r in self.slots if r is not None)
+
+    def _phase(self, name: str, info: dict | None = None) -> None:
+        """A tick phase ended now: hand its name and length to the
+        ``trace`` tap; the next one starts here."""
+        now = time.perf_counter()
+        self.trace(name, (now - self._mark) * 1000.0, info)
+        self._mark = now
 
     def _decode_tick(self, decoding: list[int]):
         if self._pages is not None:
@@ -688,9 +735,14 @@ class ContinuousBatcher:
             self.params, self.config, tokens, self.cache,
             jnp.asarray(write_positions))
         self._key, sub = jax.random.split(self._key)
-        next_tokens = np.asarray(jax.device_get(_select_tokens(
+        selected = _select_tokens(
             sub, logits, jnp.asarray(self.temperatures),
-            top_k=self.sample_top_k)), dtype=np.int32)
+            top_k=self.sample_top_k)
+        if self.trace is not None:
+            self._phase("dispatch", {"blocks": 1, "slots": len(decoding)})
+        next_tokens = np.asarray(jax.device_get(selected), dtype=np.int32)
+        if self.trace is not None:
+            self._phase("retire_wait")
         self.steps += 1
         for i in decoding:
             request = self.slots[i]
@@ -700,6 +752,8 @@ class ContinuousBatcher:
             token = int(next_tokens[i])
             self.current[i] = token
             self._emit(request, token)
+        if self.trace is not None:
+            self._phase("demux")
 
     def _dispatch_block(self, decoding: list[int]):
         """Enqueue one fused decode block chained off the previous
@@ -758,8 +812,6 @@ class ContinuousBatcher:
         self._inflight.append(_InflightBlock(
             emitted, [(i, self.slots[i]) for i in decoding], firsts,
             self.decode_block))
-        if self.on_block is not None:
-            self.on_block("dispatch", len(decoding))
 
     def _retire_block(self):
         """Fetch the OLDEST in-flight block's tokens (the async copy
@@ -770,9 +822,9 @@ class ContinuousBatcher:
         block was in flight is skipped via the request snapshot."""
         blk = self._inflight.popleft()
         emitted = np.asarray(blk.emitted)       # [steps, B]
+        if self.trace is not None:
+            self._phase("retire_wait", {"slots": len(blk.snapshot)})
         self.steps += 1
-        if self.on_block is not None:
-            self.on_block("retire", len(blk.snapshot))
         if blk.firsts is not None:
             first_meta, firsts_dev = blk.firsts
             first_tokens = np.asarray(firsts_dev)    # one fetch for all
@@ -790,6 +842,8 @@ class ContinuousBatcher:
                 token = int(emitted[block_step, slot])
                 self.current[slot] = token
                 self._emit(request, token)
+        if self.trace is not None:
+            self._phase("demux")
 
     # -- speculative auto-probe (ISSUE 18) ---------------------------------
 
@@ -947,6 +1001,8 @@ class ContinuousBatcher:
             firsts_meta.append((slot, request))
             first_vals.append(first)
         self._sync_page_table()
+        if self.trace is not None:
+            self._phase("fold", {"joining": len(firsts_meta)})
         (emitted, counts, tokens_next, lengths_next, active_next,
          budget_next, history_next, key_next, accepted, drafted, steps,
          self.cache) = llama.decode_loop(
@@ -975,8 +1031,6 @@ class ContinuousBatcher:
         self._loop_inflight.append(_LoopBlock(
             tree, [(i, self.slots[i]) for i in snapshot], firsts_meta))
         self.blocks_dispatched += 1
-        if self.on_block is not None:
-            self.on_block("dispatch", len(snapshot))
         return True
 
     def _retire_loop_block(self):
@@ -989,9 +1043,9 @@ class ContinuousBatcher:
         EARLIER than it, so truncation here only ever discards
         overshoot."""
         blk = self._loop_inflight.popleft()
-        if self.on_block is not None:
-            self.on_block("retire", len(blk.snapshot))
         fetched = self._fetch(blk.tree)
+        if self.trace is not None:
+            self._phase("retire_wait", {"slots": len(blk.snapshot)})
         emitted = np.asarray(fetched["emitted"])
         counts = np.asarray(fetched["counts"])
         self.steps += int(fetched["steps"])
@@ -1022,6 +1076,8 @@ class ContinuousBatcher:
                 self.lengths[slot] = int(lengths_fetched[slot])
         if not self._loop_inflight:
             self._lengths_upper = self.lengths.copy()
+        if self.trace is not None:
+            self._phase("demux")
 
     # -- paged-cache bookkeeping -------------------------------------------
 
@@ -1231,9 +1287,13 @@ class ContinuousBatcher:
             self._pages.prefix_lookups = 0
 
     def take_request_stats(self) -> list[dict]:
-        """Drain per-request latency stamps ({"ttft_ms", "tpot_ms",
-        "tokens"}) recorded at finish -- the serving element feeds them
-        to the telemetry plane."""
+        """Drain per-request latency stamps ({"ttft_ms", "queue_ms",
+        "admit_to_first_ms", "tpot_ms", "tokens"}) recorded at finish
+        -- the serving element feeds them to the telemetry plane.
+        ``queue_ms`` (submit to the first slot given) and
+        ``admit_to_first_ms`` (from there to the first token seen by
+        the host: prefill chunks, then the fetch of the block that
+        carries the token) add up to ``ttft_ms``."""
         stats, self._request_stats = self._request_stats, []
         return stats
 
@@ -1267,6 +1327,11 @@ class ContinuousBatcher:
                     if request.generated > 1 else 0.0
                 self._request_stats.append(
                     {"ttft_ms": round(ttft_ms, 3),
+                     "queue_ms": round((request.admit_time
+                                        - request.submit_time) * 1000.0, 3),
+                     "admit_to_first_ms": round(
+                         (request.first_time - request.admit_time)
+                         * 1000.0, 3),
                      "tpot_ms": round(tpot_ms, 3),
                      "tokens": request.generated,
                      "tenant": request.tenant,
@@ -1386,13 +1451,16 @@ class MicroBatcher:
 
     def __init__(self, run: Callable, finish: Callable,
                  context: Callable, schedule_flush: Callable,
-                 logger=None, name: str = "microbatch"):
+                 logger=None, name: str = "microbatch", recorder=None):
         self._run = run
         self._finish = finish
         self._context = context
         self._schedule_flush = schedule_flush
         self._logger = logger
         self.name = name
+        # Flight recorder (anything with its ``record``), or None: the
+        # worker stamps the two halves of ``_run_groups`` there.
+        self._recorder = recorder
         self._pending: list[tuple] = []  # (rank, seq, key, payload, complete)
         self._flush_scheduled = False
         self._queue: queue.Queue | None = None
@@ -1474,7 +1542,14 @@ class MicroBatcher:
     def _run_groups(self, context, groups):
         """Dispatch every group first, then fetch/complete each.  A
         failing dispatch errors every frame of ITS group -- anything
-        not completed here would stay parked forever."""
+        not completed here would stay parked forever.  With a
+        recorder the two halves are duration events: ``mb_run`` (stack,
+        upload, program calls) and ``mb_finish`` (fetch, complete)."""
+        recorder = self._recorder
+        if recorder is not None:
+            started = time.perf_counter()
+            info = {"groups": len(groups),
+                    "frames": sum(len(entries) for _, entries in groups)}
         dispatched = []
         for key, entries in groups:
             try:
@@ -1489,6 +1564,10 @@ class MicroBatcher:
                                    f"{self.name} dispatch: {error}")
                 continue
             dispatched.append((key, entries, result))
+        if recorder is not None:
+            ran = time.perf_counter()
+            recorder.record("mb_run", None, None, self.name,
+                            (ran - started) * 1000.0, info)
         for key, entries, result in dispatched:
             try:
                 self._finish(context, key, entries, result)
@@ -1498,6 +1577,9 @@ class MicroBatcher:
                         "%s: batch finish failed", self.name)
                 for complete, _ in entries:
                     complete_error(complete, str(error))
+        if recorder is not None:
+            recorder.record("mb_finish", None, None, self.name,
+                            (time.perf_counter() - ran) * 1000.0, info)
 
 
 def complete_error(complete: Callable, diagnostic: str):
@@ -1541,7 +1623,8 @@ class MicroBatchElement:
                 context=self.batch_context,
                 schedule_flush=(self.pipeline.runtime.engine
                                 .post_when_drained),
-                logger=self.logger, name=self.name)
+                logger=self.logger, name=self.name,
+                recorder=getattr(self.pipeline, "recorder", None))
         max_batch, _ = self.get_parameter("max_batch", 8)
         try:
             key = self.batch_key(payload)

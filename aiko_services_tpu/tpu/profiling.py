@@ -23,6 +23,16 @@ processes into one trace); the xprof annotations align those events
 with the device ops on the XLA timeline.  Debug latency with the
 trace/histograms, then zoom into a span's device work with xprof.
 
+Which timeline is for what (ISSUE 26).  The flight recorder
+(``observability/recorder.py``) is the program's host timeline: always
+on, and the one the benchmark lays on the device trace to name idle
+gaps.  Telemetry spans are per-request and cross-process.  The
+annotations here are for xprof by hand -- and they land in a trace only
+with the profiler's host tracer on, which is known to stall 720p
+camera uploads on the v5e (PERF.md section 5: 82-99 % device idle with
+it against 18-20 % without), so do not take a measurement from a
+profile made this way.
+
 CLI: ``python -m aiko_services_tpu pipeline create DEF --profile DIR``.
 """
 

@@ -71,8 +71,8 @@ def test_ring_bounds_and_snapshot_filters():
     assert only and all(event[3] == 1 for event in only)
     assert recorder.snapshot(tail=5) == recorder.snapshot()[-5:]
     # global events (stream/frame None) never join a frame's timeline
-    recorder.record("llm_block", None, None, "dispatch")
-    assert all(event[1] != "llm_block"
+    recorder.record("llm_tick", None, None, "dispatch")
+    assert all(event[1] != "llm_tick"
                for event in recorder.snapshot(frame=1))
 
 
