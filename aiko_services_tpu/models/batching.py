@@ -12,8 +12,9 @@ token streams.
 - ``max_slots`` sequences decode together as one [B] ``decode_step``;
 - admission is CHUNKED and INTERLEAVED: prompt tokens are written
   chunk-at-a-time straight into the admitted slot's region of the
-  batched cache (``llama.prefill_into_slot``; no scratch cache, no
-  full-extent scatter), interleaved with decode ticks.  With
+  batched cache (``llama.prefill_into_slot``; no scratch cache: the
+  donated cache is written once per chunk, after the layer scan, and
+  only where the chunk lands), interleaved with decode ticks.  With
   ``decode_block == 1`` each ``step()`` prefills at most ONE
   ``prefill_chunk`` -- a long prompt never stalls active decodes beyond
   one chunk's latency.  With ``decode_block > 1`` (the pipelined path,

@@ -23,7 +23,7 @@ from ..ops import decode_backend, matmul_backend
 from ..ops.layers import (rms_norm, rope_frequencies, apply_rope,
                           attention_prefill, attention_decode_append)
 from ..parallel.mesh import P
-from .paged import (gather_layer, gather_slot, is_paged, paged_extent,
+from .paged import (gather_layer, gather_rows, is_paged, paged_extent,
                     pool_page_tokens, scatter_pages)
 from .quant import dequantize_kv, is_quantized, quantize_kv
 
@@ -492,11 +492,21 @@ def _forward_layers(params: dict, config: LlamaConfig, hidden,
 
     ``kv_write_factory(k_layer, v_layer) -> kv_write`` builds the
     per-layer cache-write-and-attend closure (see :func:`_block`); each
-    layer's ``kv_write.updated`` is stacked as the scan output.  By
-    default those updates ARE the new cache layers (prefill writes
-    in-scan); ``cache_from_updates`` post-processes them instead -- the
-    decode path emits only each layer's new-token k/v (so the scan never
-    rewrites the whole cache) and scatters once at the end.
+    layer's ``kv_write.updated`` is stacked as the scan output.  The
+    cache rides the scan as ``xs``, so every step slices its layer out
+    (a copy wherever XLA cannot fuse the slice into its consumer).  By
+    default the updates ARE the new cache layers, restacked into a
+    FRESH cache that donation cannot alias -- only ``_prefill_core``
+    (training / whole-batch dense prefill, whose cache is the size of
+    its batch) is left on that branch.  ``cache_from_updates``
+    post-processes them instead: the reference decode and verify paths
+    read the cache as a read-only ``xs`` (their einsums fuse the
+    slice), emit only each layer's new-token k/v and scatter once at
+    the end.  Serving admission (:func:`_admit_chunks`) does not come
+    through here at all: beside a multi-GB page pool the ``xs`` slices
+    and the restacked ``ys`` were three quarters of a chunk's device
+    time, so it scans the layer index and closes over the cache, as
+    the flash decode scan does.
     Activation sharding follows from the param/cache input shardings via
     SPMD propagation; serving/training wrappers pin in_shardings
     explicitly (see models/train.py, tpu elements).
@@ -619,14 +629,118 @@ def prefill_with_aux(params: dict, config: LlamaConfig,
                          start_positions)
 
 
+def _scatter_chunks(cache: dict, k_chunks, v_chunks, slots,
+                    starts) -> dict:
+    """Write admission chunks (``[L, N, S, K, hd]``, row i at
+    ``(slots[i], starts[i])``) into the donated cache AFTER the layer
+    scan -- the whole-chunk twin of :func:`_scatter_positions`: one
+    dynamic_update_slice per row spanning all layers for a dense cache,
+    one per (row, covered page) through the page table for a paged one
+    (paged.scatter_pages).  The cache is touched nowhere else."""
+    def write(old, new):                         # new [L, N, S, *]
+        if is_paged(cache):
+            return scatter_pages(old, new, cache["page_table"], slots,
+                                 starts, pool_page_tokens(cache))
+        for i in range(new.shape[1]):
+            old = jax.lax.dynamic_update_slice(
+                old, new[:, i:i + 1],
+                (0, slots[i], starts[i]) + (0,) * (old.ndim - 3))
+        return old
+
+    return {**cache, "k": _kv_store(cache["k"], k_chunks, write),
+            "v": _kv_store(cache["v"], v_chunks, write)}
+
+
+def _admit_chunks(params: dict, config: LlamaConfig, tokens: jax.Array,
+                  cache: dict, slots, starts) -> tuple[jax.Array, dict]:
+    """Admission body shared by prefill_into_slot (N=1) and
+    prefill_into_slots: forward chunks ``tokens`` [N, S], row i at
+    cache offset ``starts[i]`` of batch row ``slots[i]``.
+
+    The cache NEVER enters or leaves the layer scan as ``xs``/``ys``
+    (the decode discipline, see _decode_step_impl): the scan carries
+    the LAYER INDEX and closes over the stacked cache read-only.  Per
+    layer it reads only the admitting slots' own rows (one gather: at
+    ``(layer, slots)`` of a dense cache, of the slots' pages of a paged
+    pool -- N unrolled dynamic_slices fuse into one consumer of the
+    WHOLE cache, for which the v5e compiler then picks a T-minor layout
+    and copies the cache ahead of the loop), lays the chunk's fresh k/v
+    into that small row view, attends over it, and emits the chunk's
+    k/v as the scan's only cache-related output;
+    :func:`_scatter_chunks` writes them into the donated cache once,
+    after the scan.  With the cache in ``xs``
+    and the updated layers as ``ys`` each step sliced its layer out of
+    the cache and the scan stacked a FRESH cache donation could not
+    alias: on v5e every chunk moved each side of a 5.2 GB pool about
+    four times (71 ms a chunk, of which ~16 ms matmuls and attention;
+    PERF.md, PR 27)."""
+    c = config
+    rope_table = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
+    n, s = tokens.shape
+    positions = starts[:, None] + jnp.arange(s)[None, :]     # [N, S]
+    paged = is_paged(cache)
+    if paged and s % pool_page_tokens(cache):
+        raise ValueError(
+            f"paged prefill chunk of {s} tokens is not a whole "
+            f"number of {pool_page_tokens(cache)}-token pages")
+
+    def rows(side, index):
+        """One layer's rows of the admitting slots: [N, T, ...]."""
+        if paged:
+            return gather_rows(side, cache["page_table"][slots], index)
+        return _kv_rows(side, lambda arr: arr[index, slots])
+
+    def lay(old, new):                  # chunks into their row views
+        for i in range(n):
+            old = jax.lax.dynamic_update_slice(
+                old, new[i:i + 1], (i, starts[i]) + (0,) * (old.ndim - 2))
+        return old
+
+    def layer_step(carry, xs):
+        hidden, aux = carry
+        layer, index = xs
+
+        def kv_write(q, k, v):
+            q = apply_rope(q, rope_table, positions)
+            k = apply_rope(k, rope_table, positions)
+            kv_write.updated = (k, v)
+            k_rows = _grouped(_kv_store(rows(cache["k"], index), k, lay),
+                              c.n_kv_heads)
+            v_rows = _grouped(_kv_store(rows(cache["v"], index), v, lay),
+                              c.n_kv_heads)
+            if c.attention == "flash":
+                # Causality from the traced chunk offset covers both
+                # intra-chunk masking and the unwritten cache tail.
+                # The kernel reads bf16; an int8 cache row is
+                # dequantized here (admission is compute-bound -- the
+                # byte saving matters in decode, which never does this).
+                from ..ops.pallas_attention import flash_attention
+                if is_quantized(k_rows):
+                    k_rows = dequantize_kv(k_rows, q.dtype)
+                    v_rows = dequantize_kv(v_rows, q.dtype)
+                return flash_attention(q, k_rows, v_rows,
+                                       q_offset=starts[0])
+            return attention_prefill(q, k_rows, v_rows, positions)
+        hidden2, aux2 = _block(c, hidden, layer, kv_write)
+        return (hidden2, aux + aux2), kv_write.updated
+
+    (hidden, _), (k_chunks, v_chunks) = jax.lax.scan(
+        layer_step, (params["embed"][tokens], jnp.float32(0.0)),
+        (params["layers"], jnp.arange(c.n_layers)))
+    return _finish(params, c, hidden), \
+        _scatter_chunks(cache, k_chunks, v_chunks, slots, starts)
+
+
 @partial(jax.jit, static_argnames=("config",), donate_argnames=("cache",))
 def _prefill_into_slot_jit(params: dict, config: LlamaConfig,
                            tokens: jax.Array, cache: dict,
                            slot: jax.Array,
                            start: jax.Array) -> tuple[jax.Array, dict]:
     """Process one prompt chunk for ONE sequence, writing its KV directly
-    into batch row ``slot`` of the BATCHED cache (no scratch cache, no
-    full-extent scatter -- the continuous batcher's admission path).
+    into batch row ``slot`` of the BATCHED cache (no scratch cache; the
+    donated cache is written once, after the layer scan, and only where
+    the chunk lands -- see :func:`_admit_chunks`; the continuous
+    batcher's admission path).
 
     tokens: [1, S] chunk (right-padding allowed; pad positions are
     overwritten by decode before the length mask ever admits them);
@@ -635,75 +749,15 @@ def _prefill_into_slot_jit(params: dict, config: LlamaConfig,
     0..N-1 written by earlier calls.  Returns (logits [1, S, vocab],
     cache) with the cache donated for in-place update.
 
-    A PAGED cache (models/paged.py) is written through its page table:
-    the chunk start must be page-aligned and S a whole number of pages
-    (the ContinuousBatcher's chunk discipline guarantees both), so the
-    write is one dynamic_update_slice per covered page and the
-    attention row is the slot's gathered page view.
+    A PAGED cache (models/paged.py) is read and written through its
+    page table: the chunk start must be page-aligned and S a whole
+    number of pages (the ContinuousBatcher's chunk discipline
+    guarantees both), so the write is one dynamic_update_slice per
+    covered page and the attention row is the slot's gathered page
+    view with the chunk laid into it.
     """
-    c = config
-    rope_table = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
-    s = tokens.shape[1]
-    positions = start[None, None] + jnp.arange(s)[None, :]   # [1, S]
-    paged = is_paged(cache)
-    if paged:
-        table, page_tokens = cache["page_table"], pool_page_tokens(cache)
-        if s % page_tokens:
-            raise ValueError(
-                f"paged prefill chunk of {s} tokens is not a whole "
-                f"number of {page_tokens}-token pages")
-
-    def factory(k_layer, v_layer):
-        def kv_write(q, k, v):
-            q = apply_rope(q, rope_table, positions)
-            k = apply_rope(k, rope_table, positions)
-
-            if paged:
-                def write(old, new):
-                    return scatter_pages(old, new, table, [slot],
-                                         [start], page_tokens)
-
-                def row(arr):
-                    raise NotImplementedError   # paged uses gather_slot
-            else:
-                def write(old, new):
-                    return jax.lax.dynamic_update_slice(
-                        old, new, (slot, start) + (0,) * (old.ndim - 2))
-
-                def row(arr):
-                    return jax.lax.dynamic_slice(
-                        arr, (slot,) + (0,) * (arr.ndim - 1),
-                        (1,) + arr.shape[1:])
-            k_layer2 = _kv_store(k_layer, k, write)
-            v_layer2 = _kv_store(v_layer, v, write)
-            kv_write.updated = (k_layer2, v_layer2)
-            if paged:
-                k_row = _grouped(gather_slot(k_layer2, table[slot]),
-                                 c.n_kv_heads)
-                v_row = _grouped(gather_slot(v_layer2, table[slot]),
-                                 c.n_kv_heads)
-            else:
-                k_row = _grouped(_kv_rows(k_layer2, row), c.n_kv_heads)
-                v_row = _grouped(_kv_rows(v_layer2, row), c.n_kv_heads)
-            if c.attention == "flash":
-                # Causality from the traced chunk offset covers both
-                # intra-chunk masking and the unwritten cache tail.
-                # The kernel reads bf16; an int8 cache row is
-                # dequantized here (admission is compute-bound -- the
-                # byte saving matters in decode, which never does this).
-                from ..ops.pallas_attention import flash_attention
-                if is_quantized(k_row):
-                    k_row = dequantize_kv(k_row, q.dtype)
-                    v_row = dequantize_kv(v_row, q.dtype)
-                return flash_attention(q, k_row, v_row, q_offset=start)
-            return attention_prefill(q, k_row, v_row, positions)
-        return kv_write
-
-    logits, new_cache, _ = _forward_layers(
-        params, c, params["embed"][tokens], cache, factory)
-    if paged:
-        new_cache["page_table"] = table
-    return logits, new_cache
+    return _admit_chunks(params, config, tokens, cache,
+                         jnp.reshape(slot, (1,)), jnp.reshape(start, (1,)))
 
 
 def prefill_into_slot(params: dict, config: LlamaConfig,
@@ -739,69 +793,10 @@ def _prefill_into_slots_jit(params: dict, config: LlamaConfig,
     attention only (the flash path keeps per-slot calls: its q_offset
     is per-dispatch).  Returns (logits [N, S, vocab], cache).
     """
-    c = config
-    if c.attention == "flash":
+    if config.attention == "flash":
         raise ValueError("prefill_into_slots is dense-only; "
                          "flash admission uses prefill_into_slot")
-    rope_table = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
-    n, s = tokens.shape
-    positions = starts[:, None] + jnp.arange(s)[None, :]     # [N, S]
-    paged = is_paged(cache)
-    if paged:
-        table, page_tokens = cache["page_table"], pool_page_tokens(cache)
-        if s % page_tokens:
-            raise ValueError(
-                f"paged prefill chunk of {s} tokens is not a whole "
-                f"number of {page_tokens}-token pages")
-
-    def factory(k_layer, v_layer):
-        def kv_write(q, k, v):
-            q = apply_rope(q, rope_table, positions)
-            k = apply_rope(k, rope_table, positions)
-
-            if paged:
-                def write_rows(old, new):
-                    return scatter_pages(old, new, table, slots,
-                                         starts, page_tokens)
-            else:
-                def write_rows(old, new):
-                    # Unrolled per-row DUS (in-place under donation; a
-                    # batched scatter would copy the cache -- see
-                    # decode_step).
-                    for i in range(n):
-                        old = jax.lax.dynamic_update_slice(
-                            old, new[i:i + 1],
-                            (slots[i], starts[i])
-                            + (0,) * (old.ndim - 2))
-                    return old
-
-            def gather_rows(arr):
-                return jnp.concatenate(
-                    [jax.lax.dynamic_slice(
-                        arr, (slots[i],) + (0,) * (arr.ndim - 1),
-                        (1,) + arr.shape[1:])
-                     for i in range(n)])                     # [N,T,*]
-            k_l = _kv_store(k_layer, k, write_rows)
-            v_l = _kv_store(v_layer, v, write_rows)
-            kv_write.updated = (k_l, v_l)
-            if paged:
-                k_rows = _grouped(gather_layer(k_l, table[slots]),
-                                  c.n_kv_heads)
-                v_rows = _grouped(gather_layer(v_l, table[slots]),
-                                  c.n_kv_heads)
-            else:
-                k_rows = _grouped(_kv_rows(k_l, gather_rows),
-                                  c.n_kv_heads)
-                v_rows = _grouped(_kv_rows(v_l, gather_rows),
-                                  c.n_kv_heads)
-            return attention_prefill(q, k_rows, v_rows, positions)
-        return kv_write
-
-    logits, new_cache, _ = _forward_layers(
-        params, c, params["embed"][tokens], cache, factory)
-    if paged:
-        new_cache["page_table"] = table
-    return logits, new_cache
+    return _admit_chunks(params, config, tokens, cache, slots, starts)
 
 
 def prefill_into_slots(params: dict, config: LlamaConfig,

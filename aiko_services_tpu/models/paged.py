@@ -25,9 +25,18 @@ Device access goes through gather/scatter:
 ``llama.prefill_into_slot(s)`` / ``decode_step`` / ``decode_loop``
 detect a paged cache (:func:`is_paged`) and (a) gather a slot's pages
 into the contiguous row view their attention already consumes, (b)
-scatter KV writes through the table with per-position
-``dynamic_update_slice`` (in-place under donation, same discipline as
-the dense path).  The gather materializes the logical view, so the
+scatter KV writes through the table with ``dynamic_update_slice`` AFTER
+the layer scan, on the stacked pool (:func:`scatter_pages` per covered
+page for admission, ``llama._scatter_positions`` per position for
+decode).  The pool never rides the layer scan as ``xs``/``ys``: a scan
+input is read-only, so each step copied its layer out of the pool and
+the scan stacked the updated layers into a FRESH pool that donation
+could not alias -- the v5e trace showed four pool-sized slice /
+update-slice fusions and two pool copies per admission chunk (71 ms
+against 18 ms for the same chunk beside a pool a sixth the size;
+PERF.md, PR 24/27).  Admission reads only the slot's own pages of each
+layer (:func:`gather_rows`) and writes only the chunk's pages.  The
+decode-side gather materializes the logical view, so the
 REFERENCE paged decode streams the cache roughly twice per step on TPU
 -- the price of paging without a paged-attention kernel.  ISSUE 11
 removed that price on the kernel plane: when the decode backend
@@ -51,7 +60,7 @@ from .quant import is_quantized
 
 __all__ = ["PageAllocator", "init_paged_cache", "is_paged",
            "pages_per_slot", "pool_page_tokens", "paged_extent",
-           "gather_layer", "gather_slot", "scatter_pages",
+           "gather_layer", "gather_rows", "gather_slot", "scatter_pages",
            "prefix_page_keys"]
 
 
@@ -134,24 +143,40 @@ def gather_layer(layer, table):
     return _gather(layer, table)
 
 
+def gather_rows(side, table, index):
+    """Layer ``index`` (may be traced) of a STACKED pool side
+    ``[L, P, pt, ...]`` -> the logical rows ``[N, T, ...]`` of table
+    rows ``[N, pps]``: one gather that reads only those pages, so no
+    per-layer slice of the pool materialises ahead of it (admission's
+    in-scan read; the pool is closed over, never a scan input)."""
+    def take(arr):
+        rows = arr[index, table]               # [N, pps, pt, ...]
+        return rows.reshape(table.shape[0], -1, *arr.shape[3:])
+    if is_quantized(side):
+        return {"int8": take(side["int8"]), "scale": take(side["scale"])}
+    return take(side)
+
+
 def scatter_pages(old, new, table, slots, starts, page_tokens: int):
-    """Write whole-page prefill rows through the page table: one
-    ``dynamic_update_slice`` per (row, covered page).  ``old`` is one
-    pool side ``[P, pt, ...]``, ``new`` the page-aligned chunk
-    ``[N, S, ...]`` (S a whole number of pages), ``slots``/``starts``
-    index ``new``'s rows into the table (scalars may be traced; the
-    row/page unroll is static).  Duplicated bucket-pad rows rewrite the
-    same physical pages with the same values.  The single shared
-    authority for both prefill paths (models/llama.py)."""
-    n, s = new.shape[0], new.shape[1]
+    """Write whole-page prefill chunks through the page table, all
+    layers at once: one ``dynamic_update_slice`` per (row, covered
+    page).  ``old`` is one STACKED pool array ``[L, P, pt, ...]``
+    (donated: updated in place), ``new`` the page-aligned chunks
+    ``[L, N, S, ...]`` the layer scan emitted (S a whole number of
+    pages), ``slots``/``starts`` index ``new``'s rows into the table
+    (scalars may be traced; the row/page unroll is static).
+    Duplicated bucket-pad rows rewrite the same physical pages with the
+    same values.  The page-granular twin of
+    ``llama._scatter_positions``; the pool is touched nowhere else."""
+    n, s = new.shape[1], new.shape[2]
     for i in range(n):
         for j in range(s // page_tokens):
             page = table[slots[i], starts[i] // page_tokens + j]
             part = jax.lax.dynamic_slice(
-                new, (i, j * page_tokens) + (0,) * (new.ndim - 2),
-                (1, page_tokens) + new.shape[2:])
+                new, (0, i, j * page_tokens) + (0,) * (new.ndim - 3),
+                (new.shape[0], 1, page_tokens) + new.shape[3:])
             old = jax.lax.dynamic_update_slice(
-                old, part, (page, 0) + (0,) * (old.ndim - 2))
+                old, part, (0, page, 0) + (0,) * (old.ndim - 3))
     return old
 
 

@@ -239,13 +239,127 @@ def test_paged_prefill_matches_dense(tiny_f32):
         start=jnp.int32(0))
     np.testing.assert_array_equal(np.asarray(logits_d),
                                   np.asarray(logits_p))
-    # gather_slot works on one layer's pool view (the layer scan's
-    # perspective); compare each layer's gathered row to the dense row.
+    # gather_slot works on one layer's pool view; compare each layer's
+    # gathered row to the dense row.
     for layer in range(config.n_layers):
         row_d = np.asarray(dense["k"][layer, 1])           # [T, K*hd]
         row_p = np.asarray(gather_slot(paged["k"][layer],
                                        paged["page_table"][1])[0])
         np.testing.assert_array_equal(row_d[:16], row_p[:16])
+
+
+def _noise(array, seed):
+    return jax.random.normal(jax.random.PRNGKey(seed), array.shape,
+                             dtype=array.dtype)
+
+
+# Chunk starts of one 40-token admission at prefill_chunk 16, pages of
+# 8, max_seq 40 (ContinuousBatcher._admission_chunk): the third chunk
+# clamps to 40 - 16 = 24 and rewrites positions 24..31.
+@pytest.mark.parametrize("starts", [(0,), (0, 16), (0, 16, 24)],
+                         ids=["first", "second", "clamped"])
+def test_paged_admission_writes_only_its_pages(tiny_f32, starts):
+    """The post-scan page write (ISSUE 27) against the one prefill
+    that still writes in-scan, ``llama.prefill`` on a dense cache --
+    the ``xs``/``ys`` discipline admission shared before: (a) logits
+    equal the dense admission path's, (b) the slot's pages hold exactly
+    the bytes the in-scan write lays down, (c) every page the slot's
+    table row does not name -- other slots' pages, the free pages, the
+    trash page -- is byte-identical before and after."""
+    config, params = tiny_f32
+    extent, chunk, page = 40, 16, 8
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (1, extent), 0,
+                                config.vocab_size)
+    slot, mine = 1, [5, 9, 2, 14, 11]
+    paged = init_paged_cache(config, 3, extent, page_tokens=page,
+                             total_pages=17)
+    paged["k"], paged["v"] = _noise(paged["k"], 1), _noise(paged["v"], 2)
+    paged["page_table"] = paged["page_table"] \
+        .at[0].set(jnp.asarray([1, 3, 4, 6, 7])) \
+        .at[slot].set(jnp.asarray(mine))
+    before = {side: np.asarray(paged[side]) for side in ("k", "v")}
+    dense = llama.init_cache(config, 3, extent)
+    in_scan = llama.init_cache(config, 1, extent)
+    for start in starts:
+        piece = tokens[:, start:start + chunk]
+        logits_p, paged = llama.prefill_into_slot(
+            params, config, piece, paged, jnp.int32(slot),
+            jnp.int32(start))
+        logits_d, dense = llama.prefill_into_slot(
+            params, config, piece, dense, jnp.int32(slot),
+            jnp.int32(start))
+        logits_x, in_scan = llama.prefill(
+            params, config, piece, in_scan, jnp.asarray([start]))
+        np.testing.assert_array_equal(np.asarray(logits_p),
+                                      np.asarray(logits_d))
+        np.testing.assert_array_equal(np.asarray(logits_p),
+                                      np.asarray(logits_x))
+    written = starts[-1] + chunk
+    others = [p for p in range(17) if p not in mine[:written // page]]
+    for side in ("k", "v"):
+        pool = np.asarray(paged[side])                 # [L, P, pt, C]
+        row = pool[:, mine].reshape(config.n_layers, extent, -1)
+        np.testing.assert_array_equal(
+            row[:, :written], np.asarray(in_scan[side])[:, 0, :written])
+        np.testing.assert_array_equal(
+            row[:, :written], np.asarray(dense[side])[:, slot, :written])
+        np.testing.assert_array_equal(pool[:, others],
+                                      before[side][:, others])
+
+
+def _scans(jaxpr, length):
+    """Every ``scan`` of ``length`` steps in a jaxpr, nested ones too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan" \
+                and eqn.params["length"] == length:
+            yield eqn
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                yield from _scans(inner, length)
+
+
+@pytest.mark.parametrize("program", ["prefill_into_slot",
+                                     "prefill_into_slots"])
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
+def test_admission_keeps_the_cache_out_of_the_layer_scan(tiny_f32, paged,
+                                                         program):
+    """Structural guard (ISSUE 27): in the admission programs the KV
+    cache is never a scanned input or a stacked output of the layer
+    scan -- the pool may appear only closed over (a constant of the
+    scan) or carried.  As ``xs``/``ys`` every chunk sliced each layer
+    out of the pool and restacked a fresh one: 53 of a chunk's 71 ms on
+    the v5e beside a 5.2 GB pool (PERF.md, PR 27).  The compiled
+    program's temporaries stay well under one side of a cache sized far
+    above the chunk (8 MB a side here; the chunk's own are ~1 MB)."""
+    config, params = tiny_f32  # the CPU backend widens bf16 updates
+    chunk, page, extent = 16, 8, 64
+    cache = init_paged_cache(config, 8, extent, page_tokens=page,
+                             total_pages=8193) if paged \
+        else llama.init_cache(config, 1031, extent)
+    layer_shape = llama.cache_array(cache).shape[1:]
+    if program == "prefill_into_slot":
+        args = (jnp.zeros((1, chunk), jnp.int32), cache, jnp.int32(1),
+                jnp.int32(16))
+    else:
+        args = (jnp.zeros((4, chunk), jnp.int32), cache,
+                jnp.asarray([1, 2, 3, 1]), jnp.asarray([16, 0, 8, 16]))
+    jitted = getattr(llama, f"_{program}_jit")
+    closed = jax.make_jaxpr(jitted, static_argnums=1)(
+        params, config, *args)
+    (scan,) = list(_scans(closed.jaxpr, config.n_layers))
+    consts, carry = scan.params["num_consts"], scan.params["num_carry"]
+    scanned = [v.aval.shape[1:] for v in scan.invars[consts + carry:]]
+    stacked = [v.aval.shape[1:] for v in scan.outvars[carry:]]
+    assert layer_shape not in scanned, scanned
+    assert layer_shape not in stacked, stacked
+    assert tuple(llama.cache_array(cache).shape) in \
+        [v.aval.shape for v in scan.invars[:consts + carry]]
+    stats = jitted.lower(params, config, *args).compile() \
+        .memory_analysis()
+    if stats is not None:
+        side_bytes = llama.cache_array(cache).nbytes
+        assert stats.temp_size_in_bytes < side_bytes // 2, stats
 
 
 def test_pool_pressure_preempts_youngest_and_resumes(tiny_f32):
