@@ -6,42 +6,39 @@ touching the program.
   into a decoded 720p frame from a per-camera pool made from the seed,
   as ``VideoReadFile`` or an RTSP source hands one over: the image
   never crosses the door as JSON.
-- :class:`ConfiguredLLM` serves the widths of a configuration file
-  through the program's own ``LLM`` element (the program only knows
-  Llama presets; PERF.md section 7 asks for "widths from a file" so
-  this subclass can go).
+- :class:`ConfiguredLLM` is the Llama family's element class
+  (``benchmark/architectures/llama.py`` hands it ``widths``): it serves
+  the widths of a configuration file through the program's own ``LLM``
+  element (the program only knows presets; PERF.md section 7 asks for
+  "widths from a file" so this subclass can go).
+- :class:`EveryBucketResize` and :class:`EveryBucketDetector` are the
+  program's ``ImageResize`` and ``Detector`` with every micro-batch
+  bucket built on the first frame, and each frame's bucket named in
+  its result: which group sizes a run's traffic forms is a matter of
+  timing, a size first met in the window is a program built in the
+  window, and a bucket of 4 rounds otherwise than one of 1 (PERF.md
+  section 6, PR 28).
 - :class:`ResultTrim` keeps tensors out of the result message.
 """
 
 from __future__ import annotations
 
+import functools
 import json
 import time
 
 import numpy as np
 
+from aiko_services_tpu.elements.detect import Detector
+from aiko_services_tpu.elements.image import ImageResize
 from aiko_services_tpu.elements.llm import LLM
 from aiko_services_tpu.models import llama
+from aiko_services_tpu.models.batching import pad_to_bucket
 from aiko_services_tpu.models.tokenizer import ByteTokenizer
 from aiko_services_tpu.pipeline import PipelineElement, StreamEvent
 
+from benchmark.architectures.llama import llama_config
 from benchmark.traffic import seed31
-
-# Published config.json key -> LlamaConfig field.
-WIDTH_FIELDS = {"vocab_size": "vocab_size", "hidden_size": "dim",
-                "num_hidden_layers": "n_layers",
-                "num_attention_heads": "n_heads",
-                "num_key_value_heads": "n_kv_heads",
-                "intermediate_size": "hidden_dim",
-                "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps"}
-
-
-def llama_config(widths: dict) -> llama.LlamaConfig:
-    fields = {}
-    for key, field in WIDTH_FIELDS.items():
-        kind = float if field in ("rope_theta", "norm_eps") else int
-        fields[field] = kind(widths[key])
-    return llama.LlamaConfig(**fields)
 
 
 class _NoStopTokenizer(ByteTokenizer):
@@ -102,6 +99,63 @@ class ConfiguredLLM(LLM):
         first = jnp.zeros((1,), dtype=jnp.int32)
         for count in range(1, self._batcher.max_slots + 1):
             jnp.concatenate([first] * count).block_until_ready()
+
+
+class _EveryBucket:
+    """Over a ``MicroBatchElement``, two things a run's timing must not
+    decide.
+
+    The first group of a key also runs that key's every bucket (1, 2,
+    4 .. ``max_batch``, a power of two as the program's default 8 is,
+    copies of the group's first payload) through the
+    element's own ``batch_run`` and ``batch_finish``, on the
+    micro-batcher's worker, and drops what they complete.  The
+    traffic's first request is in set-up, so every program a group of
+    any size needs is built there, whatever sizes the warm-up forms.
+
+    And every frame's outputs say which bucket it rode in, under
+    ``<element name>_group``: on the chip a bucket of 4 or 8 rounds
+    otherwise than one of 1 or 2 (PERF.md section 6, PR 28), so "the
+    same input gives the same answer" holds per bucket and is checked
+    per bucket (a workload file's ``same_answer.key``)."""
+
+    def batch_run(self, context, key, payloads):
+        seen = self.__dict__.setdefault("_buckets_built", set())
+        if key not in seen:
+            seen.add(key)
+            max_batch, _ = self.get_parameter("max_batch", 8)
+            size = 1
+            while size <= int(max_batch):
+                group = [payloads[0]] * size
+                super().batch_finish(
+                    context, key,
+                    [(_drop, payload) for payload in group],
+                    super().batch_run(context, key, group))
+                size *= 2
+        return super().batch_run(context, key, payloads)
+
+    def batch_finish(self, context, key, entries, result):
+        tag = {f"{self.name}_group": len(pad_to_bucket(entries))}
+        super().batch_finish(
+            context, key,
+            [(functools.partial(_tagged, complete, tag), payload)
+             for complete, payload in entries], result)
+
+
+def _drop(event, outputs):
+    """``complete`` of a frame nobody sent."""
+
+
+def _tagged(complete, tag, event, outputs):
+    complete(event, {**outputs, **tag})
+
+
+class EveryBucketResize(_EveryBucket, ImageResize):
+    pass
+
+
+class EveryBucketDetector(_EveryBucket, Detector):
+    pass
 
 
 class CameraSource(PipelineElement):

@@ -19,22 +19,18 @@ zero fell 1.9 ms after ``jax.profiler.start_trace`` was entered -- and
 the program cannot know when the harness made that call.  So no clock
 converts, and the alignment has two parts.
 
-**Coarse: the window's end.**  The harness starts the trace one slice
-(``trace_slice_s``) before the window ends, so the trace's zero is the
-window's end less the slice on ``perf_counter``, a few ms late (the
-harness's wake-up, its counter snapshot, the 0.7-1.9 ms above).  The
-window's end lies between two results: after the last frame that
-finished inside the window (its wall stamp, which
-``FlightRecorder.clock()`` converts) and before the next frame finished
-at all (the ring's own ``done`` event).  That brackets the offset to one
-gap between results (``window_of_frames``; 92-97 ms in ``camera-paced``,
-217-468 ms in ``chat-batch``, my chip runs, PR 26) plus
-``anchor_margin_s`` above for the lateness -- the true offset was inside
-it in all six of those runs -- and only differences inside the bracket
-are looked at.  Results
-leave at a block's retire, so the bracket is about one tick wide: it
-settles which block is which, and what it cannot settle the rival rule
-below refuses.
+**Coarse: the stamp around ``start_trace``** (PR 28).  The harness
+takes ``perf_counter`` just before it enters
+``jax.profiler.start_trace`` and just after the call returns, and the
+profiler session starts inside the call: the offset (perf_counter minus
+trace time) lies between the two stamps.  The call takes 48-54 ms, and
+the session starts early in it (0.7-2.1 ms after entry in the probes
+above, 0.7-1.5 ms in four ``camera-paced`` runs of PR 28), so the
+reader cuts the bracket to ``anchor_margin_s`` after entry: 15 ms,
+where the window's last results gave one of a tick (PR 26: 92-97 ms in
+``camera-paced``, which after PR 27's shorter ticks could hold the
+wrong block and miss the right one).  Only differences inside the
+bracket are looked at, so the fine part is a check more than a search.
 
 **Fine: the program's own work** (my chip runs, PR 26, both cells).
 ISSUE 26 expected a ``retire_wait`` that blocked to end just after its
@@ -65,6 +61,19 @@ of ``camera-paced`` had a rival of 7 pairs against 9, one tick away).
 construction in ``camera-paced``, whose blocks all last 43.6 ms: an end
 looks like a start one block later.)
 
+**Which blocks are paired** (PR 28).  The premise -- the worker
+reaches the block's enqueue with the chip idle -- fails for a block
+that starts back to back with the program before it: there the worker
+was ahead of the chip and its ``dispatch`` returned before the block
+started (after PR 27 shortened admission, 3 blocks of 12 in a
+``camera-paced`` slice; ``chat-batch`` has had some all along).  So a
+``start`` edge is on offer only where the chip had been idle for
+``idle_before_ms`` before it (``program_runs`` reads that off the
+cut's programs: 1.0-2.1 ms where the premise holds, 0.004-0.26 ms
+where it does not; my chip runs, PR 27 and PR 28).  An ``end`` edge
+needs no such rule: a ``retire_wait`` that blocked returns after its
+block's end whoever was ahead.
+
 The offset is refused (``None``, with the reason in the notes) with
 fewer than ``least_pairs`` pairs inside the bracket, a distance over
 ``spread_ms``, such a rival, or a ring that wrapped past the slice's
@@ -88,9 +97,6 @@ GLOBAL_PREFIXES = ("gc:", "build:")
 # remote round trip): they cover everything and explain nothing.
 SPAN_PREFIXES = ("resume:", "done:", "response:")
 NO_SPAN = trace.NO_HOST_SPAN
-# The engine's wall stamp of a frame's finish and the ring's ``done``
-# event of the same frame are this close; results further apart are two.
-SAME_RESULT_S = 0.005
 
 
 def live_recorder():
@@ -107,28 +113,35 @@ def is_wait(name: str) -> bool:
     return name.startswith(WAIT_PREFIXES)
 
 
-def window_of_frames(frames: dict, clock, intervals) -> dict | None:
-    """What is known of the measured window's ends, in ``perf_counter``
-    seconds: ``first`` and ``last`` -- the first and last finish of the
-    frames that finished inside it, from their wall stamps -- and
-    ``end_by``: the first frame of the ring (``done:...``) to finish
-    clearly after ``last`` finished after the window ended.  Where the
-    ring holds none, the longest gap between two of the window's
-    results stands in for that gap."""
-    finished = sorted(entry["finished"] for entry in frames.values()
-                      if entry.get("finished") is not None)
-    if len(finished) < 2:
-        return None
-    perf_ns, wall_ns = clock
-    shift = (perf_ns - wall_ns) / 1e9
-    last = finished[-1] + shift
-    later = [start + duration for name, start, duration in intervals
-             if name.startswith("done:")
-             and start + duration > last + SAME_RESULT_S]
-    longest = max(after - before
-                  for before, after in zip(finished, finished[1:]))
-    return {"first": finished[0] + shift, "last": last,
-            "end_by": min(later, default=last + longest)}
+def program_runs(modules, program: str) -> list[tuple]:
+    """``(start_s, end_s, idle_before_s)`` of every run of the programs
+    whose name contains ``program``, in trace seconds; the last is how
+    long the device had run no program at all when this one started
+    (nought for the slice's first, whose past is not in the cut)."""
+    runs, busy_until = [], None
+    for name, start, duration in sorted(modules,
+                                        key=lambda event: event[1]):
+        if program in trace.program_name(name):
+            idle = 0 if busy_until is None else max(0, start - busy_until)
+            runs.append((start / 1e9, (start + duration) / 1e9,
+                         idle / 1e9))
+        busy_until = max(busy_until or 0, start + duration)
+    return runs
+
+
+def sync_pairs(runs, intervals, sync: dict, idle_before_ms: float) -> list:
+    """What ``align`` pairs, per family of ``sync`` (phase name ->
+    ``[edge, least ms]``): the trace seconds of that edge of the
+    program's ``runs`` (``program_runs``) -- a ``start`` only where
+    the chip had been idle ``idle_before_ms`` before it -- and the
+    perf_counter seconds at which the family's phases of at least
+    ``least ms`` ended."""
+    after_idle = [start for start, _, idle in runs
+                  if idle * 1000.0 >= idle_before_ms]
+    return [([end for _, end, _ in runs] if edge == "end" else after_idle,
+             [start + duration for name, start, duration in intervals
+              if name == family and duration * 1000.0 >= least_ms])
+            for family, (edge, least_ms) in sync.items()]
 
 
 def align(pairs, *, bounds, inlier_ms, spread_ms, least_pairs,
@@ -235,8 +248,8 @@ def _owner(active, micro_batched):
 def flatten(intervals, lo, hi) -> list[list]:
     """``[name, start, duration]`` intervals of several threads, nested
     and overlapping, as disjoint leaf segments inside ``[lo, hi)``
-    sorted by start (the shape ``trace._covering_span`` expects of
-    ``cut["host"]``): work goes before wait, so a wait is entered only
+    sorted by start (the shape ``trace._covering_span`` expects of its
+    host spans): work goes before wait, so a wait is entered only
     where no other thread works."""
     micro_batched = {name.partition(":")[2] for name, _, _ in intervals
                      if name.startswith(("mb_run:", "mb_finish:"))}

@@ -8,7 +8,12 @@ is nothing to read (the harness then leaves the metric out).
 traced slice), ``quantile(name, q, labels)`` (the telemetry registry
 since the window opened), ``label_values(name, label)``,
 ``client_latencies_ms``, ``cut`` (the reduced trace, None untraced),
-``config``, ``workload``, ``peaks`` and ``metric(name)`` (another
+``window`` and ``trace_stamp`` (``perf_counter`` seconds: the measured
+window; just before ``start_trace`` was entered and just after it
+returned), ``host`` (host spans on the cut's clock: None until
+``idle_by_host_span`` has laid the recorder on it), ``config``,
+``architecture`` (the configuration's family, ``benchmark/
+architectures``), ``workload``, ``peaks`` and ``metric(name)`` (another
 per-layer metric, by name)."""
 
 import importlib

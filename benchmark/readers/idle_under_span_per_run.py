@@ -6,8 +6,8 @@ and ``decode_loop`` it is the host time per decode block that the chip
 waits for -- what is left of the worker's ticks when the time it
 overlaps with a busy chip, or is held up in a launch behind one, is
 taken away.  Reads the host spans that the metric ``after`` leaves in
-``ctx.cut["host"]`` (``idle_by_host_span``: the flight recorder laid on
-the trace's clock); None where that metric has nothing."""
+``ctx.host`` (``idle_by_host_span``: the flight recorder laid on the
+trace's clock); None where that metric has nothing."""
 
 from benchmark import host_timeline, trace
 
@@ -21,7 +21,7 @@ def read(args, ctx):
     if not runs:
         return None
     by_span = host_timeline.idle_by_span(
-        host_timeline.idle_gaps(ctx.cut), ctx.cut["host"])
+        host_timeline.idle_gaps(ctx.cut), ctx.host)
     under = sum(own for name, own in by_span.items()
                 if name.startswith(args["under"])
                 and not host_timeline.is_wait(name))

@@ -6,9 +6,11 @@ The cell, its configuration, its traffic and its metrics are data:
 ``BENCHMARK.json`` names them and the harness finds
 ``benchmark/configs/<config>.json`` (through the entry's ``file``),
 ``benchmark/workloads/<traffic>.json``,
-``benchmark/layer_metrics/<metric>.json`` and
-``benchmark/readers/<kind>.py`` by those names.  Nothing here names a
-cell, a configuration or a metric.
+``benchmark/layer_metrics/<metric>.json``,
+``benchmark/readers/<kind>.py`` and, by the configuration's
+``"architecture"``, ``benchmark/architectures/<name>.py`` by those
+names (beside the manifest first, where ``--manifest`` names another).
+Nothing here names a cell, a configuration, a metric or a model family.
 
 The run: refuse anything but a TPU (``--rehearse``, never inferred,
 walks the same control flow at ``model: tiny`` on whatever backend is
@@ -46,6 +48,7 @@ import traceback            # noqa: E402
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HERE = os.path.join(ROOT, "benchmark")
 BUILD_EVENT = "/jax/core/compile/backend_compile_duration"
+MANIFEST = [os.path.join(ROOT, "BENCHMARK.json")]   # ``--manifest``
 
 
 class Expired(RuntimeError):
@@ -57,13 +60,28 @@ def load_json(*parts):
         return json.load(stream)
 
 
+def data_directories() -> list:
+    """Where a data file is looked for: beside the manifest, then here."""
+    beside = os.path.dirname(os.path.abspath(MANIFEST[0]))
+    return [HERE] if beside == ROOT else [beside, HERE]
+
+
+def load_data(*parts):
+    found = [directory for directory in data_directories()
+             if os.path.isfile(os.path.join(directory, *parts))]
+    if not found:
+        raise SystemExit(f"benchmark: no {os.path.join(*parts)} in "
+                         f"{data_directories()}")
+    return load_json(found[0], *parts)
+
+
 def load_cell(name: str) -> dict:
     """Everything the manifest and the data files say about one cell."""
-    manifest = load_json(ROOT, "BENCHMARK.json")
+    manifest = load_json(MANIFEST[0])
     cells = {cell["name"]: cell for cell in manifest["workloads"]}
     if name not in cells:
         raise SystemExit(f"benchmark: no workload {name!r} in "
-                         f"BENCHMARK.json (has: {sorted(cells)})")
+                         f"{MANIFEST[0]} (has: {sorted(cells)})")
     cell = cells[name]
     config_entry = next(entry for entry in manifest["configs"]
                         if entry["name"] == cell["config"])
@@ -74,15 +92,14 @@ def load_cell(name: str) -> dict:
     return {
         "cell": cell,
         "config": load_json(ROOT, config_entry["file"]),
-        "workload": load_json(HERE, "workloads",
-                              f"{cell['traffic']}.json"),
+        "workload": load_data("workloads", f"{cell['traffic']}.json"),
         "end_to_end": [m for m in manifest["end_to_end"] if reported(m)],
         "per_layer": [m for m in manifest["per_layer"] if reported(m)],
     }
 
 
 def layer_metric(name: str) -> dict:
-    return load_json(HERE, "layer_metrics", f"{name}.json")
+    return load_data("layer_metrics", f"{name}.json")
 
 
 def merged(base: dict, overrides: dict) -> dict:
@@ -104,17 +121,20 @@ def detail(**facts):
 class Context:
     """What a reader may read (see ``benchmark/readers/__init__.py``)."""
 
-    def __init__(self, bench, numbers, counters, slice_counters, cut,
-                 frames):
+    def __init__(self, bench, numbers, outcome):
         self.config = bench.config
+        self.architecture = bench.architecture
         self.workload = bench.workload
         self.peaks = bench.peaks
-        self.counters = counters
-        self.slice_counters = slice_counters
+        self.counters = outcome["counters"]
+        self.slice_counters = outcome["slice_counters"]
         self.client_latencies_ms = numbers["latencies_ms"]
         self.requests = numbers["requests"]
-        self.frames = frames
-        self.cut = cut
+        self.frames = outcome["frames"]
+        self.cut = outcome["cut"]
+        self.window = outcome["window"]
+        self.trace_stamp = outcome["trace_stamp"]
+        self.host = None        # a reader lays the recorder on the cut
         self.notes: dict = {}
         self._registry = bench.registry
         self._values: dict = {}
@@ -153,12 +173,14 @@ class Bench:
 
     def __init__(self, cell_name: str, seed: int, rehearse: bool):
         import jax
-        from benchmark import roofline
+        from benchmark import architectures, roofline
         from benchmark.traffic import seed31
         self.loaded = load_cell(cell_name)
         self.rehearse = rehearse
         self.seed = int(seed)
         self.config = self.loaded["config"]
+        self.architecture = architectures.load(self.config,
+                                               data_directories())
         self.workload = self.loaded["workload"]
         if rehearse:
             self.workload = merged(self.workload,
@@ -174,8 +196,7 @@ class Bench:
         jax.monitoring.register_event_duration_secs_listener(
             self._on_build)
         self._build_pipeline(seed31(seed))
-        self.traffic = None
-        self.reference = None
+        self.traffic = self.reference = None
         self.warmup_rounds = 0
 
     # -- construction ------------------------------------------------------
@@ -195,10 +216,8 @@ class Bench:
         for element in definition["elements"]:
             parameters = element["parameters"]
             if element["name"] == config["llm_element"]:
-                parameters["widths"] = {
-                    key: value for key, value in config.items()
-                    if isinstance(value, (int, float))
-                    and not isinstance(value, bool)}
+                parameters.update(
+                    self.architecture.element_parameters(config))
             if element["name"] in config.get("seeded", ()):
                 parameters["seed"] = seed
             element["parameters"] = {
@@ -349,37 +368,18 @@ class Bench:
                programs_built=len(self.builds))
 
     def check_reference(self):
-        """Served against the plain float32 reference, before any
-        window, with nothing else on the device."""
-        from benchmark import reference
+        """Served against the architecture's plain float32 reference,
+        before any window, with nothing else on the device."""
         spec = self.config["reference"]
-        batcher = self.llm._batcher
         started = time.perf_counter()
         with self.llm._device_lock, self.llm._device_scope():
-            result = reference.compare(
-                batcher.params, batcher.config, self.seed,
-                min(int(spec["prompt_tokens"]), batcher.max_seq // 2),
-                int(spec["decode_steps"]), batcher.kv_page_tokens,
-                batcher.prefill_chunk)
+            result = self.architecture.check_reference(
+                self.llm._batcher, self.seed, spec)
         result["tolerance"] = float(spec["tolerance"])
         result["ok"] = result["max_abs_diff"] <= result["tolerance"]
         result["seconds"] = time.perf_counter() - started
         self.reference = result
         detail(phase="reference", **result)
-
-    def widths_served(self) -> list:
-        """Differences between the served model and the file (none)."""
-        from benchmark.elements import WIDTH_FIELDS
-        served = self.llm._batcher.config
-        wrong = [(key, self.config[key], getattr(served, field))
-                 for key, field in WIDTH_FIELDS.items()
-                 if float(getattr(served, field))
-                 != float(self.config[key])]
-        if served.max_seq != self.config["max_position_embeddings"]:
-            wrong.append(("max_position_embeddings",
-                          self.config["max_position_embeddings"],
-                          served.max_seq))
-        return wrong
 
     # -- one window --------------------------------------------------------------
 
@@ -389,7 +389,7 @@ class Bench:
         from benchmark.traffic import window_numbers
         workload = self.workload
         lead_s = float(workload["lead_s"])
-        trace_dir, cut = None, None
+        trace_dir, cut, trace_stamp = None, None, None
         if trace:
             trace_dir = tempfile.mkdtemp(prefix="benchmark_trace_")
         # The engine's frames sit in reference cycles, so their device
@@ -421,13 +421,14 @@ class Bench:
             # camera frames (a 1 s slice saw 99 % idle with it and 18 %
             # without; PERF.md section 5), and the Python tracer and
             # the HLO protos are most of a 144 MB file: the device
-            # trace alone is taken.  The price: the program's
-            # TraceAnnotations are not in it, so idle gaps go unnamed.
+            # trace alone is taken; the flight recorder names the gaps.
             options = jax.profiler.ProfileOptions()
             options.python_tracer_level = 0
             options.host_tracer_level = 0
             options.enable_hlo_proto = False
+            entered = time.perf_counter()
             jax.profiler.start_trace(trace_dir, profiler_options=options)
+            trace_stamp = (entered, time.perf_counter())
             self.traffic.sleep_until(start_s + seconds)
             slice_counters = self.delta(self.counters(), slice_before)
         else:
@@ -463,8 +464,7 @@ class Bench:
                     size = os.path.getsize(path)
                     cut = trace_reduction.read_xplane(path)
                     detail(phase="trace read", bytes=size,
-                           seconds=time.perf_counter() - read_started,
-                           host_spans=len(cut["host"]))
+                           seconds=time.perf_counter() - read_started)
                     if keep_cut:
                         with open(keep_cut, "w") as stream:
                             json.dump(cut, stream)
@@ -476,7 +476,9 @@ class Bench:
                 "counters": counters, "slice_counters": slice_counters,
                 "round_delta": round_delta, "setup_s": setup_s,
                 "window_builds": window_builds, "cut": cut,
-                "frames": frames,
+                "frames": frames, "trace_stamp": trace_stamp,
+                "window": (self.traffic.epoch + start_s,
+                           self.traffic.epoch + start_s + seconds),
                 "start_s": start_s, "seconds": seconds}
 
     # -- checks -----------------------------------------------------------------
@@ -489,8 +491,12 @@ class Bench:
         verdict = {
             "reference_agrees": bool(self.reference
                                      and self.reference["ok"]),
-            "widths_match_file": self.rehearse
-            or not self.widths_served(),
+            # (a rehearsal that swaps the model serves other widths)
+            "widths_match_file": (
+                self.rehearse and self.config["llm_element"]
+                in self.config.get("rehearse", {}))
+            or not self.architecture.width_differences(
+                self.config, self.llm._batcher),
             "all_answered_once_in_order":
                 len(ok) == len(records) and in_order(records),
             "tokens_match_requests":
@@ -536,7 +542,6 @@ class Bench:
 def measure(bench: Bench, arguments) -> dict:
     """Warm up, run the window, reduce, check; returns the contract
     line's object."""
-    import statistics
     from benchmark import readers, trace as trace_reduction
     from benchmark.traffic import percentile, samples_beyond
     bench.warm_up()
@@ -582,9 +587,7 @@ def measure(bench: Bench, arguments) -> dict:
                     "value": end_to_end[metric["name"]](),
                     "unit": metric["unit"]}
     else:
-        context = Context(bench, numbers, outcome["counters"],
-                          outcome["slice_counters"], outcome["cut"],
-                          outcome["frames"])
+        context = Context(bench, numbers, outcome)
         for metric in bench.loaded["per_layer"]:
             value = context.metric(metric["name"])
             if value is not None:
@@ -598,17 +601,9 @@ def measure(bench: Bench, arguments) -> dict:
             device["busy_s"], device["window_s"] = busy_s, window_s
             result["breakdown"] = {
                 "device_ops": trace_reduction.top_device_ops(cut),
-                "idle_gaps": trace_reduction.idle_gaps(cut)}
-            programs = {}
-            entry = cut["devices"][sorted(cut["devices"])[0]]
-            for name, _, duration in entry["modules"]:
-                programs.setdefault(trace_reduction.program_name(name),
-                                    []).append(duration / 1e6)
-            detail(programs_ms={
-                name: {"runs": len(runs), "sum": sum(runs),
-                       "median": statistics.median(runs)}
-                for name, runs in sorted(
-                    programs.items(), key=lambda kv: -sum(kv[1]))[:12]})
+                "idle_gaps": trace_reduction.idle_gaps(
+                    cut, host=context.host)}
+            detail(programs_ms=trace_reduction.programs_ms(cut))
         if not device.get("busy_s") and not bench.rehearse:
             result["correct"] = False
             detail(problem="no operation ran on the device in the "
@@ -616,11 +611,14 @@ def measure(bench: Bench, arguments) -> dict:
     if bench.rehearse:
         # A walk-through on the CPU: its numbers are not measurements
         # and never appear under a metric's name.
-        result = {"rehearsal": True, "correct": result["correct"],
-                  "attempted": result["attempted"],
-                  "failed": result["failed"],
-                  "metrics_walked": sorted(metrics),
-                  "device": bench.device}
+        kept = {key: result[key] for key in ("correct", "attempted", "failed")}
+        result = {"rehearsal": True, **kept, "device": bench.device,
+                  "metrics_walked": sorted(metrics)}
+    # Each number compared beside its limit, last in the line.
+    result["compared"] = {
+        "reference_max_abs_diff": [bench.reference["max_abs_diff"],
+                                   bench.reference["tolerance"]],
+        **{name: [bool(ok), True] for name, ok in verdict.items()}}
     return result
 
 
@@ -658,9 +656,8 @@ def prepare(arguments) -> Bench:
     platform = devices[0].platform
     if not arguments.rehearse and platform != "tpu":
         print(f"benchmark: needs a TPU; jax found platform={platform!r} "
-              f"({devices[0].device_kind}, {len(devices)} device(s)).  "
-              f"--rehearse walks the control flow at model: tiny off "
-              f"the chip and reports no metric.", file=sys.stderr)
+              f"({devices[0].device_kind}, {len(devices)} device(s)); "
+              f"--rehearse walks a cell off the chip", file=sys.stderr)
         raise SystemExit(2)
     if not arguments.rehearse and len(devices) < int(cell["chips"]):
         print(f"benchmark: {arguments.workload} needs {cell['chips']} "
@@ -684,12 +681,14 @@ def main(argv=None) -> int:
     parser.add_argument("--trace", type=int, choices=(0, 1), default=0)
     parser.add_argument("--rehearse", action="store_true",
                         help="walk the control flow at model: tiny on "
-                             "whatever backend is present; never the "
-                             "default, reports no metric")
+                             "any backend; reports no metric")
+    parser.add_argument("--manifest", default=MANIFEST[0],
+                        help="its data files are looked for beside it")
     parser.add_argument("--keep-cut", default=None,
                         help="also write the reduced trace (JSON) here")
     arguments = parser.parse_args(argv)
     faulthandler.enable()
+    MANIFEST[0] = os.path.abspath(arguments.manifest)
     bench = prepare(arguments)
     limit_s = (sum(bench.limits.values()) + arguments.seconds + 600.0)
     try:
@@ -707,6 +706,7 @@ def main(argv=None) -> int:
     stopper.start()
     stopper.join(timeout=60.0)
     print(json.dumps(result), flush=True)
+    print("compared:", json.dumps(result["compared"]), file=sys.stderr)
     sys.stderr.flush()
     os._exit(0)             # no thread of the program may hold the exit
 

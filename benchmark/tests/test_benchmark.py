@@ -8,7 +8,7 @@ import os
 
 import pytest
 
-from benchmark import readers, roofline, trace, traffic
+from benchmark import architectures, readers, roofline, run, trace, traffic
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -149,10 +149,12 @@ def test_a_reader_kind_is_found_by_file_name():
 
 def test_decode_step_bytes_of_the_published_widths():
     widths = load("benchmark", "configs", "caption-internlm2-1.8b.json")
-    assert roofline.layer_matmul_weights(widths) == 62_914_560
-    assert roofline.matmul_weights(widths) == 1_699_479_552
-    assert roofline.cache_bytes_per_token(widths) == 98_304
-    work = roofline.decode_step(widths, rows=16, context_tokens=100)
+    family = architectures.load(widths, run.data_directories())
+    assert family.__name__ == "benchmark.architectures.llama"
+    assert family.layer_matmul_weights(widths) == 62_914_560
+    assert family.matmul_weights(widths) == 1_699_479_552
+    assert family.cache_bytes_per_token(widths) == 98_304
+    work = family.decode_step(widths, rows=16, context_tokens=100)
     assert work["bytes"] == 1_699_479_552 + 16 * 100 * 98_304
     least_s, bound = roofline.least_seconds(
         work, roofline.peaks_for("TPU v5 lite"))
@@ -183,6 +185,12 @@ def test_reduction_of_the_recorded_cut(cut):
     assert len(decode) == expected["decode_programs"]
     assert len(prefill) == expected["prefill_programs"]
     assert sum(decode) == pytest.approx(expected["decode_s"])
+    # (a program cut off by the slice's end counts here, not above)
+    summary = trace.programs_ms(cut)
+    assert len(decode) <= summary["jit__decode_loop_jit"]["runs"] \
+        <= len(decode) + 1
+    assert list(summary) == sorted(
+        summary, key=lambda name: -summary[name]["sum"])
     ops = trace.top_device_ops(cut)
     assert [name for name, _ in ops[:3]] == expected["top_ops"]
     assert len(ops) == 10 and ops[0][1] >= ops[-1][1]
@@ -213,3 +221,98 @@ def test_gap_is_named_by_the_host_span_that_covers_most_of_it():
     assert trace.idle_gaps(cut) == [["element:DET", 100 / 1e9],
                                     ["no-host-span", 10 / 1e9]]
     assert trace.busy_and_window(cut) == (30 / 1e9, 140 / 1e9)
+
+
+# -- every micro-batch bucket is built by the first group ---------------------------
+
+class _Groups:
+    """What ``_EveryBucket`` stands on: an element's micro-batch calls."""
+
+    name = "R0"
+
+    def __init__(self):
+        self.ran, self.finished = [], []
+
+    def get_parameter(self, name, default=None):
+        return {"max_batch": 8}.get(name, default), True
+
+    def batch_run(self, context, key, payloads):
+        self.ran.append((key, list(payloads)))
+        return len(payloads)
+
+    def batch_finish(self, context, key, entries, result):
+        self.finished.append(result)
+        for complete, payload in entries:
+            complete("okay", {"payload": payload})
+
+
+def test_the_first_group_of_a_key_runs_every_bucket_and_later_ones_only_themselves():
+    from benchmark.elements import _EveryBucket
+
+    class Element(_EveryBucket, _Groups):
+        pass
+
+    element = Element()
+    assert element.batch_run(None, "720p", ["a", "b", "c"]) == 3
+    assert [len(group) for _, group in element.ran] == [1, 2, 4, 8, 3]
+    assert all(set(group) == {"a"} for _, group in element.ran[:4])
+    assert element.finished == [1, 2, 4, 8]     # the caller finishes its own
+    assert element.batch_run(None, "720p", ["d"]) == 1
+    assert [len(group) for _, group in element.ran[5:]] == [1]
+    element.batch_run(None, "1080p", ["e"])
+    assert [len(group) for _, group in element.ran[6:]] == [1, 2, 4, 8, 1]
+
+
+def test_a_frame_says_which_bucket_it_rode_in():
+    from benchmark.elements import _EveryBucket
+
+    class Element(_EveryBucket, _Groups):
+        pass
+
+    got = []
+    Element().batch_finish(
+        None, "720p", [(lambda *a: got.append(a), p) for p in "abc"], 3)
+    assert got == [("okay", {"payload": p, "R0_group": 4}) for p in "abc"]
+
+
+def test_the_same_answer_is_asked_per_bucket():
+    """The detector rounds otherwise in a bucket of 4 than in one of 1
+    (my chip run, PR 28), so two answers to one frame are a fault only
+    where both rode in the same buckets."""
+    spec = load("benchmark", "workloads", "camera-paced.json")
+    assert spec["same_answer"]["key"] == ["camera", "frame", "R0_group",
+                                          "DET_group"]
+    assert set(spec["same_answer"]["key"]) <= set(spec["keep"])
+    definition = load("benchmark", "configs",
+                      "caption-internlm2-1.8b.json")["definition"]
+    outputs = {e["name"]: [o["name"] for o in e["output"]]
+               for e in definition["elements"]}
+    assert "R0_group" in outputs["R0"] and "DET_group" in outputs["DET"]
+
+
+def _checked(answers):
+    """``Bench.checks`` over ok records of one camera and frame, given
+    ``(DET_group, detections)`` pairs; its verdict."""
+    bench = object.__new__(run.Bench)
+    bench.workload = load("benchmark", "workloads", "camera-paced.json")
+    bench.reference = {"ok": True}
+    bench.rehearse = True
+    bench.config = {"llm_element": "LLM", "rehearse": {"LLM": {}}}
+    records = [
+        {"session": 3, "index": 8 * n, "frame": n, "answers": 1,
+         "position": n, "status": "ok",
+         "data": {"camera": 3, "frame": 8 * n, "text": "a cat",
+                  "R0_group": 1, "DET_group": group,
+                  "detections": detections}}
+        for n, (group, detections) in enumerate(answers)]
+    outcome = {"records": records, "window_builds": [], "round_delta": {
+        "batcher.tokens_emitted": 24 * len(records),
+        "engine.implicit_transfers": 0, "engine.fused_broken": 0}}
+    return bench.checks(outcome)
+
+
+def test_two_answers_to_one_frame_are_a_fault_only_within_one_bucket():
+    assert all(_checked([(1, ["cat"]), (1, ["cat"]), (4, ["dog"])]).values())
+    verdict = _checked([(1, ["cat"]), (4, ["dog"]), (4, ["cat"])])
+    assert not verdict.pop("same_input_same_answer")
+    assert all(verdict.values())

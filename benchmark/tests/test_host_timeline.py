@@ -20,6 +20,9 @@ LIMITS = {"bounds": (float("-inf"), float("inf")),
 SLICE_S = 2.5               # the harness starts the trace this long
 #                             before the window ends
 WINDOW_END_S = OFFSET_S + SLICE_S - 0.003   # ... and 3 ms late
+# The harness's stamps around ``start_trace``: entered 1.2 ms before the
+# profiler session began (the trace's zero), returned 50 ms after.
+STAMP = (OFFSET_S - 0.0012, OFFSET_S + 0.0500)
 
 
 def _blocks(count, seed=3):
@@ -181,9 +184,10 @@ def _scene():
 class _Context:
     """What ``benchmark.run.Context`` offers these readers."""
 
-    def __init__(self, cut, frames=None):
-        self.cut, self.frames, self.notes = cut, frames or {}, {}
-        self.workload = {"trace_slice_s": SLICE_S}
+    def __init__(self, cut, stamp=STAMP):
+        self.cut, self.trace_stamp, self.notes = cut, stamp, {}
+        self.window = (WINDOW_END_S - 30.0, WINDOW_END_S)
+        self.host = None
         self._values: dict = {}
 
     def metric(self, name):
@@ -195,40 +199,24 @@ class _Context:
         return self._values[name]
 
 
-def _frames(ring, last_s, count=100, gap_s=0.052):
-    """``count`` frames whose results came ``gap_s`` apart, the last at
-    ``last_s`` on the perf_counter clock, with the wall stamps the
-    engine's trace buffer would give them."""
-    perf_ns, wall_ns = ring.clock()
-    to_wall = (wall_ns - perf_ns) / 1e9
-    frames = {index: {"finished": last_s - gap_s * index + to_wall}
-              for index in range(count)}
-    frames["unfinished"] = {"finished": None}
-    return frames
-
-
-LAST_RESULT_S = WINDOW_END_S - 0.01     # the window's last result
-# The ring's own ``done`` events of that frame and of the next, which
-# finished 20 ms after the window ended.
-RESULTS = [["done", "s", LAST_RESULT_S + 0.0004, 450.0],
-           ["done", "s", WINDOW_END_S + 0.02, 450.0]]
-
-
 def test_idle_by_host_span_names_the_gaps(monkeypatch):
     cut, events = _scene()
-    ring = _ring(events + RESULTS)
+    ring = _ring(events)
     monkeypatch.setattr(host_timeline, "live_recorder", lambda: ring)
     before = trace.idle_gaps(cut)
     assert {name for name, _ in before} == {trace.NO_HOST_SPAN}
-    ctx = _Context(cut, _frames(ring, LAST_RESULT_S))
+    ctx = _Context(cut)
     share = ctx.metric("device.idle_host_bound_share")
     notes = ctx.notes["host_timeline"]
-    assert notes["pairs"] == 32 and notes["spread5_ms"] < 0.5
+    # The slice's first block has no past in the cut: 23 dispatch
+    # returns and 8 fetch returns line up.
+    assert (notes["runs"], notes["runs_after_idle"]) == (24, 23)
+    assert notes["pairs"] == 31 and notes["spread5_ms"] < 0.5
     assert notes["offset_s"] == pytest.approx(OFFSET_S, abs=0.0003)
-    # The bracket: one gap between results (30 ms) and the margins,
-    # the offset inside it.
+    # The bracket: from the stamp before ``start_trace`` to the margin
+    # after it (the call returns later than that), the offset inside.
     low, high = notes["bounds_s"]
-    assert high - low == pytest.approx(0.03 + 0.005 + 0.015, abs=0.001)
+    assert low == STAMP[0] and high == pytest.approx(low + 0.015)
     assert low < notes["offset_s"] < high
     # 24 gaps of 2 ms: 1 ms of each under ``prefill``, the other under
     # ``dispatch`` -- but in 12 of them ``mb_run:R0`` starts 1.5 ms
@@ -242,13 +230,14 @@ def test_idle_by_host_span_names_the_gaps(monkeypatch):
     assert by_span["mb_run:R0"] == pytest.approx(6.0, abs=3.0)
     assert not any(name.startswith("resume:") for name in by_span)
     assert notes["idle_named_share"] > 0.95
-    # The cut's host spans are filled, sorted, on the trace's clock: the
-    # harness's own reduction now names every gap.
-    starts = [start for _, start, _ in ctx.cut["host"]]
+    # The host spans are left beside the cut (not in it), sorted, on the
+    # trace's clock: with them the harness's reduction names every gap.
+    assert ctx.cut["host"] == []
+    starts = [start for _, start, _ in ctx.host]
     assert starts == sorted(starts) and starts[0] >= 0
     assert all(isinstance(value, int) for _, start, duration
-               in ctx.cut["host"] for value in (start, duration))
-    named = trace.idle_gaps(ctx.cut)
+               in ctx.host for value in (start, duration))
+    named = trace.idle_gaps(ctx.cut, host=ctx.host)
     assert len(named) == 10
     assert trace.NO_HOST_SPAN not in {name for name, _ in named}
     assert {name for name, _ in named} <= {
@@ -260,22 +249,22 @@ def test_idle_by_host_span_names_the_gaps(monkeypatch):
     assert ctx.notes["idle_under_llm_tick"]["runs"] == 24
 
 
-def test_only_offsets_the_windows_end_allows_are_looked_at(monkeypatch):
-    """The window's end is the coarse anchor: the same cadence of
-    returns one tick and ten seconds earlier -- rivals as good as the
-    truth -- is never paired, because the results around the window's
-    end say where the trace began."""
+def test_only_offsets_the_stamp_allows_are_looked_at(monkeypatch):
+    """The stamp around ``start_trace`` is the coarse anchor: the same
+    cadence of returns one tick and ten seconds earlier -- rivals as
+    good as the truth -- is never paired."""
     cut, events = _scene()
     ticks = [event for event in events if event[0] == "llm_tick"]
     rivals = [[etype, name, end - shift, ms] for shift in (0.25, 10.0)
               for etype, name, end, ms in ticks]
-    ring = _ring(events + rivals + RESULTS)
+    ring = _ring(events + rivals)
     monkeypatch.setattr(host_timeline, "live_recorder", lambda: ring)
-    ctx = _Context(cut, _frames(ring, LAST_RESULT_S))
+    ctx = _Context(cut)
     assert ctx.metric("device.idle_host_bound_share") is not None
     notes = ctx.notes["host_timeline"]
     assert notes["offset_s"] == pytest.approx(OFFSET_S, abs=0.0003)
-    assert 32 <= notes["pairs"] <= 34
+    # (a rival's return may chance on another block's edge)
+    assert 31 <= notes["pairs"] <= 33
     assert notes["runner_up_pairs"] <= 0.25 * notes["pairs"]
     # Without the bracket the three cannot be told apart.
     starts = [start / 1e9 for _, start, _ in
@@ -286,61 +275,132 @@ def test_only_offsets_the_windows_end_allows_are_looked_at(monkeypatch):
         [(starts, returns)], **LIMITS)["offset_s"] is None
 
 
-@pytest.mark.parametrize("case", ["frames", "phases"])
-def test_an_offset_that_disagrees_with_the_results_is_not_found(
+@pytest.mark.parametrize("case", ["stamp", "phases"])
+def test_an_offset_that_disagrees_with_the_stamp_is_not_found(
         monkeypatch, case):
     cut, events = _scene()
-    if case == "frames":        # the window ended 5 s after this slice
-        ring = _ring(events + RESULTS)
-        frames = _frames(ring, LAST_RESULT_S + 5.0)
+    stamp = STAMP
+    if case == "stamp":         # the trace began 5 s after these stamps
+        stamp = (STAMP[0] - 5.0, STAMP[1] - 5.0)
     else:                       # the worker's phases, 0.31 s out of step
-        ring = _ring([[etype, name, end - 0.3137, ms]
-                      for etype, name, end, ms in events
-                      if etype == "llm_tick"] + RESULTS)
-        frames = _frames(ring, LAST_RESULT_S)
+        events = [[etype, name, end - 0.3137, ms]
+                  for etype, name, end, ms in events
+                  if etype == "llm_tick"]
+    ring = _ring(events)
     monkeypatch.setattr(host_timeline, "live_recorder", lambda: ring)
-    ctx = _Context(cut, frames)
+    ctx = _Context(cut, stamp)
     assert ctx.metric("device.idle_host_bound_share") is None
-    # Too few chance pairs in the bracket, or too loose a handful.
-    assert ctx.notes["host_timeline"]["refused"]
-    assert ctx.cut["host"] == []
+    # No pair at all inside a bracket of a few ms.
+    assert "fewer than 5" in ctx.notes["host_timeline"]["refused"]
+    assert ctx.host is None
     assert ctx.metric("batcher.host_ms_per_block") is None
 
 
-def test_the_windows_end_lies_between_two_results():
-    ring = FlightRecorder(capacity=64)
-    frames = _frames(ring, 500.0, count=10, gap_s=0.1)
-    clock = ring.clock()
-    # The ring's ``done`` of the last frame itself does not count; the
-    # next one after it does.
-    intervals = [["done:s", 499.5, 0.5004], ["done:s", 499.6, 0.47],
-                 ["llm_tick:demux", 500.01, 0.001]]
-    window = host_timeline.window_of_frames(frames, clock, intervals)
-    assert window["first"] == pytest.approx(499.1, abs=1e-4)
-    assert window["last"] == pytest.approx(500.0, abs=1e-4)
-    assert window["end_by"] == pytest.approx(500.07)
-    # No later result in the ring: the longest gap stands in.
-    window = host_timeline.window_of_frames(frames, clock, intervals[:1])
-    assert window["end_by"] == pytest.approx(500.1, abs=1e-4)
-    assert host_timeline.window_of_frames({}, clock, intervals) is None
+def test_program_runs_say_how_long_the_chip_had_been_idle():
+    modules = [["jit__prefill_into_slot_jit(7)", 1_000_000, 2_000_000],
+               ["jit__decode_loop_jit(1)", 3_004_000, 40_000_000],
+               ["jit__lambda(3)", 43_500_000, 900_000],
+               ["jit__decode_loop_jit(1)", 45_600_000, 40_000_000],
+               ["jit__decode_loop_jit(1)", 85_600_000, 40_000_000]]
+    assert host_timeline.program_runs(modules[::-1], "decode_loop") == [
+        (0.003004, 0.043004, pytest.approx(4e-6)),
+        (0.0456, 0.0856, pytest.approx(0.0012)), (0.0856, 0.1256, 0.0)]
+    # The slice's first program has no past in the cut.
+    assert host_timeline.program_runs(modules[1:], "decode_loop")[0][2] == 0
+
+
+def test_blocks_that_start_back_to_back_are_not_paired(monkeypatch):
+    """Where the worker is ahead of the chip its ``dispatch`` returns
+    before the block starts (here up to 0.8 ms before, for a third of
+    the blocks, which start as the program before them ends): paired
+    with every block the floor is no longer the offset and the five
+    smallest differences are too far apart; paired with the blocks the
+    chip idled before, the offset is found."""
+    cut, events = _scene()
+    entry = cut["devices"]["/device:TPU:0"]
+    ahead = set(range(2, 24, 3))
+    for index in ahead:         # a prefill chunk right up to the block
+        start = entry["modules"][index][1]
+        entry["modules"].append(["jit__prefill_into_slot_jit(7)",
+                                 start - 12_000_000, 11_996_000])
+        entry["ops"].append(["fusion.9", start - 12_000_000, 11_996_000])
+    dispatches = [event for event in events if event[1] == "dispatch"]
+    for index in ahead:         # 7 returns before the slice's first block
+        dispatches[7 + index][2] -= 0.0001 * (1 + index % 8)
+    ring = _ring(events)
+    monkeypatch.setattr(host_timeline, "live_recorder", lambda: ring)
+    ctx = _Context(cut)
+    assert ctx.metric("device.idle_host_bound_share") is not None
+    notes = ctx.notes["host_timeline"]
+    assert (notes["runs"], notes["runs_after_idle"]) == (24, 15)
+    assert notes["offset_s"] == pytest.approx(OFFSET_S, abs=0.0003)
+    everything = [start / 1e9 for name, start, _ in entry["modules"]
+                  if "decode_loop" in name]
+    report = host_timeline.align(
+        [(everything, [event[2] for event in dispatches])],
+        **{**LIMITS, "bounds": STAMP})
+    assert report["offset_s"] is None or \
+        report["offset_s"] < OFFSET_S - 0.0003
+
+
+def test_the_offset_is_found_in_a_recorded_slice_with_blocks_back_to_back():
+    """``camera-paced`` after PR 27 (``benchmark/testdata/
+    camera_paced_alignment.json.gz``: the slice's programs, the LLM
+    worker's phases around it and the stamps around ``start_trace``,
+    kept from a chip run of PR 28): 5 of the slice's 12 blocks start
+    within 0.4 ms of the program before them, and the ``dispatch`` of
+    three of those returned 0.07-0.55 ms BEFORE its block started.
+    Paired with every block the five smallest differences span
+    0.58 ms and the alignment refuses, as three traced runs in five did
+    (PERF.md section 6); paired with the blocks the chip idled before,
+    the offset is found, 0.9 ms after ``start_trace`` was entered."""
+    import gzip
+    import json
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "testdata",
+        "camera_paced_alignment.json.gz")
+    with gzip.open(path, "rt") as stream:
+        recorded = json.load(stream)
+    entered, returned = recorded["trace_stamp"]
+    assert 0.040 < returned - entered < 0.060
+    limits = {**LIMITS,
+              "bounds": (entered, entered + ARGS["anchor_margin_s"])}
+    runs = host_timeline.program_runs(recorded["modules"], ARGS["program"])
+    idle_ms = sorted(idle * 1000.0 for _, _, idle in runs)
+    assert len(runs) == 12
+    assert idle_ms[4] < 0.34 and idle_ms[5] > 0.99      # two kinds of block
+    report = host_timeline.align(host_timeline.sync_pairs(
+        runs, recorded["intervals"], ARGS["sync"], ARGS["idle_before_ms"]),
+        **limits)
+    assert "refused" not in report
+    assert report["offset_s"] == pytest.approx(
+        recorded["expected"]["offset_s"], abs=1e-6)   # stamps kept to 0.1 us
+    assert 0.0007 < report["offset_s"] - entered < 0.0021
+    assert report["pairs"] == 7 and report["spread5_ms"] < 0.2
+    assert report["runner_up_pairs"] == 0
+    everything = host_timeline.align(host_timeline.sync_pairs(
+        runs, recorded["intervals"], ARGS["sync"], 0.0), **limits)
+    assert everything["offset_s"] is None
+    assert "over 0.5" in everything["refused"]
 
 
 @pytest.mark.parametrize("case", ["wrapped", "no-cut", "no-ring",
-                                  "no-frames"])
+                                  "no-stamp"])
 def test_idle_by_host_span_returns_nothing_and_says_why(monkeypatch, case):
     cut, events = _scene()
-    ring = _ring(events + RESULTS)
+    ring = _ring(events)
     if case == "wrapped":       # a full ring whose oldest event is inside
-        ring = _ring(events + RESULTS, capacity=64)
+        ring = _ring(events, capacity=64)
     monkeypatch.setattr(host_timeline, "live_recorder",
                         lambda: None if case == "no-ring" else ring)
-    frames = {} if case == "no-frames" else _frames(ring, LAST_RESULT_S)
-    ctx = _Context(None if case == "no-cut" else cut, frames)
+    ctx = _Context(None if case == "no-cut" else cut,
+                   None if case == "no-stamp" else STAMP)
     assert ctx.metric("device.idle_host_bound_share") is None
     assert ctx.metric("batcher.host_ms_per_block") is None
+    assert ctx.host is None
     if case == "wrapped":
         assert "wrapped" in ctx.notes["host_timeline"]["refused"]
-        assert ctx.cut["host"] == []
     else:
         assert "host_timeline" not in ctx.notes
 

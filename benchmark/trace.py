@@ -169,9 +169,10 @@ def _covering_span(host, lo, hi):
     return best
 
 
-def idle_gaps(cut: dict, count: int = 10) -> list:
+def idle_gaps(cut: dict, count: int = 10, host=None) -> list:
     """[host span, seconds] of the longest stretches in which no op
-    ran on the first device, each named by the host annotation that
+    ran on the first device, each named by the host span (of ``host``,
+    sorted and on the cut's clock; by default the cut's own) that
     covers most of it."""
     lo, hi = window_of(cut)
     entry = cut["devices"][sorted(cut["devices"])[0]]
@@ -180,7 +181,8 @@ def idle_gaps(cut: dict, count: int = 10) -> list:
     gaps = [(edges[i + 1] - edges[i], edges[i], edges[i + 1])
             for i in range(0, len(edges), 2) if edges[i + 1] > edges[i]]
     gaps.sort(reverse=True)
-    return [[_covering_span(cut["host"], start, end), length / 1e9]
+    host = cut["host"] if host is None else host
+    return [[_covering_span(host, start, end), length / 1e9]
             for length, start, end in gaps[:count]]
 
 
@@ -192,6 +194,20 @@ def program_durations(cut: dict, contains: str) -> list[float]:
     return [duration / 1e9 for name, start, duration in entry["modules"]
             if contains in program_name(name)
             and start >= lo and start + duration <= hi]
+
+
+def programs_ms(cut: dict, count: int = 12) -> dict:
+    """Runs, summed and median device ms of the ``count`` programs that
+    took most of the first device's time."""
+    import statistics
+    programs: dict = {}
+    entry = cut["devices"][sorted(cut["devices"])[0]]
+    for name, _, duration in entry["modules"]:
+        programs.setdefault(program_name(name), []).append(duration / 1e6)
+    return {name: {"runs": len(runs), "sum": sum(runs),
+                   "median": statistics.median(runs)}
+            for name, runs in sorted(
+                programs.items(), key=lambda kv: -sum(kv[1]))[:count]}
 
 
 def op_seconds(cut: dict, prefix: str) -> float:
