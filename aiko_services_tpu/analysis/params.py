@@ -343,6 +343,15 @@ ELEMENT_PARAMETERS: dict[tuple[str, str], dict[str, ParamSpec]] = {
             "structure and extent threshold",
             choices=("auto", "paged-kernel", "dense-flash",
                      "reference")),
+        # -- model family (ISSUE 29) ----------------------------------
+        "family": ParamSpec(
+            "model family the element builds from ``widths`` "
+            "(models/families.py); absent = the ``model`` presets",
+            choices=("llama", "deepseek_v3")),
+        "widths": ParamSpec(
+            "published config.json keys of the family (a key it lacks "
+            "is refused; one left out keeps the family's default)",
+            kind="json"),
         "sample_top_k": ParamSpec(
             "restrict sampled rows to the k highest logits via the "
             "ops top-k interface (0 = full-vocab categorical; the "
@@ -467,4 +476,13 @@ def validate_element_parameters(class_name: str, parameters: dict,
                                f"{where}.parameters.{name}")
         if finding is not None:
             findings.append(finding)
+    if "family" in registry and not findings:
+        # The family / widths pair and what the family refuses beside
+        # it (quantize: int8, spec_*, ... with the latent family): the
+        # jax-free twin of the element's own check at model build.
+        from ..models.families import family_spec_error
+        problem = family_spec_error(parameters or {})
+        if problem is not None:
+            findings.append(Finding("bad-parameter", problem,
+                                    f"{where}.parameters"))
     return findings

@@ -39,9 +39,10 @@ import time
 
 import jax
 
-from ..models import llama
+from ..models import deepseek, llama
 from ..models.batching import ContinuousBatcher, Request
 from ..models.checkpoint import maybe_restore as _restore
+from ..models.families import family_spec_error
 from ..models.paged import is_paged
 from ..models.tokenizer import ByteTokenizer, load_tokenizer
 from ..pipeline import PipelineElement, StreamEvent
@@ -229,6 +230,25 @@ class LLM(PipelineElement):
     batcher re-prefills prompt + committed tokens and generation
     continues -- nothing already streamed is re-emitted.
 
+    MODEL FAMILIES: ``model`` picks a preset of the Llama family
+    (``tiny`` | ``tiny-moe`` | ``llama3-1b`` | ``llama3-8b``);
+    ``family`` (``llama`` | ``deepseek_v3``) + ``widths`` (the family's
+    published ``config.json`` keys -> numbers, models/families.py)
+    build any config of either family -- a key the family lacks is
+    refused, one left out keeps its default.  ``deepseek_v3``
+    (models/deepseek.py) is latent attention over a paged latent pool,
+    a leading dense layer and drop-less routed experts, served by the
+    same batcher; ``attention: flash`` and ``decode_kernel`` choose its
+    kernels as they do the Llama family's.  What it does not serve is
+    REFUSED when the model is built (and by the create-time parameter
+    check), each by its parameter's name: ``quantize: int8``,
+    ``speculative`` / ``spec_tokens`` / ``spec_window``,
+    ``prefix_cache: on``, ``decode_block`` > 1, ``kv_page_tokens: 0``,
+    ``model``, a ``placement`` of more than one chip.  Its retired
+    decode blocks also observe ``llm_moe_experts_touched`` and
+    ``llm_moe_load_imbalance`` into the telemetry registry (and the
+    recorder's ``llm_tick:demux`` info).
+
     ASYNC by default: each frame parks and its request hops to the
     element's device WORKER THREAD, which owns the model and the shared
     :class:`ContinuousBatcher` -- model build (weight init plus the
@@ -293,7 +313,8 @@ class LLM(PipelineElement):
     # parameter precedence reads the pipeline's current-stream context,
     # which only the loop thread maintains) and shipped to the worker.
     _MODEL_PARAMS = ("checkpoint", "tokenizer", "vocab_size", "max_seq",
-                     "seed", "attention", "model", "quantize",
+                     "seed", "attention", "model", "family", "widths",
+                     "quantize",
                      "decode_block", "inflight", "max_slots",
                      "decode_block_tokens", "speculative", "spec_tokens",
                      "spec_window", "kv_page_tokens", "kv_pages",
@@ -325,6 +346,20 @@ class LLM(PipelineElement):
         self._tokenizer = load_tokenizer(tokenizer_path) \
             if tokenizer_path else ByteTokenizer()
         vocab = settings.get("vocab_size")
+        # ``family`` + ``widths`` build any config of either family
+        # (models/families.py: the same check as at create time).
+        problem = family_spec_error(settings)
+        if problem is not None:
+            raise ValueError(problem)
+        if settings.get("family") is not None:
+            family = str(settings["family"]).strip().lower()
+            if family == "deepseek_v3":
+                return self._ensure_latent_model(settings)
+            base = llama.LlamaConfig.from_widths(
+                settings.get("widths") or {})
+            if vocab is not None:
+                base = dataclasses.replace(base, vocab_size=int(vocab))
+            return self._ensure_llama_model(settings, base)
         # "flash" routes chunked admission through the Pallas kernel --
         # the long-context setting (2.5x dense at 8k on v5e).
         model = settings.get("model", "tiny")
@@ -343,6 +378,12 @@ class LLM(PipelineElement):
         elif str(model).startswith("tiny"):
             base = dataclasses.replace(
                 base, vocab_size=self._tokenizer.vocab_size)
+        self._ensure_llama_model(settings, base)
+
+    def _ensure_llama_model(self, settings: dict,
+                            base: llama.LlamaConfig):
+        """Build the Llama family's model of ``base`` widths and the
+        batcher that serves it."""
         config = dataclasses.replace(
             base, max_seq=int(settings.get("max_seq", 256)),
             attention=str(settings.get("attention", "dense")))
@@ -419,6 +460,41 @@ class LLM(PipelineElement):
         # docstring).  The pipeline TransferLedger counts the one
         # explicit host fetch each retired device-loop block pays;
         # the chaos probe arms the ``decode_block`` injection point.
+        self._build_batcher(params, config, settings, cache_put)
+
+    def _ensure_latent_model(self, settings: dict):
+        """The deepseek_v3 family (latent attention, routed experts;
+        models/deepseek.py) of the published ``widths``: bfloat16
+        weights and latent page pool on ONE chip.  What the family
+        cannot serve was refused above, by the parameter's name."""
+        plan = self._stage_plan()
+        if plan is not None and plan.mesh.size > 1:
+            raise ValueError(
+                f"placement={dict(plan.mesh.shape)}: the deepseek_v3 "
+                f"family has no partition specs for its latent pool or "
+                f"its experts; place it on one chip")
+        decode_kernel = str(settings.get("decode_kernel",
+                                         "auto")).strip().lower()
+        kernel_to_attention = {"auto": "auto", "paged-kernel": "flash",
+                               "reference": "dense"}
+        if decode_kernel not in kernel_to_attention:
+            raise ValueError(
+                f"decode_kernel={decode_kernel!r}: with the deepseek_v3 "
+                f"family one of {'|'.join(sorted(kernel_to_attention))}")
+        fields = {"max_seq": int(settings.get("max_seq", 256)),
+                  "attention": str(settings.get("attention", "dense")),
+                  "decode_attention": kernel_to_attention[decode_kernel]}
+        if settings.get("vocab_size") is not None:
+            fields["vocab_size"] = int(settings["vocab_size"])
+        config = deepseek.DeepseekConfig.from_widths(
+            settings.get("widths") or {}, **fields)
+        params = _restore(
+            deepseek.init_params(
+                jax.random.PRNGKey(int(settings.get("seed", 0))), config),
+            settings.get("checkpoint"))
+        self._build_batcher(params, config, settings, None)
+
+    def _build_batcher(self, params, config, settings: dict, cache_put):
         ledger = self._ledger()
         kv_pages = settings.get("kv_pages")
         self._batcher = ContinuousBatcher(
@@ -735,6 +811,7 @@ class LLM(PipelineElement):
         marshal onto the event loop)."""
         telemetry = getattr(self.pipeline, "telemetry", None)
         stats = batcher.take_request_stats()
+        block_stats = batcher.take_block_stats()
         if telemetry is not None:
             for entry in stats:
                 # Tenant/class labels (ISSUE 19): the per-tenant SLO
@@ -757,6 +834,15 @@ class LLM(PipelineElement):
                     telemetry.registry.observe("llm_tpot_ms",
                                                entry["tpot_ms"],
                                                **labels)
+            for observed in block_stats:
+                # What the latent family counted in a retired block
+                # (models/deepseek.py:loop_stats).
+                telemetry.registry.observe(
+                    "llm_moe_experts_touched",
+                    observed["moe_experts_touched"])
+                telemetry.registry.observe(
+                    "llm_moe_load_imbalance",
+                    observed["moe_load_imbalance"])
         changed = False
         hits = batcher.prefix_hits
         lookups = batcher.prefix_lookups

@@ -68,7 +68,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import llama
+from . import deepseek, llama
 from .paged import PageAllocator, init_paged_cache, pages_per_slot
 from .quant import draft_params
 from ..utils.misc import next_power_of_two
@@ -153,6 +153,22 @@ _select_tokens = jax.jit(llama.select_tokens,
                          static_argnames=("top_k",))
 
 
+def model_family(config):
+    """The model family that serves ``config``, chosen by its type: the
+    module whose functions the batcher calls (``init_cache``,
+    ``cache_array``, ``prefill_into_slot(s)``, ``decode_step``,
+    ``decode_block``, ``decode_loop``, ``check_serving``,
+    ``_matmul_safe_config``; sampling is shared).  A family may also
+    say ``ADMISSION_LOGITS_AT_LAST`` (its admission computes the logits
+    of one chunk position, which the batcher then names), leave out
+    ``prefill_into_slots`` (admission stays one slot a program) and
+    return block statistics after ``decode_loop``'s twelve results,
+    which its ``loop_stats`` turns into what the LLM element observes."""
+    if isinstance(config, deepseek.DeepseekConfig):
+        return deepseek
+    return llama
+
+
 def _prefetch(tree) -> None:
     """Start the device-to-host copies of a dispatched block's result
     tree, so they overlap newer blocks' compute and the retire's ONE
@@ -195,7 +211,8 @@ class _LoopBlock:
 
 
 class ContinuousBatcher:
-    def __init__(self, params, config: llama.LlamaConfig,
+    def __init__(self, params,
+                 config: llama.LlamaConfig | deepseek.DeepseekConfig,
                  max_slots: int = 8, max_seq: int | None = None,
                  prefill_chunk: int = 512, rng_seed: int = 0,
                  decode_block: int = 1, inflight: int = 2,
@@ -212,10 +229,11 @@ class ContinuousBatcher:
                  prefix_min_tokens: int = 64,
                  spec_autoprobe: bool | str = True):
         self.params = params
+        self._family = family = model_family(config)
         # A pre-sharded (TP/fsdp) quantized tree must keep XLA's
         # matmul path -- resolved here, where the concrete leaves'
         # sharding is visible (llama._matmul_safe_config).
-        self.config = llama._matmul_safe_config(config, params)
+        self.config = family._matmul_safe_config(config, params)
         self.max_slots = max_slots
         self.max_seq = max_seq or config.max_seq
         self.prefill_chunk = min(prefill_chunk, self.max_seq)
@@ -285,6 +303,13 @@ class ContinuousBatcher:
                 f"sample_top_k={self.sample_top_k}: the on-TPU top-k "
                 f"kernel holds candidates in one 128-lane tile; use "
                 f"k <= 128 (0 = full-vocab categorical)")
+        # What the config's family cannot serve is refused here, by the
+        # parameter's name; nothing falls back silently.
+        family.check_serving(
+            speculative=self.speculative,
+            prefix_cache=_knob_on(prefix_cache, default=False),
+            kv_page_tokens=max(0, int(kv_page_tokens)),
+            decode_block=1 if self.device_loop else self.decode_block)
         self._draft = draft_params(params) \
             if self.speculative == "draft" else None
         # Paged KV cache (models/paged.py): fixed-size pages + per-slot
@@ -311,12 +336,12 @@ class ContinuousBatcher:
             self.cache = init_paged_cache(
                 config, max_slots, self.max_seq, self.kv_page_tokens,
                 kv_pages)
-            pool = llama.cache_array(self.cache).shape[1]
+            pool = family.cache_array(self.cache).shape[1]
             self._pages = PageAllocator(
                 pool, pps, max_slots, prefix_cache=self.prefix_cache,
                 prefix_min_tokens=self.prefix_min_tokens)
         else:
-            self.cache = llama.init_cache(config, max_slots, self.max_seq)
+            self.cache = family.init_cache(config, max_slots, self.max_seq)
         # Multichip serving: ``cache_put`` places the initial KV cache
         # onto the serving mesh (e.g. ``lambda c: plan.put(c,
         # llama.cache_specs(config))`` for TP-sharded kv heads) --
@@ -396,8 +421,11 @@ class ContinuousBatcher:
         # skipped because their pages were adopted from the index.
         self.prefix_shared_tokens = 0
         # per-request latency stamps drained by the serving element
-        # into the telemetry plane (llm_ttft_ms / llm_tpot_ms).
+        # into the telemetry plane (llm_ttft_ms / llm_tpot_ms), and the
+        # family's per-block observations (``loop_stats``), drained
+        # the same way.
         self._request_stats: list[dict] = []
+        self._block_stats: list[dict] = []
         # ``speculative: auto``: measure, then commit to draft or off.
         if self.speculative == "auto":
             self.spec_probe_ratio = self._spec_probe()
@@ -494,6 +522,7 @@ class ContinuousBatcher:
         of chunks written."""
         pipelined = self.decode_block > 1 or self.device_loop
         if (pipelined and len(self._prefilling) > 1
+                and hasattr(self._family, "prefill_into_slots")
                 and self.config.attention != "flash"):
             return self._prefill_tick_batched()
         budget = len(self._prefilling) if pipelined \
@@ -513,9 +542,10 @@ class ContinuousBatcher:
             self._sync_page_table()
             padded = np.zeros((1, self.prefill_chunk), dtype=np.int32)
             padded[0, :len(chunk_tokens)] = chunk_tokens
-            logits, self.cache = llama.prefill_into_slot(
+            logits, self.cache = self._family.prefill_into_slot(
                 self.params, self.config, jnp.asarray(padded),
-                self.cache, jnp.int32(slot), jnp.int32(start))
+                self.cache, jnp.int32(slot), jnp.int32(start),
+                *self._admission_last(len(chunk_tokens)))
             self._admission_advance(slot, request, start,
                                     len(chunk_tokens), logits)
             chunks += 1
@@ -562,7 +592,7 @@ class ContinuousBatcher:
             slot_rows[i] = slot
             starts[i] = start
             metas.append((slot, request, start, len(chunk_tokens)))
-        logits, self.cache = llama.prefill_into_slots(
+        logits, self.cache = self._family.prefill_into_slots(
             self.params, self.config, jnp.asarray(tokens), self.cache,
             jnp.asarray(slot_rows), jnp.asarray(starts))
         for i, (slot, request, start, chunk_len) in enumerate(metas[:n]):
@@ -609,7 +639,9 @@ class ContinuousBatcher:
         if request.prefill_pos < len(prompt):
             self._prefilling.append(slot)       # more chunks to go
             return
-        last = len(prompt) - start - 1
+        # (a family that computes the sampled position alone hands
+        # back one position)
+        last = min(len(prompt) - start - 1, logits.shape[1] - 1)
         first = self._sample(logits[:, last, :], request.temperature)
         self.lengths[slot] = len(prompt)
         self._lengths_upper[slot] = len(prompt)
@@ -639,6 +671,13 @@ class ContinuousBatcher:
                     top_k=self.sample_top_k)
             return llama.temperature_sample(sub, logits, temperature)
         return llama.greedy_sample(logits)
+
+    def _admission_last(self, chunk_len: int) -> tuple:
+        """The chunk's last real position, for a family whose admission
+        computes that position's logits alone; nothing otherwise."""
+        if getattr(self._family, "ADMISSION_LOGITS_AT_LAST", False):
+            return (jnp.int32(chunk_len - 1),)
+        return ()
 
     def step(self) -> int:
         """Admit pending requests, advance one prefill chunk per
@@ -732,7 +771,7 @@ class ContinuousBatcher:
         # the masks never admit max_seq-1 for a live row).
         write_positions = np.where(self.decoding, self.lengths,
                                    self.max_seq - 1).astype(np.int32)
-        logits, self.cache = llama.decode_step(
+        logits, self.cache = self._family.decode_step(
             self.params, self.config, tokens, self.cache,
             jnp.asarray(write_positions))
         self._key, sub = jax.random.split(self._key)
@@ -796,7 +835,7 @@ class ContinuousBatcher:
         if self._temps_dev is None:
             self._temps_dev = jnp.asarray(self.temperatures)
         emitted, tokens_n, lengths_n, self._key, self.cache = \
-            llama.decode_block(
+            self._family.decode_block(
                 self.params, self.config, tokens, self.cache, lengths,
                 self._active_dev, self._temps_dev, self._key,
                 num_steps=self.decode_block,
@@ -874,7 +913,7 @@ class ContinuousBatcher:
                                   dtype=jnp.int32)
                 begin = time.perf_counter()
                 (_, counts, tokens, _, _, _, history, key, _, _, _,
-                 cache) = llama.decode_loop(
+                 cache) = self._family.decode_loop(
                     self.params, self.config, tokens, cache, lengths,
                     active, budget, temps, eos, history, key,
                     ring=ring, speculative=mode,
@@ -894,8 +933,8 @@ class ContinuousBatcher:
         pool) so the probe pays real gather/scatter traffic instead of
         the all-trash-page fast case."""
         if not self.kv_page_tokens:
-            cache = llama.init_cache(self.config, self.max_slots,
-                                     self.max_seq)
+            cache = self._family.init_cache(self.config, self.max_slots,
+                                            self.max_seq)
         else:
             cache = init_paged_cache(
                 self.config, self.max_slots, self.max_seq,
@@ -1006,7 +1045,7 @@ class ContinuousBatcher:
             self._phase("fold", {"joining": len(firsts_meta)})
         (emitted, counts, tokens_next, lengths_next, active_next,
          budget_next, history_next, key_next, accepted, drafted, steps,
-         self.cache) = llama.decode_loop(
+         self.cache, *stats) = self._family.decode_loop(
             self.params, self.config, tokens, self.cache, lengths,
             active, budget, temps_dev, eos_dev, history, key,
             ring=ring, speculative=self.speculative,
@@ -1018,6 +1057,8 @@ class ContinuousBatcher:
         tree = {"emitted": emitted, "counts": counts,
                 "lengths": lengths_next,
                 "accepted": accepted, "drafted": drafted, "steps": steps}
+        if stats:
+            tree["stats"] = stats[0]    # the family's block statistics
         if first_vals:
             tree["firsts"] = jnp.concatenate(first_vals)
         _prefetch(tree)                 # overlap newer blocks
@@ -1077,8 +1118,12 @@ class ContinuousBatcher:
                 self.lengths[slot] = int(lengths_fetched[slot])
         if not self._loop_inflight:
             self._lengths_upper = self.lengths.copy()
+        observed = self._family.loop_stats(fetched["stats"]) \
+            if "stats" in fetched else None
+        if observed:
+            self._block_stats.append(observed)
         if self.trace is not None:
-            self._phase("demux")
+            self._phase("demux", observed)
 
     # -- paged-cache bookkeeping -------------------------------------------
 
@@ -1183,8 +1228,8 @@ class ContinuousBatcher:
                 self.config, self.max_slots, self.max_seq,
                 self.kv_page_tokens, self._pages.total)
         else:
-            self.cache = llama.init_cache(self.config, self.max_slots,
-                                          self.max_seq)
+            self.cache = self._family.init_cache(
+                self.config, self.max_slots, self.max_seq)
         if self._cache_put is not None:
             self.cache = self._cache_put(self.cache)
         self.recoveries += 1
@@ -1296,6 +1341,13 @@ class ContinuousBatcher:
         the host: prefill chunks, then the fetch of the block that
         carries the token) add up to ``ttft_ms``."""
         stats, self._request_stats = self._request_stats, []
+        return stats
+
+    def take_block_stats(self) -> list[dict]:
+        """Drain the family's per-block observations (one dict a
+        retired block, the family's ``loop_stats``; none for a family
+        that counts nothing in its loop)."""
+        stats, self._block_stats = self._block_stats, []
         return stats
 
     def _emit(self, request: Request, token: int):

@@ -23,6 +23,7 @@ from ..ops import decode_backend, matmul_backend
 from ..ops.layers import (rms_norm, rope_frequencies, apply_rope,
                           attention_prefill, attention_decode_append)
 from ..parallel.mesh import P
+from .families import config_fields
 from .paged import (gather_layer, gather_rows, is_paged, paged_extent,
                     pool_page_tokens, scatter_pages)
 from .quant import dequantize_kv, is_quantized, quantize_kv
@@ -137,6 +138,13 @@ class LlamaConfig:
     @property
     def gqa_groups(self) -> int:
         return self.n_heads // self.n_kv_heads
+
+    @classmethod
+    def from_widths(cls, widths: dict, **fields) -> "LlamaConfig":
+        """The config of published ``config.json`` keys
+        (``families.FAMILY_WIDTHS["llama"]``; a key the family lacks is
+        an error, a key left out keeps the dataclass default)."""
+        return cls(**{**fields, **config_fields("llama", widths)})
 
     @classmethod
     def llama3_8b(cls) -> "LlamaConfig":
@@ -1039,6 +1047,12 @@ def _matmul_safe_config(c: LlamaConfig, params: dict) -> LlamaConfig:
     if is_quantized(unembed) and _distributed_array(unembed["int8"]):
         return dataclasses.replace(c, matmul_kernel="off")
     return c
+
+
+def check_serving(**_) -> None:
+    """What this family cannot serve under a batcher's settings
+    (``batching.model_family``): nothing is refused here -- the
+    batcher's own checks cover the Llama family."""
 
 
 def decode_step(params: dict, config: LlamaConfig, tokens: jax.Array,

@@ -8,7 +8,10 @@ KV in fixed-size **pages** instead:
 
 - one physical **pool** per cache side, ``[L, P, page_tokens, K*hd]``
   (int8 caches pair it with a ``[L, P, page_tokens, K, 1]`` scale pool
-  -- the per-token-per-head scales ride their page);
+  -- the per-token-per-head scales ride their page); a LATENT cache
+  (models/deepseek.py) is ONE pool under the key ``latent``, ``[L, P,
+  W, page_tokens]`` -- a page's tokens lie along the LAST axis -- behind
+  the same table and allocator;
 - a device **page table** ``[B, pages_per_slot] int32`` mapping each
   slot's logical pages to physical pages.  Entry 0 is the reserved
   TRASH page: unallocated logical pages point at it, and inactive
@@ -61,7 +64,8 @@ from .quant import is_quantized
 __all__ = ["PageAllocator", "init_paged_cache", "is_paged",
            "pages_per_slot", "pool_page_tokens", "paged_extent",
            "gather_layer", "gather_rows", "gather_slot", "scatter_pages",
-           "prefix_page_keys"]
+           "gather_latent_pages", "latent_pages", "scatter_latent_pages",
+           "scatter_latent_rows", "prefix_page_keys"]
 
 
 def pages_per_slot(max_seq: int, page_tokens: int) -> int:
@@ -75,7 +79,15 @@ def pages_per_slot(max_seq: int, page_tokens: int) -> int:
 def init_paged_cache(config, batch: int, max_seq: int | None = None,
                      page_tokens: int = 64,
                      total_pages: int | None = None) -> dict:
-    """Paged serving cache: ``{"k": pool, "v": pool, "page_table"}``.
+    """Paged serving cache: ``{"k": pool, "v": pool, "page_table"}``,
+    or, for a config with a ``latent_width`` (latent attention: one
+    payload a token, no heads, no k/v pair), ``{"latent": pool,
+    "page_table"}`` with the pool ``[L, P, latent_width, page_tokens]``
+    behind the same table and allocator.  A latent page is stored
+    TOKENS-MINOR: a width that is no multiple of the 128 lanes (576)
+    makes that the v5e's own default layout of the array, and a pool
+    declared rows-minor was transposed -- three passes over 2.4 GB --
+    at the entry of every program that took it (PERF.md, PR 29).
 
     ``total_pages`` counts PHYSICAL pages including the reserved trash
     page 0 (default: full provisioning, ``batch * pages_per_slot + 1``
@@ -91,6 +103,13 @@ def init_paged_cache(config, batch: int, max_seq: int | None = None,
         raise ValueError(
             f"kv_pages={pool_pages}: the pool must hold at least one "
             f"full slot plus the trash page ({pps + 1})")
+    table = jnp.zeros((batch, pps), dtype=jnp.int32)
+    latent_width = getattr(c, "latent_width", None)
+    if latent_width is not None:
+        return {"latent": jnp.zeros(
+                    (c.n_layers, pool_pages, latent_width, page_tokens),
+                    dtype=jnp.dtype(c.dtype)),
+                "page_table": table}
     shape = (c.n_layers, pool_pages, page_tokens,
              c.n_kv_heads * c.head_dim)
     if c.kv_dtype == "int8":
@@ -102,8 +121,7 @@ def init_paged_cache(config, batch: int, max_seq: int | None = None,
     else:
         def side():
             return jnp.zeros(shape, dtype=jnp.dtype(c.dtype))
-    return {"k": side(), "v": side(),
-            "page_table": jnp.zeros((batch, pps), dtype=jnp.int32)}
+    return {"k": side(), "v": side(), "page_table": table}
 
 
 def is_paged(cache) -> bool:
@@ -116,6 +134,8 @@ def _payload(layer):
 
 def pool_page_tokens(cache: dict) -> int:
     """Static tokens-per-page of a paged cache's pool."""
+    if "latent" in cache:
+        return cache["latent"].shape[3]
     return _payload(cache["k"]).shape[2]
 
 
@@ -177,6 +197,58 @@ def scatter_pages(old, new, table, slots, starts, page_tokens: int):
                 (new.shape[0], 1, page_tokens) + new.shape[3:])
             old = jax.lax.dynamic_update_slice(
                 old, part, (0, page, 0) + (0,) * (old.ndim - 3))
+    return old
+
+
+def gather_latent_pages(pool, table, index):
+    """Layer ``index`` (may be traced) of a latent pool ``[L, P, W, pt]``
+    -> the pages ``[N, pps, W, pt]`` of table rows ``[N, pps]``: one
+    gather that reads only those pages (the pool is closed over, never
+    a scan input).  The logical row ``t`` of a slot is ``[.., t // pt,
+    :, t % pt]``; consumers contract over the page and token axes as
+    they lie, so no row view is materialised."""
+    return pool[index, table]
+
+
+def latent_pages(rows, page_tokens: int):
+    """Rows ``[..., S, W]`` (S whole pages) -> pages ``[..., S // pt, W,
+    pt]``, the pool's own form."""
+    *lead, s, w = rows.shape
+    return jnp.swapaxes(
+        rows.reshape(*lead, s // page_tokens, page_tokens, w), -1, -2)
+
+
+def scatter_latent_pages(old, new, table, slots, starts):
+    """Write whole-page prefill chunks into a latent pool ``[L, P, W,
+    pt]`` (donated: updated in place) through the page table, all
+    layers at once: ``new`` ``[L, N, pages, W, pt]`` (``latent_pages``
+    of the chunks the layer loop emitted), row ``i`` starting at token
+    ``starts[i]`` of slot ``slots[i]``.  One ``dynamic_update_slice``
+    per (row, page), as :func:`scatter_pages`."""
+    page_tokens = old.shape[3]
+    for i in range(new.shape[1]):
+        for j in range(new.shape[2]):
+            page = table[slots[i], starts[i] // page_tokens + j]
+            old = jax.lax.dynamic_update_slice(
+                old, new[:, i, j][:, None], (0, page, 0, 0))
+    return old
+
+
+def scatter_latent_rows(old, new, table, positions):
+    """Write one token's row per batch row into a latent pool ``[L, P,
+    W, pt]`` (donated) through the page table, all layers at once:
+    ``new`` ``[L, B, W]``, row ``b`` landing at logical position
+    ``positions[b]`` of its slot.  One unrolled
+    ``dynamic_update_slice`` per batch row (a batched scatter defeats
+    the in-place aliasing: ``llama._scatter_positions``, whose
+    single-array twin this is)."""
+    page_tokens = old.shape[3]
+    for row in range(new.shape[1]):
+        position = positions[row]
+        old = jax.lax.dynamic_update_slice(
+            old, new[:, row][:, None, :, None],
+            (0, table[row, position // page_tokens], 0,
+             position % page_tokens))
     return old
 
 

@@ -36,6 +36,7 @@ DEFINITION_FIXTURES = {
     "bad_parameter.json": "bad-parameter",
     "bad_element_parameter.json": "bad-parameter",
     "bad_prefix_cache.json": "bad-parameter",
+    "bad_llm_family.json": "bad-parameter",
     "bad_data_plane.json": "bad-parameter",
     "bad_qos.json": "bad-parameter",
     "bad_qos_tenant.json": "bad-parameter",
