@@ -247,7 +247,9 @@ class LLM(PipelineElement):
     ``model``, a ``placement`` of more than one chip.  Its retired
     decode blocks also observe ``llm_moe_experts_touched`` and
     ``llm_moe_load_imbalance`` into the telemetry registry (and the
-    recorder's ``llm_tick:demux`` info).
+    recorder's ``llm_tick:demux`` info).  Where the Llama family's
+    paged decode kernel serves decode (``decode_kernel``), a retired
+    block observes ``llm_decode_live_grid_share`` the same way.
 
     ASYNC by default: each frame parks and its request hops to the
     element's device WORKER THREAD, which owns the model and the shared
@@ -835,14 +837,22 @@ class LLM(PipelineElement):
                                                entry["tpot_ms"],
                                                **labels)
             for observed in block_stats:
-                # What the latent family counted in a retired block
-                # (models/deepseek.py:loop_stats).
-                telemetry.registry.observe(
-                    "llm_moe_experts_touched",
-                    observed["moe_experts_touched"])
-                telemetry.registry.observe(
-                    "llm_moe_load_imbalance",
-                    observed["moe_load_imbalance"])
+                if "moe_experts_touched" in observed:
+                    # What the latent family counted in a retired
+                    # block (models/deepseek.py:loop_stats).
+                    telemetry.registry.observe(
+                        "llm_moe_experts_touched",
+                        observed["moe_experts_touched"])
+                    telemetry.registry.observe(
+                        "llm_moe_load_imbalance",
+                        observed["moe_load_imbalance"])
+                if "paged_grid_steps" in observed:
+                    # How much of the paged decode kernel's grid had a
+                    # live page to stream at the block's first step.
+                    telemetry.registry.observe(
+                        "llm_decode_live_grid_share",
+                        100.0 * observed["paged_grid_steps_live"]
+                        / observed["paged_grid_steps"])
         changed = False
         hits = batcher.prefix_hits
         lookups = batcher.prefix_lookups

@@ -163,7 +163,10 @@ def model_family(config):
     of one chunk position, which the batcher then names), leave out
     ``prefill_into_slots`` (admission stays one slot a program) and
     return block statistics after ``decode_loop``'s twelve results,
-    which its ``loop_stats`` turns into what the LLM element observes."""
+    which its ``loop_stats`` turns into what the LLM element observes;
+    ``paged_decode_pages`` says how many pages a grid step its paged
+    decode kernel takes where that kernel serves the cache (the batcher
+    then counts how much of each block's grid streams a live page)."""
     if isinstance(config, deepseek.DeepseekConfig):
         return deepseek
     return llama
@@ -202,12 +205,15 @@ class _LoopBlock:
     (llama.decode_loop).  ``tree`` holds every device array the retire
     needs -- emitted ring, counts, carries, accept counters, folded
     first tokens -- fetched in ONE counted host copy."""
-    __slots__ = ("tree", "snapshot", "firsts_meta")
+    __slots__ = ("tree", "snapshot", "firsts_meta", "grid")
 
-    def __init__(self, tree, snapshot, firsts_meta):
+    def __init__(self, tree, snapshot, firsts_meta, grid=None):
         self.tree = tree
         self.snapshot = snapshot      # [(slot, request)] in the block
         self.firsts_meta = firsts_meta  # [(slot, request)] admissions
+        # (live, all) grid steps of the paged decode kernel at the
+        # block's first step, or None where it does not serve decode
+        self.grid = grid
 
 
 class ContinuousBatcher:
@@ -352,6 +358,12 @@ class ContinuousBatcher:
         self._cache_put = cache_put
         if cache_put is not None:
             self.cache = cache_put(self.cache)
+        # Pages a grid step of the family's paged decode kernel, where
+        # it serves this cache (None: another backend decodes).
+        paged_pages = getattr(family, "paged_decode_pages", None)
+        self._paged_pages = paged_pages(config, self.cache) \
+            if paged_pages is not None and self._pages is not None \
+            else None
         # One explicit host fetch per retired device-loop block; the
         # LLM element wires the pipeline TransferLedger's counted fetch
         # here so serving obeys the device-resident swag contract.
@@ -1067,11 +1079,21 @@ class ContinuousBatcher:
                             "active": active_next, "budget": budget_next,
                             "history": history_next, "key": key_next}
         snapshot = sorted({*live, *joining})
+        grid = None
+        if self._paged_pages is not None:
+            # The rows of this block at its first step, from the host's
+            # own lengths: how much of the kernel's grid has a page.
+            from ..ops.pallas_decode import paged_grid_steps
+            rows = np.zeros(self.max_slots, dtype=np.int64)
+            rows[snapshot] = self._lengths_upper[snapshot]
+            grid = paged_grid_steps(rows, self.kv_page_tokens,
+                                    self._pages.pps, self._paged_pages)
         for slot in snapshot:
             self._lengths_upper[slot] = min(
                 int(self._lengths_upper[slot]) + ring, self.max_seq)
         self._loop_inflight.append(_LoopBlock(
-            tree, [(i, self.slots[i]) for i in snapshot], firsts_meta))
+            tree, [(i, self.slots[i]) for i in snapshot], firsts_meta,
+            grid))
         self.blocks_dispatched += 1
         return True
 
@@ -1120,6 +1142,12 @@ class ContinuousBatcher:
             self._lengths_upper = self.lengths.copy()
         observed = self._family.loop_stats(fetched["stats"]) \
             if "stats" in fetched else None
+        if blk.grid is not None:
+            live_steps, grid_steps = blk.grid
+            observed = {**(observed or {}),
+                        "paged_grid_steps_live": live_steps,
+                        "paged_grid_steps": grid_steps,
+                        "paged_pages_per_step": self._paged_pages}
         if observed:
             self._block_stats.append(observed)
         if self.trace is not None:

@@ -33,7 +33,7 @@ __all__ = ["LlamaConfig", "init_params", "partition_specs",
            "prefill", "prefill_with_aux", "prefill_into_slot",
            "prefill_into_slots", "decode_step", "decode_block",
            "decode_loop", "greedy_sample", "select_tokens",
-           "resolve_decode_backend"]
+           "resolve_decode_backend", "paged_decode_pages"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -862,6 +862,17 @@ def _resolve_decode_flash(c: LlamaConfig, cache: dict) -> bool:
     return resolve_decode_backend(c, cache) != "reference"
 
 
+def paged_decode_pages(c: LlamaConfig, cache: dict) -> int | None:
+    """Pages a grid step of the paged decode kernel where that kernel
+    serves this cache's decode (:func:`resolve_decode_backend`), else
+    None: what the batcher counts ``llm_decode_live_grid_share`` at."""
+    if resolve_decode_backend(c, cache) != "paged-kernel":
+        return None
+    from ..ops.pallas_decode import _split_paged, paged_pages_per_step
+    return paged_pages_per_step(jax.eval_shape(_split_paged, cache["k"]),
+                                cache["page_table"].shape[1])
+
+
 def _scatter_positions(config: LlamaConfig, cache: dict, k_tokens,
                        v_tokens, positions) -> dict:
     """Scatter per-token KV updates (``[L, B, S, K, hd]``) into the
@@ -905,17 +916,23 @@ def _scatter_positions(config: LlamaConfig, cache: dict, k_tokens,
 def _decode_step_impl(params: dict, config: LlamaConfig,
                       tokens: jax.Array, cache: dict,
                       lengths: jax.Array,
-                      use_flash: bool | None = None) \
+                      use_flash: bool | None = None,
+                      attend: jax.Array | None = None) \
         -> tuple[jax.Array, dict]:
     """One token per active sequence.
 
     tokens: [B] current tokens; lengths: [B] positions to write (= current
-    sequence length).  Returns (logits [B, vocab], cache).
+    sequence length); attend: [B] cache positions each row reads
+    (default ``lengths``; the device loop gives 0 to a row that does
+    not decode -- its write lands at the trash position and it streams
+    no page).  Returns (logits [B, vocab], cache).
     """
     c = config
     b = tokens.shape[0]
     rope_table = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
     positions = lengths[:, None]                       # [B, 1]
+    if attend is None:
+        attend = lengths
     paged = is_paged(cache)
     extent = cache_extent(cache)
     if use_flash is None:
@@ -978,9 +995,9 @@ def _decode_step_impl(params: dict, config: LlamaConfig,
                 if paged:
                     return flash_decode_append_paged(
                         q, k_view, v_view, index, k, v,
-                        cache["page_table"], lengths)
+                        cache["page_table"], attend)
                 return flash_decode_append_stacked(
-                    q, k_view, v_view, index, k, v, lengths)
+                    q, k_view, v_view, index, k, v, attend)
             hidden2, aux2 = _block(c, hidden, layer, kv_write)
             return (hidden2, aux + aux2), kv_write.updated
 
@@ -1007,7 +1024,7 @@ def _decode_step_impl(params: dict, config: LlamaConfig,
                 k_view, v_view = k_layer, v_layer
             return attention_decode_append(
                 q, _grouped(k_view, c.n_kv_heads),
-                _grouped(v_view, c.n_kv_heads), k, v, lengths)
+                _grouped(v_view, c.n_kv_heads), k, v, attend)
         return kv_write
 
     logits, new_cache, _ = _forward_layers(
@@ -1124,7 +1141,7 @@ def _decode_block_jit(params: dict, config: LlamaConfig, tokens: jax.Array,
     tokens: [B] current tokens; lengths: [B] write positions of ACTIVE
     rows; active: [B] bool (inactive rows -- empty or mid-prefill slots
     -- write to the trash position T-1 every step, exactly like the
-    single-step batcher tick).  Returns
+    single-step batcher tick, and attend nothing).  Returns
     ``(emitted [num_steps, B], tokens' [B], lengths' [B], key', cache)``
     -- the final carries come back as DEVICE arrays so the batcher can
     dispatch block k+1 from block k's outputs without a host round trip
@@ -1140,9 +1157,9 @@ def _decode_block_jit(params: dict, config: LlamaConfig, tokens: jax.Array,
     def body(carry, _):
         tokens, cache, lengths, key = carry
         positions = jnp.where(active, jnp.minimum(lengths, trash), trash)
-        logits, cache = _decode_step_impl(params, config, tokens,
-                                          cache, positions,
-                                          use_flash=use_flash)
+        logits, cache = _decode_step_impl(
+            params, config, tokens, cache, positions,
+            use_flash=use_flash, attend=jnp.where(active, positions, 0))
         key, sub = jax.random.split(key)
         tokens = select_tokens(sub, logits, temperatures,
                                top_k=top_k).astype(jnp.int32)
@@ -1334,7 +1351,7 @@ def _draft_window(draft, config: LlamaConfig, tokens, cache, lengths,
 
 
 def _chunk_verify(params, config: LlamaConfig, chunk, cache, starts,
-                  trash: int, use_flash: bool = False):
+                  trash: int, use_flash: bool = False, attend=None):
     """One batched multi-token target step: forward ``chunk`` [B, S]
     (current token + S-1 draft tokens per row) at per-row positions
     ``starts + i``, writing every position's KV optimistically and
@@ -1355,9 +1372,14 @@ def _chunk_verify(params, config: LlamaConfig, chunk, cache, starts,
     [B, H, S, T] HBM logits -- and paged caches walk the page table
     in-kernel instead of paying the per-layer gather.  int8 caches
     dequantize in-kernel (exact), so the dense path's gather-and-
-    dequantize trick is no longer the only option."""
+    dequantize trick is no longer the only option.
+
+    ``attend``: [B] cache positions each row reads, default ``starts``
+    (0 for a row that does not decode, as in ``_decode_step_impl``)."""
     c = config
     b, s = chunk.shape
+    if attend is None:
+        attend = starts
     rope_table = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
     positions = jnp.minimum(starts[:, None] + jnp.arange(s)[None, :],
                             trash)                           # [B, S]
@@ -1388,7 +1410,7 @@ def _chunk_verify(params, config: LlamaConfig, chunk, cache, starts,
                 k = apply_rope(k, rope_table, positions)
                 kv_write.updated = (k, v)
                 return flash_verify_append(
-                    q, k_view, v_view, index, k, v, starts, positions,
+                    q, k_view, v_view, index, k, v, attend, positions,
                     page_table=cache["page_table"] if paged else None)
             hidden2, aux2 = _block(c, hidden, layer, kv_write)
             return (hidden2, aux + aux2), kv_write.updated
@@ -1423,7 +1445,7 @@ def _chunk_verify(params, config: LlamaConfig, chunk, cache, starts,
                 [jnp.broadcast_to(jnp.arange(extent)[None, :],
                                   (b, extent)), positions], axis=1)
             valid = jnp.concatenate(
-                [jnp.arange(extent)[None, :] < starts[:, None],
+                [jnp.arange(extent)[None, :] < attend[:, None],
                  jnp.ones((b, s), dtype=bool)], axis=1)
             return attention_prefill(q, k_all, v_all, positions,
                                      kv_length_mask=valid,
@@ -1494,8 +1516,12 @@ def _decode_loop_jit(params: dict, draft: dict, config: LlamaConfig,
         (i, tokens, cache, lengths, active, budget, key, emitted,
          counts, history, accepted, drafted) = carry
         positions = jnp.where(active, jnp.minimum(lengths, trash), trash)
-        logits, cache = _decode_step_impl(params, config, tokens, cache,
-                                          positions, use_flash=use_flash)
+        # (a row that does not decode writes at the trash position and
+        # reads nothing: its pages -- a part-admitted prompt's, say --
+        # cost the attention kernel no copy and no product)
+        logits, cache = _decode_step_impl(
+            params, config, tokens, cache, positions,
+            use_flash=use_flash, attend=jnp.where(active, positions, 0))
         key, sub = jax.random.split(key)
         sampled = select_tokens(sub, logits, temperatures,
                                 top_k=top_k).astype(jnp.int32)
@@ -1525,9 +1551,9 @@ def _decode_loop_jit(params: dict, draft: dict, config: LlamaConfig,
                                    trash)                    # [B, k]
         chunk = jnp.concatenate([tokens[:, None], drafts], axis=1)
         starts = jnp.where(active, jnp.minimum(lengths, trash), trash)
-        logits, cache = _chunk_verify(params, config, chunk, cache,
-                                      starts, trash,
-                                      use_flash=use_flash)
+        logits, cache = _chunk_verify(
+            params, config, chunk, cache, starts, trash,
+            use_flash=use_flash, attend=jnp.where(active, starts, 0))
         key, sub = jax.random.split(key)
         greedy = jnp.argmax(logits, -1).astype(jnp.int32)    # [B, k+1]
         first = select_tokens(sub, logits[:, 0, :], temperatures,

@@ -46,12 +46,14 @@ removed that price on the kernel plane: when the decode backend
 resolves to ``paged-kernel`` (ops.decode_backend -- 'auto' past the
 flash threshold, or an explicit flash/``decode_kernel`` request),
 decode and chunk-verify walk the page table IN-KERNEL
-(ops/pallas_decode.py:flash_decode_attention_paged): the BlockSpec
-index maps resolve each slot's physical pages from the scalar-
-prefetched table, so the logical row view never materializes and the
-cache streams once.  The gather path remains the reference (and the
-sub-threshold / distributed fallback); the memory win (pool sized to
-the *live* token count) and recompile-free admission hold on both.
+(ops/pallas_decode.py:flash_decode_attention_paged): each row copies
+its own live pages out of the pool, their physical indices read from
+the scalar-prefetched table, so the logical row view never
+materializes, the cache streams once, and a page a slot could hold
+but does not is never touched.  The gather path remains the reference
+(and the sub-threshold / distributed fallback); the memory win (pool
+sized to the *live* token count) and recompile-free admission hold on
+both.
 """
 
 from __future__ import annotations

@@ -30,8 +30,13 @@ it is the MXU-friendly formulation):
 - blocks wholly beyond a row's ``length`` clamp their DMA index to the
   last live block (fetch skipped, compute skipped via pl.when), so
   short rows in a ragged batch do not pay full-T bandwidth;
-- block_t defaults to 2048: per-grid-step overhead dominates below that
-  (measured on v5e at 8k: 233 GB/s at 512, 367 at 1024, 410+ at 2048);
+- block_t defaults to 2048 in the flat and stacked kernels: a grid
+  step's fixed cost dominates below that (v5e at 8k, BENCH_r03: 233
+  GB/s at 512, 367 at 1024, 410+ at 2048).  A dead step is not free
+  either -- the pipeline evaluates every operand's index map and
+  waits on its semaphore each step, ~0.2 us an operand (PR 30) -- which
+  is why the PAGED kernel, whose blocks are pages of 128 tokens, does
+  not ride the BlockSpec pipeline at all (below);
 - the kernel returns UNNORMALIZED (acc, m, l) partial softmax stats;
   the caller merges the current token's self-attention term outside
   (exactly the split the dense path uses) -- see
@@ -41,9 +46,18 @@ ISSUE 11 grew this module into the serving kernel PLANE: the same
 split-K body now also runs over layer-STACKED caches (scan-invariant,
 layer picked in the BlockSpecs -- no per-layer slice copy), over PAGED
 page pools (``flash_decode_attention_paged``: the [B, pps] page table
-is scalar-prefetched and walked inside the grid's index maps, so the
-logical row view the gather-attention path materialized never exists
-and the cache streams once), and under the speculative verify chunk
+is scalar-prefetched and walked in the kernel, so the logical row view
+the gather-attention path materialized never exists and the cache
+streams once.  Its grid is ``(B,)``: a row loops over its LIVE pages
+only, a few at a time, copying them HBM -> VMEM itself one group ahead
+of the products -- across rows too -- so a page a slot could hold but
+does not costs nothing, and a row that does not decode costs its grid
+step.  PR 30 measured the forms on v5e at 32 rows x 16 pages of
+[128, 1024] bf16, 29 rows of ~610 tokens live, 97 us of bytes a
+layer: a page a grid step 220 us; the pools handed to the pipeline
+2-16 times over, dead pages repeating the index an operand held,
+157-199 us; this form 111 us -- PERF.md section 6), and under the
+speculative verify chunk
 (``flash_verify_append``: all S draft positions share one cache
 frontier, so the cache part is THIS kernel with S*H block-diagonal
 query rows, and the chunk's own causal keys combine outside -- the
@@ -56,7 +70,10 @@ Off the TPU the kernels run in interpret mode when asked for by name
 the CPU mesh; ``auto`` never routes here off the chip
 (``ops.on_tpu``), and Mosaic itself is checked on the chip by
 ``chip_smoke.py`` (``interpret=False``; all of flat, stacked, paged at
-page sizes 8..256 and the verify chunk compile on v5e, bf16 and int8).
+page sizes 8..256 x 4..64 pages a slot and the verify chunk compile on
+v5e, bf16 and int8).  The paged kernel copies by hand, so off the chip
+it takes the TPU interpreter (``pltpu.InterpretParams``), which models
+DMAs and semaphores.
 """
 
 from __future__ import annotations
@@ -66,6 +83,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
@@ -76,7 +94,8 @@ from .tiles import (interpret_off_chip, pad_to as _pad_to,
 __all__ = ["flash_decode_attention", "flash_decode_append",
            "flash_decode_attention_stacked", "flash_decode_append_stacked",
            "flash_decode_attention_paged", "flash_decode_append_paged",
-           "flash_verify_append"]
+           "flash_verify_append", "paged_pages_per_step",
+           "paged_grid_steps"]
 
 #: kernel entry -> its tier-1 equivalence test (``file::test``) -- the
 #: ``kernel-test`` selfcheck rule requires every ``pl.pallas_call``
@@ -194,9 +213,9 @@ def _decode_kernel(meta_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
     """meta_ref: scalar-prefetch i32 array -- ``lengths`` [B] in the
     per-layer form, ``[layer, *lengths]`` in the layered/paged forms
     (the cache refs then carry a leading layer dim the BlockSpecs index
-    into; the PAGED form additionally appends the flattened page table,
-    consumed only by the index maps -- the kernel body is identical,
-    one ``block_t``-sized stretch of the logical row per grid step)."""
+    into).  One ``block_t``-sized stretch of the row per grid step; the
+    paged form has its own body (:func:`_paged_kernel`) and shares
+    :func:`_scores_block` and :func:`_online_update`."""
     b = pl.program_id(0)
     ti = pl.program_id(1)
     nt = pl.num_programs(1)
@@ -483,34 +502,217 @@ def flash_decode_attention_stacked(q_pad, k_flat, v_flat, k_scale_t,
     return acc[:, :h], m[:, :h, 0], l[:, :h, 0]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "qrow_period"))
+#: VMEM the paged kernel's page buffers may take: K and V (and, for
+#: int8 pools, their scale pages), two groups each -- the one being
+#: folded and the one in flight.  ``_PAGED_MAX_PAGES`` bounds the
+#: copies in flight (and the unrolled body) where pages are tiny;
+#: ``_PAGED_VMEM_LIMIT`` is the call's scoped-VMEM limit: room for
+#: those buffers beside the query rows, the accumulator and a page's
+#: scores (the verify chunk's are S times a decode step's).
+_PAGED_BUFFER_BYTES = 4 << 20
+_PAGED_MAX_PAGES = 16
+_PAGED_VMEM_LIMIT = 32 << 20
+
+
+def paged_pages_per_step(view, pps: int) -> int:
+    """How many logical pages the paged kernel copies and folds as one
+    group: as many as the buffer budget holds twice over (four at
+    [128, 1024] bf16 pages) -- derived from the static shapes alone
+    (``view``: one pool side as :func:`_split_paged` returns it;
+    ``pps``: pages a slot can hold).  1 where a single page already
+    fills the budget."""
+    payload, scale = view
+    _, _, page_tokens, c = payload.shape
+    page_bytes = page_tokens * c * payload.dtype.itemsize
+    if scale is not None:
+        page_bytes += scale.shape[2] * scale.shape[3] \
+            * scale.dtype.itemsize
+    fit = max(1, min(_PAGED_BUFFER_BYTES // (4 * page_bytes),
+                     _PAGED_MAX_PAGES, pps))
+    return -(-pps // -(-pps // fit))     # even groups, least padding
+
+
+def paged_grid_steps(lengths, page_tokens: int, pps: int,
+                     pages_per_step: int) -> tuple[int, int]:
+    """On the host, from ``lengths`` [B] as the kernel is given them (0
+    for a row that does not decode): (steps of the paged kernel that
+    stream at least one live page, steps of the call), a step being one
+    group of a row's pages -- the kernel loops over a row's LIVE groups
+    only, so the steps that stream nothing are the grid steps of the
+    rows with no page at all (``llm_decode_live_grid_share``)."""
+    pages = -(-np.minimum(np.asarray(lengths), pps * page_tokens)
+              // page_tokens)
+    groups = -(-pages // pages_per_step)
+    return int(groups.sum()), int(np.maximum(groups, 1).sum())
+
+
+def _paged_kernel(layer_ref, lengths_ref, table_ref, q_ref, *refs,
+                  page_tokens, pages, pps, n_heads, n_kv, groups,
+                  compute_dtype, quantized, period):
+    """One ROW of the paged form (grid ``(B,)``, walked in order).  The
+    pools stay in HBM (``ANY`` space); the row loops over its LIVE page
+    groups only -- ``pages`` consecutive logical pages a group -- and
+    copies them itself, double-buffered: while group ``g`` is folded
+    page by page into the online-softmax state (each page waited for
+    just before its products, the op sequence of :func:`_decode_kernel`
+    at ``block_t`` = one page), group ``g + 1`` is in flight -- at a
+    row's last group, the next live row's first.  A dead page costs no
+    copy, no product and no step; an inactive row (length 0) its grid
+    step alone.
+
+    refs: the pools (k, v[, k scales, v scales]); the outputs (acc, m,
+    l); one ``[2, pages, ...]`` VMEM buffer a pool; DMA semaphores
+    ``[pools, 2, pages]``; SMEM ``[slot to fold next, a first group is
+    in flight]`` (carried across rows); the m, l, acc scratch."""
+    sides = 4 if quantized else 2
+    pools = refs[:sides]
+    o_ref, m_ref, l_ref = refs[sides:sides + 3]
+    bufs = refs[sides + 3:2 * sides + 3]
+    sems, state, m_scr, l_scr, acc_scr = refs[2 * sides + 3:]
+    b = pl.program_id(0)
+    rows = pl.num_programs(0)
+    layer = layer_ref[0]
+
+    def row_length(row):
+        # (the table covers pps pages: the allocator's contract)
+        return jnp.minimum(lengths_ref[row], pps * page_tokens)
+
+    length = row_length(b)
+    n_groups = pl.cdiv(length, page_tokens * pages)
+
+    def copies(row, logical, slot, index):
+        physical = table_ref[row * pps + logical]
+        return [pltpu.make_async_copy(
+            pools[side].at[layer, physical], bufs[side].at[slot, index],
+            sems.at[side, slot, index]) for side in range(sides)]
+
+    def start_group(row, group, slot):
+        limit = row_length(row)
+        for index in range(pages):
+            logical = group * pages + index
+
+            @pl.when(logical * page_tokens < limit)
+            def _start(index=index, logical=logical):
+                for copy in copies(row, logical, slot, index):
+                    copy.start()
+
+    @pl.when(b == 0)
+    def _first_row():
+        state[0] = 0
+        state[1] = 0
+
+    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(jnp.logical_and(n_groups > 0, state[1] == 0))
+    def _prime():
+        # (no earlier row had a page: nobody prefetched this group)
+        start_group(b, 0, state[0])
+        state[1] = 1
+
+    shared = dict(n_heads=n_heads, n_kv=n_kv, groups=groups,
+                  period=period, compute_dtype=compute_dtype,
+                  quantized=quantized)
+
+    def fold_group(group, carry):
+        slot = state[0]
+        other = 1 - slot
+
+        @pl.when(group + 1 < n_groups)
+        def _next_group():
+            start_group(b, group + 1, other)
+
+        @pl.when(group + 1 == n_groups)
+        def _next_row():
+            following = jax.lax.while_loop(
+                lambda row: jnp.logical_and(
+                    row < rows,
+                    lengths_ref[jnp.minimum(row, rows - 1)] <= 0),
+                lambda row: row + 1, b + 1)
+
+            @pl.when(following < rows)
+            def _():
+                start_group(following, 0, other)
+
+        for index in range(pages):
+            logical = group * pages + index
+            t_start = logical * page_tokens
+
+            @pl.when(t_start < length)
+            def _live(index=index, logical=logical, t_start=t_start):
+                for copy in copies(b, logical, slot, index):
+                    copy.wait()
+
+                def page(side):
+                    if side >= sides:
+                        return None
+                    if side >= 2:       # a scale page, lane-padded
+                        return bufs[side][slot, index, :, :page_tokens]
+                    return bufs[side][slot, index]
+
+                def scores():
+                    return _scores_block(q_ref[0], page(0), page(2),
+                                         **shared)
+                interior = t_start + page_tokens <= length
+
+                @pl.when(interior)
+                def _whole_page():
+                    _online_update(m_scr, l_scr, acc_scr, scores(),
+                                   page(1), page(3), **shared)
+
+                @pl.when(jnp.logical_not(interior))
+                def _last_page():
+                    mask = t_start + jax.lax.broadcasted_iota(
+                        jnp.int32, (n_heads, page_tokens), 1) < length
+                    _online_update(m_scr, l_scr, acc_scr,
+                                   jnp.where(mask, scores(), _NEG_INF),
+                                   page(1), page(3), p_mask=mask,
+                                   **shared)
+        state[0] = other
+        return carry
+
+    jax.lax.fori_loop(0, n_groups, fold_group, 0)
+    o_ref[0] = acc_scr[...]
+    m_ref[0] = m_scr[...]
+    l_ref[0] = l_scr[...]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "qrow_period",
+                                             "pages_per_step"))
 def flash_decode_attention_paged(q_pad, k_pool, v_pool, k_scale_t,
                                  v_scale_t, layer, page_table, lengths,
                                  *, interpret: bool | None = None,
-                                 qrow_period: int | None = None):
+                                 qrow_period: int | None = None,
+                                 pages_per_step: int | None = None):
     """:func:`flash_decode_attention` over ONE layer of a PAGED cache
     pool, the page table walked IN-KERNEL (ISSUE 11 tentpole).
 
     k_pool/v_pool: [L, P, pt, C] physical page pools (models/paged.py
     layout, layer-stacked and scan-invariant -- the same no-per-layer-
     slice discipline as the stacked kernel); k_scale_t/v_scale_t:
-    [L, P, K, pt] f32 per-page scale pools or None (int8 pools,
-    dequantized in-kernel exactly like the flat kernel); ``layer``:
+    [L, P, K, pt'] f32 per-page scale pools or None (int8 pools,
+    dequantized in-kernel exactly like the flat kernel; ``pt'`` >= pt,
+    padded to whole lane tiles: :func:`_split_paged`); ``layer``:
     traced scalar; page_table: [B, pps] int32 (entry 0 = the reserved
-    trash page); lengths: [B] valid positions.
+    trash page); lengths: [B] valid positions (0: the row reads
+    nothing).
 
-    The grid is (B, pages_per_slot): each step's BlockSpec resolves its
-    PHYSICAL page from the scalar-prefetched table --
-    ``table[b, min(pi, last_live)]`` -- so the pool is read in place,
-    one page DMA per live logical page.  No host-side ``gather_layer``
+    The grid is (B,) and the pools never enter a BlockSpec pipeline:
+    each row copies its own live pages HBM -> VMEM, a group of
+    ``pages_per_step`` at a time and one group ahead
+    (:func:`_paged_kernel`), the physical page read from the scalar-
+    prefetched table -- so the pool is read in place, one page DMA per
+    live logical page and nothing at all for a dead one (why not the
+    pipeline: the module docstring).  No host-side ``gather_layer``
     materialization: the logical [B, T, C] row view never exists, which
     is exactly the 2x cache traffic the gather-attention paged path
-    paid.  Blocks past a row's length clamp to its last live page
-    (compute skipped via pl.when, the repeated index skips the DMA), so
-    a short slot reads only its own extent.  Returns the same partial
+    paid.  ``pages_per_step`` follows the
+    pools' shapes (:func:`paged_pages_per_step`; the argument is for
+    the tests and the probe): whatever it is, pages meet the float32
+    statistics one by one, in order.  Returns the same partial
     (acc, m, l) stats as the flat kernel.
     """
-    interpret = interpret_off_chip(interpret)
     quantized = k_scale_t is not None
     b, h, c = q_pad.shape
     page_tokens = k_pool.shape[2]
@@ -527,76 +729,47 @@ def flash_decode_attention_paged(q_pad, k_pool, v_pool, k_scale_t,
             f"tile); use an aligned page size or the reference "
             f"gather path")
     pps = page_table.shape[1]
-    n_kv = k_scale_t.shape[2] if quantized else None
+    pages = int(pages_per_step or paged_pages_per_step(
+        (k_pool, k_scale_t), pps))
+    n_kv = k_scale_t.shape[2] if quantized else 1
 
     h_pad = _round_up(max(h, 8), 8)
     q_pad = _pad_to(q_pad, 1, h_pad)
-    if not quantized:
-        n_kv = 1
-        k_scale_t = jnp.zeros((1, 1, 1, page_tokens), dtype=jnp.float32)
-        v_scale_t = jnp.zeros((1, 1, 1, page_tokens), dtype=jnp.float32)
 
-    grid = (b, pps)
-    compute_dtype = q_pad.dtype if q_pad.dtype != jnp.float32 \
-        else jnp.float32
-    scale_layers = k_pool.shape[0] if quantized else 1
-    scale_pages = k_scale_t.shape[1]
-
-    def _physical(bi, pi, meta):
-        # meta = [layer, lengths[B], table.ravel()[B*pps]].  Clamp dead
-        # logical pages to the row's last live one (pl.when skips the
-        # compute, the repeated physical index skips the DMA), then
-        # translate logical -> physical through the prefetched table.
-        last_live = jnp.maximum(
-            pl.cdiv(meta[1 + bi], page_tokens) - 1, 0)
-        logical = jnp.minimum(pi, last_live)
-        return meta[1 + b + bi * pps + logical]
-
-    def kv_block(bi, pi, meta):
-        return (meta[0], _physical(bi, pi, meta), 0, 0)
-
-    def scale_block(bi, pi, meta):
-        # Unquantized pools pass a [1, 1, 1, pt] dummy: clamp both the
-        # layer and the page index so the spec never reads past it.
-        return (jnp.minimum(meta[0], scale_layers - 1),
-                jnp.minimum(_physical(bi, pi, meta), scale_pages - 1),
-                0, 0)
+    def row(bi, *_):
+        return (bi, 0, 0)
 
     kernel = functools.partial(
-        _decode_kernel, block_t=page_tokens, n_heads=h_pad, n_kv=n_kv,
+        _paged_kernel, page_tokens=page_tokens, pages=pages, pps=pps,
+        n_heads=h_pad, n_kv=n_kv,
         groups=max((qrow_period or h) // n_kv, 1),
-        compute_dtype=compute_dtype,
-        quantized=quantized, layered=True, period=qrow_period)
+        compute_dtype=q_pad.dtype, quantized=quantized,
+        period=qrow_period)
 
+    # Scale pools ride as [L, P, K, pt] so the kernel's [K, Tb] block
+    # matches the flat kernel's layout exactly.
+    pools = [k_pool, v_pool] \
+        + ([k_scale_t, v_scale_t] if quantized else [])
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, h_pad, c), lambda bi, pi, meta: (bi, 0, 0)),
-            pl.BlockSpec((1, 1, page_tokens, c), kv_block),
-            pl.BlockSpec((1, 1, page_tokens, c), kv_block),
-            pl.BlockSpec((1, 1, n_kv, page_tokens), scale_block),
-            pl.BlockSpec((1, 1, n_kv, page_tokens), scale_block),
-        ],
+        num_scalar_prefetch=3,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, h_pad, c), row)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
         out_specs=[
-            pl.BlockSpec((1, h_pad, c), lambda bi, pi, meta: (bi, 0, 0)),
-            pl.BlockSpec((1, h_pad, _STAT_LANES),
-                         lambda bi, pi, meta: (bi, 0, 0)),
-            pl.BlockSpec((1, h_pad, _STAT_LANES),
-                         lambda bi, pi, meta: (bi, 0, 0)),
+            pl.BlockSpec((1, h_pad, c), row),
+            pl.BlockSpec((1, h_pad, _STAT_LANES), row),
+            pl.BlockSpec((1, h_pad, _STAT_LANES), row),
         ],
         scratch_shapes=[
+            pltpu.VMEM((2, pages) + pool.shape[2:], pool.dtype)
+            for pool in pools] + [
+            pltpu.SemaphoreType.DMA((len(pools), 2, pages)),
+            pltpu.SMEM((2,), jnp.int32),
             pltpu.VMEM((h_pad, _STAT_LANES), jnp.float32),
             pltpu.VMEM((h_pad, _STAT_LANES), jnp.float32),
             pltpu.VMEM((h_pad, c), jnp.float32),
         ],
     )
-    meta = jnp.concatenate([
-        jnp.asarray(layer, dtype=jnp.int32).reshape(1),
-        jnp.asarray(lengths, dtype=jnp.int32),
-        jnp.asarray(page_table, dtype=jnp.int32).reshape(-1)])
-    # Scale pools ride as [L, P, K, pt] so the kernel's [K, Tb] block
-    # matches the flat kernel's layout exactly.
     acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -605,19 +778,32 @@ def flash_decode_attention_paged(q_pad, k_pool, v_pool, k_scale_t,
             jax.ShapeDtypeStruct((b, h_pad, _STAT_LANES), jnp.float32),
             jax.ShapeDtypeStruct((b, h_pad, _STAT_LANES), jnp.float32),
         ],
-        interpret=interpret,
-    )(meta, q_pad, k_pool, v_pool, k_scale_t, v_scale_t)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_PAGED_VMEM_LIMIT),
+        # (the kernel copies by hand: off the chip it takes the TPU
+        # interpreter, which models DMAs and semaphores)
+        interpret=pltpu.InterpretParams()
+        if interpret_off_chip(interpret) else False,
+        name="flash_decode_attention_paged",
+    )(jnp.asarray(layer, dtype=jnp.int32).reshape(1),
+      jnp.asarray(lengths, dtype=jnp.int32),
+      jnp.asarray(page_table, dtype=jnp.int32).reshape(-1), q_pad, *pools)
     return acc[:, :h], m[:, :h, 0], l[:, :h, 0]
 
 
 def _split_paged(side):
     """One paged pool side (models/paged.py layout) -> ([L, P, pt, C]
-    payload, [L, P, K, pt] f32 scales or None).  Payloads are stored
+    payload, [L, P, K, pt'] f32 scales or None).  Payloads are stored
     flat already; the scale transpose is a real copy, but of the small
-    f32 scale pool, once per step -- the stacked-cache discipline."""
+    f32 scale pool, once per step -- the stacked-cache discipline.
+    ``pt'`` is ``pt`` rounded up to a lane tile (128): the paged kernel
+    copies a scale page by hand, and a copy out of HBM moves whole lane
+    tiles (pages of 128 tokens and more are whole already)."""
     if is_quantized(side):
-        return side["int8"], side["scale"][..., 0] \
-            .transpose(0, 1, 3, 2).astype(jnp.float32)
+        return side["int8"], _pad_to(
+            side["scale"][..., 0].transpose(0, 1, 3, 2)
+            .astype(jnp.float32), 3, 128)
     return side, None
 
 
@@ -767,12 +953,12 @@ def flash_decode_append_paged(q, k_view, v_view, layer, k_new, v_new,
     stays its PHYSICAL page pools ([L, P, pt, C] payload views +
     [L, P, K, pt] scales from :func:`_split_paged`, scan-invariant) and
     the kernel resolves each slot's pages from the [B, pps] table
-    inside the grid -- no host-side gather, no logical-row
-    materialization.  The stacked-cache invariant differs here: the
-    POOL extent never has to divide a block size (pages ARE the
-    blocks), but the table must cover the logical extent the lengths
-    claim -- the allocator's ``ensure`` contract.  q/k_new/v_new/
-    lengths as in flash_decode_append."""
+    itself -- no host-side gather, no logical-row materialization.  The
+    stacked-cache invariant differs here: the POOL extent never has to
+    divide a block size (pages ARE the blocks), but the table must
+    cover the logical extent the lengths claim -- the allocator's
+    ``ensure`` contract.  q/k_new/v_new/lengths as in
+    flash_decode_append."""
     b, _, h, d = q.shape
     k_payload, k_scale_t = k_view
     v_payload, v_scale_t = v_view

@@ -15,8 +15,10 @@ its last axis, the v5e's own layout for a width that is no multiple of
 128 lanes), so a page is a ``[W, pt]`` tile as it lies: the score
 product is ``q [H, W] @ page [W, pt]`` and the value product contracts
 the token axis of both operands.  The ``[B, pps]`` page table is
-scalar-prefetched and walked inside the BlockSpec index maps
-(``flash_decode_attention_paged`` is the model): the logical view the
+scalar-prefetched and walked inside the BlockSpec index maps (as
+``flash_decode_attention_paged`` did until PR 30; that kernel now
+copies its live pages by hand, a form that fits this one as it
+stands: PERF.md section 7): the logical view the
 gather path materialises -- every slot's whole extent, 302 MB a layer
 at 32 slots x 8192 -- never exists, and a row of ``length`` tokens
 reads ``ceil(length / pt)`` pages.

@@ -399,7 +399,7 @@ def kernels(settings: dict, rehearse: bool, facts: dict) -> None:
     layers, layer, width = 2, 1, kv * hd
     chunk = min(512, extent)
     bf16 = jnp.bfloat16
-    keys = iter(jax.random.split(jax.random.PRNGKey(21), 64))
+    keys = iter(jax.random.split(jax.random.PRNGKey(21), 256))
 
     def normal(shape, dtype=bf16, scale=1.0):
         return (jax.random.normal(next(keys), shape, dtype=jnp.float32)
@@ -503,6 +503,64 @@ def kernels(settings: dict, rehearse: bool, facts: dict) -> None:
                   vv, starts, positions, page_table=table,
                   interpret=interpret),
               verify_reference)
+
+    # 5b. the paged form across the shapes its pages-a-group follows
+    # (ops/pallas_decode.py:paged_pages_per_step): page sizes 8..256 at
+    # pps 4, 16 and 64, bf16 and int8 pools, decode and the verify
+    # chunk -- a ragged batch with an inactive row in its middle (the
+    # rehearsal interprets the first and the last shape only).
+    paged_shapes = ((8, 64), (16, 16), (32, 64), (64, 16), (128, 16),
+                    (256, 4))
+    for page_tokens, slot_pages in paged_shapes[::5 if rehearse else 1]:
+        reach = page_tokens * slot_pages
+        live = jnp.asarray([reach - 1, 0, reach // 3, page_tokens + 1],
+                           dtype=jnp.int32)
+        rows = live.shape[0]
+        rows_k = normal((layers, rows, reach, kv, hd))
+        rows_v = normal((layers, rows, reach, kv, hd))
+        sweep_table = 1 + jax.random.permutation(
+            next(keys), rows * slot_pages).reshape(
+                rows, slot_pages).astype(jnp.int32)
+
+        def pool_of(side):
+            pages = side.reshape(layers, rows * slot_pages, page_tokens,
+                                 *side.shape[3:])
+            pool = jnp.zeros((layers, rows * slot_pages + 1)
+                             + pages.shape[2:], dtype=side.dtype)
+            return pool.at[:, sweep_table.reshape(-1)].set(pages)
+
+        dq = normal((rows, 1, heads, hd), scale=4.0)
+        dk, dv = normal((rows, 1, kv, hd)), normal((rows, 1, kv, hd))
+        sq = normal((rows, span, heads, hd), scale=4.0)
+        sk = normal((rows, span, kv, hd))
+        sv = normal((rows, span, kv, hd))
+        at = jnp.minimum(live[:, None] + jnp.arange(span)[None, :],
+                         reach - 1)
+        for form in ("bf16", "int8"):
+            if form == "int8":
+                sides = [quantize_kv(rows_k), quantize_kv(rows_v)]
+                ref = [dequantize_kv(s, bf16)[layer] for s in sides]
+                pools = [{"int8": pool_of(s["int8"].reshape(
+                              layers, rows, reach, width)),
+                          "scale": pool_of(s["scale"])} for s in sides]
+            else:
+                ref = [rows_k[layer], rows_v[layer]]
+                pools = [pool_of(s.reshape(layers, rows, reach, width))
+                         for s in (rows_k, rows_v)]
+            tag = f"pt={page_tokens},pps={slot_pages},{form}"
+            close(f"flash_decode_attention_paged[{tag}]",
+                  lambda pools=pools: flash_decode_append_paged(
+                      dq, *map(_split_paged, pools), jnp.int32(layer),
+                      dk, dv, sweep_table, live, interpret=interpret),
+                  lambda ref=ref: jax.jit(attention_decode_append)(
+                      dq, *ref, dk, dv, live))
+            close(f"flash_verify_append[paged,{tag}]",
+                  lambda pools=pools: flash_verify_append(
+                      sq, *map(_split_paged, pools), jnp.int32(layer),
+                      sk, sv, live, at, page_table=sweep_table,
+                      interpret=interpret),
+                  lambda ref=ref: jax.jit(_verify_reference)(
+                      *ref, sq, sk, sv, live, at))
 
     # 6. int8_matmul: the quantized unembed, decode rows and one
     # prefill chunk's rows.

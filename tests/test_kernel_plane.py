@@ -20,9 +20,9 @@ from aiko_services_tpu.ops import decode_backend, matmul_backend, topk
 from aiko_services_tpu.ops.layers import (attention_decode_append,
                                           attention_prefill)
 from aiko_services_tpu.ops.pallas_decode import (
-    _prep_query, _split_paged, flash_decode_append_paged,
+    _combine_self, _prep_query, _split_paged, flash_decode_append_paged,
     flash_decode_attention, flash_decode_attention_paged,
-    flash_verify_append)
+    flash_verify_append, paged_grid_steps, paged_pages_per_step)
 from aiko_services_tpu.ops.pallas_matmul import int8_matmul
 from aiko_services_tpu.ops.pallas_topk import topk as pallas_topk
 
@@ -63,11 +63,15 @@ def _paged_case(key, dtype=jnp.float32, quantized=False):
             v_new, dict(L=L, P=P, pt=pt, B=B, K=K, G=G, hd=hd, C=C))
 
 
-def test_paged_kernel_bitwise_matches_dense_kernel():
+@pytest.mark.parametrize("pages_per_step", [1, 2, 4, 8])
+def test_paged_kernel_bitwise_matches_dense_kernel(pages_per_step):
     """f32 acceptance gate: the paged kernel walking the page table
     in-kernel is BITWISE identical to the dense split-K kernel run on
-    the gathered contiguous view (same block size -> same op sequence),
-    on every layer -- the strongest possible no-gather equivalence."""
+    the gathered contiguous view at ``block_t`` = one page, on every
+    layer and at every count of pages a group (a group's pages are
+    folded one by one, in order: the same op sequence) -- the strongest
+    possible no-gather equivalence.  8 pages a group is more than the
+    slot's 4: the pages past the table are dead."""
     (pool_k, pool_v, dense_k, dense_v, table, lengths, q, _, _,
      dims) = _paged_case(jax.random.PRNGKey(0))
     B, pt, K, hd, C = (dims["B"], dims["pt"], dims["K"], dims["hd"],
@@ -82,9 +86,210 @@ def test_paged_kernel_bitwise_matches_dense_kernel():
             block_t=pt, interpret=True)
         acc_p, m_p, l_p = flash_decode_attention_paged(
             q_pad, pool_k, pool_v, None, None, jnp.int32(layer), table,
-            lengths, interpret=True)
+            lengths, interpret=True, pages_per_step=pages_per_step)
         for dense, paged in ((acc_d, acc_p), (m_d, m_p), (l_d, l_p)):
             assert np.array_equal(np.asarray(dense), np.asarray(paged))
+
+
+def _ragged_paged_case(key, pool: str):
+    """Seven slots x 8 logical pages of 16 tokens whose lengths are 0,
+    1, an exact page multiple, one past it, the full extent, 0 again
+    (an inactive row between live ones) and a ragged middle; the last
+    row's first two pages ARE the fourth row's (a shared prefix: one
+    physical page under two rows).  Every table entry past a row's
+    length names a page of NaNs (and so do the inactive rows'): a copy
+    or a product of a dead page would show.  ``pool``: ``bf16`` (bf16
+    queries) or ``int8`` (f32 queries: the in-kernel dequantisation is
+    exact)."""
+    L, P, pt, K, G, hd, pps = 2, 40, 16, 2, 2, 16, 8
+    C = K * hd
+    lengths = jnp.asarray([0, 1, 32, 33, 128, 0, 70], dtype=jnp.int32)
+    B = lengths.shape[0]
+    poison = P - 1
+    table = np.full((B, pps), poison, dtype=np.int32)
+    free = iter(np.random.default_rng(0).permutation(np.arange(1, poison)))
+    for row, length in enumerate(np.asarray(lengths)):
+        for page in range(-(-int(length) // pt)):
+            table[row, page] = next(free)
+    table[6, :2] = table[3, :2]
+    table = jnp.asarray(table)
+    raw_k = jax.random.normal(key, (L, P, pt, K, hd), dtype=jnp.float32)
+    raw_v = jax.random.normal(jax.random.fold_in(key, 1),
+                              (L, P, pt, K, hd), dtype=jnp.float32)
+    if pool == "int8":
+        dtype = jnp.float32
+        qk, qv = quantize_kv(raw_k), quantize_kv(raw_v)
+        pools = tuple(
+            {"int8": side["int8"].reshape(L, P, pt, C),
+             "scale": side["scale"].at[:, poison].set(jnp.nan)}
+            for side in (qk, qv))
+        dense = (dequantize_kv(qk, dtype), dequantize_kv(qv, dtype))
+    else:
+        dtype = jnp.bfloat16
+        pools = tuple(
+            side.reshape(L, P, pt, C).astype(dtype)
+            .at[:, poison].set(jnp.nan) for side in (raw_k, raw_v))
+        dense = tuple(side.reshape(L, P, pt, K, hd) for side in pools)
+    # (the reference reads the dead pages as zeros: it masks them, and
+    # a masked NaN is still a NaN)
+    dense = tuple(jnp.nan_to_num(side) for side in dense)
+    return pools, dense, table, lengths, dtype, \
+        dict(B=B, K=K, H=K * G, hd=hd, pt=pt, pps=pps)
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+@pytest.mark.parametrize("pages_per_step", [1, 2, 4, 8])
+def test_paged_pages_per_step_match_dense_reference(pages_per_step, pool):
+    """The several-pages-a-group form against the dense reference over
+    the gathered view: every count of pages a group x raw and int8
+    pools, on lengths 0 / 1 / a page multiple / one past it / the full
+    extent, with a physical page shared by two rows, an inactive row
+    between live ones and every dead page full of NaNs."""
+    key = jax.random.PRNGKey(20 + pages_per_step)
+    pools, dense, table, lengths, dtype, dims = _ragged_paged_case(
+        key, pool)
+    B, K, H, hd = dims["B"], dims["K"], dims["H"], dims["hd"]
+    q = jax.random.normal(jax.random.fold_in(key, 2), (B, 1, H, hd),
+                          dtype=dtype)
+    k_new = jax.random.normal(jax.random.fold_in(key, 3), (B, 1, K, hd),
+                              dtype=dtype)
+    v_new = jax.random.normal(jax.random.fold_in(key, 4), (B, 1, K, hd),
+                              dtype=dtype)
+    q_pad, blocks, onehot, scale = _prep_query(q[:, 0], H, K, hd)
+    tolerance = 6e-2 if pool == "bf16" else 1e-4
+    for layer in range(2):
+        (k_payload, k_scale), (v_payload, v_scale) = (
+            _split_paged(pools[0]), _split_paged(pools[1]))
+        acc, m, l = flash_decode_attention_paged(
+            q_pad, k_payload, v_payload, k_scale, v_scale,
+            jnp.int32(layer), table, lengths, interpret=True,
+            pages_per_step=pages_per_step)
+        out = _combine_self(acc, m, l, q[:, 0], k_new, v_new, blocks,
+                            onehot, scale, K, hd).reshape(q.shape)
+        reference = attention_decode_append(
+            q, dense[0][layer][table].reshape(B, -1, K, hd).astype(dtype),
+            dense[1][layer][table].reshape(B, -1, K, hd).astype(dtype),
+            k_new, v_new, lengths)
+        assert np.isfinite(np.asarray(out, dtype=np.float32)).all()
+        np.testing.assert_allclose(
+            np.asarray(out, dtype=np.float32),
+            np.asarray(reference, dtype=np.float32),
+            atol=tolerance, rtol=tolerance)
+
+
+def _copy_schedule(lengths, pt, pps, pages):
+    """The paged kernel's copy schedule replayed on the host, statement
+    for statement (``_paged_kernel``: prime, then per group 'start the
+    next group -- the next live row's first at a row's end -- and fold
+    this one'): the (row, logical page, slot) copies in the order they
+    start, and the order they are waited for."""
+    lengths = np.minimum(np.asarray(lengths), pps * pt)
+    rows = len(lengths)
+    started, waited = [], []
+    slot, primed = 0, False
+
+    def start_group(row, group, slot):
+        for index in range(pages):
+            logical = group * pages + index
+            if logical * pt < lengths[row]:
+                started.append((row, logical, slot, index))
+
+    for b in range(rows):
+        groups = -(-lengths[b] // (pt * pages))
+        if groups > 0 and not primed:
+            start_group(b, 0, slot)
+            primed = True
+        for group in range(groups):
+            other = 1 - slot
+            if group + 1 < groups:
+                start_group(b, group + 1, other)
+            else:
+                following = b + 1
+                while following < rows and lengths[following] <= 0:
+                    following += 1
+                if following < rows:
+                    start_group(following, 0, other)
+            for index in range(pages):
+                logical = group * pages + index
+                if logical * pt < lengths[b]:
+                    waited.append((b, logical, slot, index))
+            slot = other
+    return started, waited
+
+
+@pytest.mark.parametrize("pages_per_step", [1, 2, 3, 4, 8, 16])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_paged_copy_schedule_copies_each_live_page_once(seed,
+                                                        pages_per_step):
+    """The kernel's copy schedule on the host: every live (row, page)
+    is copied exactly once and waited for exactly once, in the order
+    the rows and pages are folded; a dead page and an inactive row copy
+    nothing; a buffer slot is never overwritten while its copy is
+    awaited (at most one group in flight beside the one being folded);
+    and the steps the schedule takes are what ``paged_grid_steps``
+    counts."""
+    rng = np.random.default_rng(seed)
+    B, pps, pt = 12, 16, 32
+    lengths = rng.integers(1, pps * pt + 1, size=B)
+    lengths[rng.integers(0, B, size=3)] = 0          # inactive rows
+    lengths[1], lengths[2] = pps * pt, 5 * pt        # full; a multiple
+    if seed == 2:
+        lengths[:4] = 0                      # the first rows inactive
+    started, waited = _copy_schedule(lengths, pt, pps, pages_per_step)
+    live = [(row, page) for row in range(B)
+            for page in range(-(-lengths[row] // pt))]
+    assert sorted((row, page) for row, page, _, _ in started) == live
+    assert [(row, page) for row, page, _, _ in waited] == live
+    assert sorted(started) == sorted(waited)   # the same slot and index
+    # a (slot, index) buffer is free again before it is copied into
+    position = {copy: n for n, copy in enumerate(waited)}
+    busy = {}
+    for copy in started:
+        row, page, slot, index = copy
+        earlier = busy.get((slot, index))
+        if earlier is not None:
+            # the copy that used this buffer before was waited for (and
+            # folded) before the waits that follow this start
+            assert position[earlier] < position[copy]
+        busy[(slot, index)] = copy
+    live_steps, steps = paged_grid_steps(lengths, pt, pps, pages_per_step)
+    groups = {(row, page // pages_per_step) for row, page in live}
+    assert live_steps == len(groups)
+    assert steps == live_steps + int((lengths == 0).sum())
+
+
+def test_paged_pages_per_step_follows_shapes():
+    """Pages a group come from the static shapes alone: four
+    [128, 1024] bf16 pages (K and V, two groups each: 4 MiB), a page a
+    group where one page fills the budget or the slot holds one, int8
+    pools nearly twice the pages, tiny pages capped at 16, and an even
+    split where the budget does not divide the slot."""
+    def view(page_tokens, c, dtype, n_kv=None):
+        payload = jax.ShapeDtypeStruct((2, 9, page_tokens, c), dtype)
+        scale = None if n_kv is None else jax.ShapeDtypeStruct(
+            (2, 9, n_kv, page_tokens), jnp.float32)
+        return payload, scale
+    assert paged_pages_per_step(view(128, 1024, jnp.bfloat16), 16) == 4
+    assert paged_pages_per_step(view(512, 1024, jnp.bfloat16), 8) == 1
+    assert paged_pages_per_step(view(128, 1024, jnp.bfloat16), 1) == 1
+    # (int8: 135,168 B a page with its scales: 7 fit, 64 = 10 x 7 - 6)
+    assert paged_pages_per_step(view(128, 1024, jnp.int8, 8), 64) == 7
+    assert paged_pages_per_step(view(8, 1024, jnp.bfloat16), 64) == 16
+    assert paged_pages_per_step(view(128, 1024, jnp.bfloat16), 10) == 4
+    assert paged_pages_per_step(view(128, 1024, jnp.bfloat16), 9) == 3
+
+
+def test_paged_grid_steps_counts_steps_with_a_live_page():
+    """The host's count behind ``llm_decode_live_grid_share``: a row of
+    ``length`` tokens takes ``ceil(ceil(length / pt) / K)`` steps, each
+    with a live page; an inactive row its grid step, with none."""
+    lengths = np.array([0, 1, 128, 129, 631, 1024, 1025, 2048, 9999, 0])
+    assert paged_grid_steps(lengths, 128, 16, 1) == (
+        1 + 1 + 2 + 5 + 8 + 9 + 16 + 16, 58 + 2)
+    assert paged_grid_steps(lengths, 128, 16, 8) == (
+        1 + 1 + 1 + 1 + 1 + 2 + 2 + 2, 11 + 2)
+    assert paged_grid_steps(lengths, 128, 16, 4) == (
+        1 + 1 + 1 + 2 + 2 + 3 + 4 + 4, 18 + 2)
 
 
 def test_paged_append_matches_dense_reference_f32():
@@ -377,6 +582,60 @@ def test_chunk_verify_wired_into_speculative_loop():
 
 # -- fused int8 dequant-matmul ----------------------------------------------
 
+@pytest.mark.parametrize("pool", ["raw", "int8"])
+@pytest.mark.parametrize("pages_per_step", [1, 2, 4, 8])
+def test_paged_verify_chunk_pages_per_step(pages_per_step, pool):
+    """The verify chunk's [S*H] query rows (``qrow_period``) through
+    the several-pages-a-group paged form == the dense concat path's
+    cache part: a zero-start row, a row starting on a page boundary,
+    mid-page rows, an inactive row and a row whose first pages another
+    row shares -- the kernel's (acc, m, l) against the same statistics
+    of the dense scores over the gathered view."""
+    key = jax.random.PRNGKey(40 + pages_per_step)
+    L, K, G, hd, S = 2, 2, 2, 16, 5
+    H = K * G
+    pools, dense, table, _, _, dims = _ragged_paged_case(key, "int8")
+    if pool == "raw":
+        pools = tuple(jnp.where(
+            (jnp.arange(side.shape[1]) == side.shape[1] - 1)
+            [None, :, None, None], jnp.nan,
+            side.reshape(L, -1, dims["pt"], K * hd)) for side in dense)
+    B, pt, pps = dims["B"], dims["pt"], dims["pps"]
+    # (starts within each row's mapped pages: _ragged_paged_case)
+    starts = jnp.asarray([0, 1, 32, 29, 123, 0, 70], dtype=jnp.int32)
+    q = jax.random.normal(jax.random.fold_in(key, 1), (B, S, H, hd),
+                          dtype=jnp.float32)
+    q_pad, _, _, scale = _prep_query(q.reshape(B, S * H, hd), S * H, K,
+                                     hd, period=H)
+    layer = 1
+    (k_payload, k_scale), (v_payload, v_scale) = (
+        _split_paged(pools[0]), _split_paged(pools[1]))
+    acc, m, l = flash_decode_attention_paged(
+        q_pad, k_payload, v_payload, k_scale, v_scale, jnp.int32(layer),
+        table, starts, interpret=True, qrow_period=H,
+        pages_per_step=pages_per_step)
+    rows_k = dense[0][layer][table].reshape(B, pps * pt, K, hd)
+    rows_v = dense[1][layer][table].reshape(B, pps * pt, K, hd)
+    heads = jnp.arange(H) // G
+    logits = jnp.einsum("bshd,bthd->bsht", q, rows_k[:, :, heads]) * scale
+    valid = (jnp.arange(pps * pt)[None, :] < starts[:, None])[:, None,
+                                                                None, :]
+    logits = jnp.where(valid, logits, -1e30)
+    m_ref = logits.max(-1)
+    p = jnp.where(valid, jnp.exp(logits - m_ref[..., None]), 0.0)
+    out_ref = jnp.einsum("bsht,bthd->bshd", p, rows_v[:, :, heads])
+    acc = acc.reshape(B, S, H, K, hd)[:, :, jnp.arange(H), heads]
+    seen = np.asarray(starts) > 0
+    np.testing.assert_allclose(np.asarray(m.reshape(B, S, H))[seen],
+                               np.asarray(m_ref)[seen], atol=1e-4,
+                               rtol=1e-4)
+    np.testing.assert_allclose(np.asarray(l.reshape(B, S, H)),
+                               np.asarray(p.sum(-1)), atol=1e-4,
+                               rtol=1e-4)
+    np.testing.assert_allclose(np.asarray(acc), np.asarray(out_ref),
+                               atol=1e-4, rtol=1e-4)
+
+
 def test_int8_matmul_matches_xla():
     """Exact on exactly-representable inputs; f32 accumulation-order
     tolerance on gaussian bf16 -- vs the XLA reference
@@ -581,3 +840,41 @@ def test_batcher_sample_top_k_round_trip():
         batcher.run_until_drained(max_steps=200)
         streams[label] = collected
     assert streams["top1"] == streams["greedy"]
+
+
+@pytest.mark.parametrize("attention", ["flash", "dense"])
+def test_batcher_counts_live_grid_share(attention):
+    """Where the paged kernel serves decode, every retired device-loop
+    block carries the kernel's (live, all) steps at its first step and
+    the pages a step -- counted from the host's own lengths -- to
+    ``take_block_stats`` and the ``demux`` phase's info; under another
+    backend nothing is counted."""
+    from aiko_services_tpu.models.batching import (ContinuousBatcher,
+                                                   Request)
+
+    config = dataclasses.replace(
+        llama.LlamaConfig.tiny(vocab_size=64, max_seq=64),
+        decode_attention=attention)
+    params = llama.init_params(jax.random.PRNGKey(0), config)
+    phases = []
+    batcher = ContinuousBatcher(
+        params, config, max_slots=3, decode_block_tokens=4,
+        kv_page_tokens=8, prefill_chunk=16,
+        trace=lambda name, ms, info: phases.append((name, info)))
+    for index, prompt in enumerate(([5, 9, 2, 7], list(range(1, 20)))):
+        batcher.submit(Request(f"r{index}", prompt, max_new_tokens=9,
+                               emit=lambda rid, tok, fin: None))
+    batcher.run_until_drained(max_steps=200)
+    stats = batcher.take_block_stats()
+    if attention == "dense":
+        assert stats == [] and batcher._paged_pages is None
+        return
+    pages = batcher._paged_pages
+    assert pages == 8 and len(stats) == batcher.blocks_retired > 0
+    for observed in stats:
+        assert observed["paged_pages_per_step"] == pages
+        live = observed["paged_grid_steps_live"]
+        assert 1 <= live <= 2       # one step a decoding row
+        assert observed["paged_grid_steps"] == 3     # and an idle one
+    assert [info for name, info in phases
+            if name == "demux" and info] == stats
