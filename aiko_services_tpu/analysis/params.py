@@ -327,9 +327,6 @@ ELEMENT_PARAMETERS: dict[tuple[str, str], dict[str, ParamSpec]] = {
         "prefix_min_tokens": ParamSpec(
             "shortest prompt the prefix cache will index or match",
             number=True, minimum=1),
-        "decode_block": ParamSpec(
-            "fused decode steps per dispatch (host-pipelined path)",
-            number=True, minimum=1),
         "inflight": ParamSpec(
             "decode blocks kept in flight, chained device-side",
             number=True, minimum=1),

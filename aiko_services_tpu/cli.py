@@ -505,7 +505,7 @@ def loadgen(host, port, rate, overload, frames, deadline_ms, busy_ms):
     """Open-loop mixed-tenant load against a gateway: an interactive
     tenant at --rate plus a batch tenant at --rate * --overload,
     per-class p50/p99/goodput and per-tenant shed/reject counts as
-    JSON (the same generator bench_pipeline_gateway drives)."""
+    JSON."""
     import json as json_module
     import threading
 

@@ -72,8 +72,7 @@ class LLMService(Actor):
     def __init__(self, name: str = "llm", runtime=None,
                  config: llama.LlamaConfig | None = None,
                  params=None, tokenizer=None, max_slots: int = 8,
-                 checkpoint: str | None = None, seed: int = 0,
-                 decode_block: int = 1, inflight: int = 2):
+                 checkpoint: str | None = None, seed: int = 0):
         super().__init__(name, PROTOCOL_LLM, tags=["ec=true"],
                          runtime=runtime)
         if config is None:
@@ -83,14 +82,10 @@ class LLMService(Actor):
                 llama.init_params(jax.random.PRNGKey(seed), config),
                 checkpoint)
         self.tokenizer = tokenizer or ByteTokenizer()
-        # decode_block > 1 with inflight > 1 is the pipelined serving
-        # path (fused multi-step blocks chained device-side) -- the same
-        # configuration the bench runs; the wire-facing server defaults
-        # stay at one-step dispatches so token streaming is per-step.
+        # One-step dispatches, so the wire-facing server streams its
+        # tokens per step.
         self.batcher = ContinuousBatcher(params, config,
-                                         max_slots=max_slots,
-                                         decode_block=decode_block,
-                                         inflight=inflight)
+                                         max_slots=max_slots)
         # Keyed by (response_topic, request_id): two callers independently
         # choosing the same request_id (both starting at "1") must not
         # collide -- the response topic is the caller's identity.
@@ -206,11 +201,10 @@ class LLM(PipelineElement):
     ``vocab_size``/``max_seq``/``seed`` (local tiny config),
     ``attention`` (``dense`` | ``flash`` -- the Pallas long-context
     prefill path, 2.5x dense at 8k context), ``quantize`` (weight-only
-    int8: halves decode's HBM stream), ``decode_block`` (fuse N decode
-    steps per device dispatch: amortizes host round trips), ``inflight``
-    (keep N fused/loop blocks in flight, chained device-side: hides the
-    dispatch round trip behind device compute), ``max_slots`` (device
-    batch width: size to the expected concurrent-frame count; decode is
+    int8: halves decode's HBM stream), ``inflight`` (keep N device-loop
+    blocks in flight, chained device-side: hides the dispatch round
+    trip behind device compute), ``max_slots`` (device batch width:
+    size to the expected concurrent-frame count; decode is
     weight-HBM-bound at short context, so wider batches decode more
     frames' requests per block at nearly the same step time).
 
@@ -243,7 +237,7 @@ class LLM(PipelineElement):
     REFUSED when the model is built (and by the create-time parameter
     check), each by its parameter's name: ``quantize: int8``,
     ``speculative`` / ``spec_tokens`` / ``spec_window``,
-    ``prefix_cache: on``, ``decode_block`` > 1, ``kv_page_tokens: 0``,
+    ``prefix_cache: on``, ``kv_page_tokens: 0``,
     ``model``, a ``placement`` of more than one chip.  Its retired
     decode blocks also observe ``llm_moe_experts_touched`` and
     ``llm_moe_load_imbalance`` into the telemetry registry (and the
@@ -314,6 +308,9 @@ class LLM(PipelineElement):
     # Model-config parameters, resolved ON THE EVENT LOOP (stream
     # parameter precedence reads the pipeline's current-stream context,
     # which only the loop thread maintains) and shipped to the worker.
+    # ``decode_block`` (the fused-block driver's knob, gone) is resolved
+    # only so that model build refuses it by name instead of serving by
+    # the per-token tick in silence (families.family_spec_error).
     _MODEL_PARAMS = ("checkpoint", "tokenizer", "vocab_size", "max_seq",
                      "seed", "attention", "model", "family", "widths",
                      "quantize",
@@ -502,7 +499,6 @@ class LLM(PipelineElement):
         self._batcher = ContinuousBatcher(
             params, config,
             max_slots=int(settings.get("max_slots", 8)),
-            decode_block=int(settings.get("decode_block", 1)),
             inflight=int(settings.get("inflight", 2)),
             decode_block_tokens=int(
                 settings.get("decode_block_tokens", 0)),

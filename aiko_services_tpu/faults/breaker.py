@@ -12,9 +12,8 @@ through half-open -- success recloses, failure reopens.
 
 Owned by the pipeline's event loop but read by the metrics exporter
 thread, so state transitions take a lock.  ``transitions`` records
-``(state, monotonic_time)`` pairs -- the bench derives
-open->half-open->closed latency from it, and tests assert the exact
-state walk.
+``(state, monotonic_time)`` pairs: tests assert the exact state
+walk.
 """
 
 from __future__ import annotations

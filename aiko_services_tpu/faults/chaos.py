@@ -92,9 +92,7 @@ def _pilot_definition(name: str, journal_dir: str, busy_ms: float,
     """The controller-mode pilot: same two-stage graph, plus its own
     gateway front door, a deliberately unmeetable SLO (p99 far below
     the stage busy time, so sustained load burns the budget
-    immediately), and the fleet controller armed to scale out.
-    ``bench_pipeline_controller`` reuses this with a wider
-    ``fleet_max`` for the 1->3->1 ramp."""
+    immediately), and the fleet controller armed to scale out."""
     base = _definition(name, journal_dir, busy_ms)
     base["parameters"].update({
         "gateway": "on",

@@ -2,7 +2,7 @@
 door.  ``qos`` is the one admission authority the engine's four former
 admission planes consult; ``server`` is the HTTP + WebSocket service
 that funnels client connections into pipeline streams; ``loadgen`` is
-the open-loop mixed-tenant load generator the bench and CLI drive.
+the open-loop mixed-tenant load generator the CLI and tests drive.
 
 Import discipline: this package root re-exports only the jax-free QoS
 authority (the engine seams import it on their hot paths); the server

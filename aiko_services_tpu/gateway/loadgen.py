@@ -11,8 +11,8 @@ thread that tallies results, rejections and backpressure.
 Latencies come from the gateway's own ``e2e_ms`` stamp (admission ->
 result, the server-side view of the session SLO); per-class p50/p99,
 goodput (ok results / wall), and shed/reject counts aggregate across
-sessions.  Reused by ``bench_pipeline_gateway``, the ``loadgen`` CLI
-command, and the overload fairness tests.
+sessions.  Used by the ``loadgen`` CLI command and the overload
+fairness tests.
 """
 
 from __future__ import annotations

@@ -439,8 +439,7 @@ class StreamingAsr:
       window -- the rolling re-encode strategy, one compiled shape --
       updating ``partial_text`` (the current revisable hypothesis) and
       ``stable_text`` (the prefix two consecutive hypotheses agree on).
-      First-word latency is bounded by the hop, not ``chunk_seconds``
-      (~4000x realtime per the bench, so a 1 s hop costs ~2.5 ms).
+      First-word latency is bounded by the hop, not ``chunk_seconds``.
     - **energy endpointing**: with ``endpoint_silence`` set, a trailing
       silence of that many seconds after detected speech finalizes the
       utterance immediately instead of waiting for the chunk to fill.

@@ -14,33 +14,29 @@ token streams.
   chunk-at-a-time straight into the admitted slot's region of the
   batched cache (``llama.prefill_into_slot``; no scratch cache: the
   donated cache is written once per chunk, after the layer scan, and
-  only where the chunk lands), interleaved with decode ticks.  With
-  ``decode_block == 1`` each ``step()`` prefills at most ONE
+  only where the chunk lands), interleaved with decode ticks.  On the
+  per-token tick each ``step()`` prefills at most ONE
   ``prefill_chunk`` -- a long prompt never stalls active decodes beyond
-  one chunk's latency.  With ``decode_block > 1`` (the pipelined path,
-  below) a burst of admissions prefills one chunk PER admitting slot
-  per step: the chunks are async dispatches chained on the cache, so a
-  burst costs device time, not host round trips, and decode stall is
-  bounded by one fused block's latency anyway;
+  one chunk's latency.  On the device loop (below) a burst of
+  admissions prefills one chunk PER admitting slot per step: the
+  chunks are async dispatches chained on the cache, so a burst costs
+  device time, not host round trips, and decode stall is bounded by
+  one block's latency anyway;
 - finished sequences (EOS or token budget) free their slot immediately;
   a long generation never blocks a short one (continuous, not static,
   batching);
-- with ``decode_block > 1`` the decode loop is PIPELINED: the batcher
-  keeps ``inflight`` fused blocks in flight, chaining each dispatch off
-  the previous block's DEVICE-side carries (tokens/lengths/key/cache --
-  ``llama.decode_block`` returns them) so the host never blocks on a
-  device result between dispatches; emitted tokens are copied back
-  asynchronously and retired one block behind.  A request's tokens past
-  its EOS/budget inside in-flight blocks are discarded host-side (the
-  same overshoot semantics a single fused block already had);
 - with ``decode_block_tokens > 0`` (ISSUE 8) generation is DEVICE
   RESIDENT: ``step()`` dispatches ``llama.decode_loop`` blocks -- a
   ``lax.while_loop`` with on-device sampling, per-slot stop detection
   (EOS + budget + cache boundary) and an emitted-token ring in the
   carry -- and the host pays ONE counted fetch per retired block (the
   ``fetch`` hook, wired to the pipeline's TransferLedger by the LLM
-  element) instead of one round trip per token.  Admission and
-  eviction happen only at block boundaries; ``speculative:
+  element) instead of one round trip per token.  The batcher keeps
+  ``inflight`` blocks in flight, chaining each dispatch off the
+  previous block's DEVICE-side carries, so the host never blocks on a
+  device result between dispatches; a request's tokens past its
+  EOS/budget inside in-flight blocks are discarded host-side.
+  Admission and eviction happen only at block boundaries; ``speculative:
   ngram|draft`` layers multi-token decoding onto the loop with
   acceptance bookkeeping entirely on-device;
 - with ``kv_page_tokens > 0`` the KV cache is PAGED (models/paged.py):
@@ -157,7 +153,7 @@ def model_family(config):
     """The model family that serves ``config``, chosen by its type: the
     module whose functions the batcher calls (``init_cache``,
     ``cache_array``, ``prefill_into_slot(s)``, ``decode_step``,
-    ``decode_block``, ``decode_loop``, ``check_serving``,
+    ``decode_loop``, ``check_serving``,
     ``_matmul_safe_config``; sampling is shared).  A family may also
     say ``ADMISSION_LOGITS_AT_LAST`` (its admission computes the logits
     of one chunk position, which the batcher then names), leave out
@@ -187,19 +183,6 @@ def _prefetch(tree) -> None:
             leaf.copy_to_host_async()
 
 
-class _InflightBlock:
-    """One dispatched-but-unretired fused decode block."""
-    __slots__ = ("emitted", "snapshot", "firsts", "steps")
-
-    def __init__(self, emitted, snapshot, firsts, steps):
-        self.emitted = emitted        # [steps, B] device, copy in flight
-        self.snapshot = snapshot      # [(slot, request)] active at dispatch
-        # ([(slot, request)], stacked first-token device array) or None:
-        # admissions folded into this block, fetched in ONE host copy.
-        self.firsts = firsts
-        self.steps = steps
-
-
 class _LoopBlock:
     """One dispatched-but-unretired device-resident generation block
     (llama.decode_loop).  ``tree`` holds every device array the retire
@@ -221,7 +204,7 @@ class ContinuousBatcher:
                  config: llama.LlamaConfig | deepseek.DeepseekConfig,
                  max_slots: int = 8, max_seq: int | None = None,
                  prefill_chunk: int = 512, rng_seed: int = 0,
-                 decode_block: int = 1, inflight: int = 2,
+                 inflight: int = 2,
                  cache_put: Callable | None = None,
                  decode_block_tokens: int = 0,
                  speculative: str = "off", spec_tokens: int = 4,
@@ -243,21 +226,15 @@ class ContinuousBatcher:
         self.max_slots = max_slots
         self.max_seq = max_seq or config.max_seq
         self.prefill_chunk = min(prefill_chunk, self.max_seq)
-        # >1: fuse that many decode iterations (sampling included) into
-        # one device dispatch -- the host round trip stops bounding
-        # tokens/s.  Tokens a request emits past its EOS/budget inside a
-        # block are discarded host-side.
-        self.decode_block = max(1, int(decode_block))
-        # How many fused blocks to keep in flight (decode_block > 1
-        # only).  Each dispatch chains off the previous block's device
-        # carries, so depth d hides up to d * block_compute of host
-        # round-trip latency behind device work.
+        # How many device-loop blocks to keep in flight.  Each dispatch
+        # chains off the previous block's device carries, so depth d
+        # hides up to d * block_compute of host round-trip latency
+        # behind device work.
         self.inflight = max(1, int(inflight))
         # Device-resident generation (ISSUE 8): > 0 sizes the emitted
         # ring of llama.decode_loop blocks -- sampling, stop detection
         # and (optionally) speculation run inside one dispatch, the
-        # host fetches once per block.  Supersedes decode_block when
-        # set.
+        # host fetches once per block.  0 is the per-token tick.
         self.decode_block_tokens = max(0, int(decode_block_tokens))
         self.device_loop = self.decode_block_tokens > 0
         # Normalized exactly as the create-time domain check
@@ -298,7 +275,7 @@ class ContinuousBatcher:
         self.spec_window = max(4, int(spec_window))
         # Restrict sampled rows to the k highest logits (0 = full
         # categorical).  Static per-trace: rides llama.select_tokens /
-        # decode_loop / decode_block through the ops top-k interface
+        # decode_loop through the ops top-k interface
         # (the Pallas kernel on TPU, lax.top_k elsewhere); greedy rows
         # are unaffected either way.  Bounded at build to the kernel's
         # lane cap so a CPU-tested config cannot blow up mid-serving
@@ -314,8 +291,7 @@ class ContinuousBatcher:
         family.check_serving(
             speculative=self.speculative,
             prefix_cache=_knob_on(prefix_cache, default=False),
-            kv_page_tokens=max(0, int(kv_page_tokens)),
-            decode_block=1 if self.device_loop else self.decode_block)
+            kv_page_tokens=max(0, int(kv_page_tokens)))
         self._draft = draft_params(params) \
             if self.speculative == "draft" else None
         # Paged KV cache (models/paged.py): fixed-size pages + per-slot
@@ -395,20 +371,13 @@ class ContinuousBatcher:
         self.pending: list[Request] = []
         self._prefilling: list[int] = []      # slot FIFO, round-robin
         self._key = jax.random.PRNGKey(rng_seed)
-        # pipelining state (decode_block > 1): device-side carries of
-        # the latest dispatched block, cached device mirrors of the
-        # active/temperature rows (re-uploaded only when they change),
-        # first-token futures from prefill completions not yet folded
-        # into a dispatch, and the in-flight block queue.
-        self._chain: tuple | None = None      # (tokens_dev, lengths_dev)
-        self._active_dev = None
-        self._temps_dev = None
+        # device-loop state: first-token futures from prefill
+        # completions not yet folded into a dispatch, the chained
+        # carries of the latest loop block, the in-flight loop-block
+        # queue, host mirrors of per-slot eos rows and a conservative
+        # length upper bound for page allocation while blocks are in
+        # flight.
         self._pending_first: dict[int, tuple] = {}   # slot -> (req, dev)
-        self._inflight: deque[_InflightBlock] = deque()
-        # device-loop state: the chained carries of the latest loop
-        # block, the in-flight loop-block queue, host mirrors of
-        # per-slot eos rows and a conservative length upper bound for
-        # page allocation while blocks are in flight.
         self._loop_chain: dict | None = None
         self._loop_inflight: deque[_LoopBlock] = deque()
         self._eos_width = 1
@@ -501,7 +470,6 @@ class ContinuousBatcher:
             self._lengths_upper[slot] = 0
             self.current[slot] = 0
             self.temperatures[slot] = request.temperature
-            self._temps_dev = None
             self.decoding[slot] = False
             self._set_eos_row(slot, request.eos_tokens)
             self._prefilling.append(slot)
@@ -523,21 +491,20 @@ class ContinuousBatcher:
 
     def _prefill_tick(self):
         """Advance admissions by one chunk (<= prefill_chunk tokens)
-        each.  Pipelined path (decode_block > 1): every admitting slot
-        advances -- a multi-slot burst runs as ONE batched dispatch
+        each.  Device loop: every admitting slot advances -- a
+        multi-slot burst runs as ONE batched dispatch
         (``llama.prefill_into_slots``: the [N*S, dim] matmuls feed the
         MXU far better than N serialized [S, dim] dispatches), falling
         back to per-slot dispatches for the flash-attention config.
-        Synchronous path (decode_block == 1): at most ONE chunk total,
-        preserving the one-chunk decode-stall bound (each chunk's
-        completion fetch blocks the host there).  Returns the number
-        of chunks written."""
-        pipelined = self.decode_block > 1 or self.device_loop
-        if (pipelined and len(self._prefilling) > 1
+        Per-token tick: at most ONE chunk total, preserving the
+        one-chunk decode-stall bound (each chunk's completion fetch
+        blocks the host there).  Returns the number of chunks
+        written."""
+        if (self.device_loop and len(self._prefilling) > 1
                 and hasattr(self._family, "prefill_into_slots")
                 and self.config.attention != "flash"):
             return self._prefill_tick_batched()
-        budget = len(self._prefilling) if pipelined \
+        budget = len(self._prefilling) if self.device_loop \
             else min(1, len(self._prefilling))
         chunks = 0
         for _ in range(budget):
@@ -634,7 +601,7 @@ class ContinuousBatcher:
         """Account one written chunk; on the FINAL chunk, sample the
         first generated token from the last real prompt position's
         logits ([1, S, vocab] row) and hand the slot to decode --
-        without fetching on the pipelined path (the device scalar folds
+        without fetching on the device loop (the device scalar folds
         into the next block dispatch and emits when that block
         retires)."""
         prompt = request.prompt_tokens
@@ -658,8 +625,7 @@ class ContinuousBatcher:
         self.lengths[slot] = len(prompt)
         self._lengths_upper[slot] = len(prompt)
         self.decoding[slot] = True
-        self._active_dev = None
-        if self.device_loop or self.decode_block > 1:
+        if self.device_loop:
             # No host copy here: the retire fetches the CONCATENATED
             # firsts array of the block this admission folds into.
             self._pending_first[slot] = (request, first)
@@ -723,34 +689,9 @@ class ContinuousBatcher:
                                        for block in new)})
                 if self._loop_inflight:
                     self._retire_loop_block()
-            return sum(1 for r in self.slots if r is not None)
-        if self.decode_block > 1:
-            if decoding:
-                # Top the pipeline up to `inflight` blocks, then retire
-                # the oldest: steady state is one dispatch + one retire
-                # per step, with the retire's host copy overlapping the
-                # newer blocks' device compute.  Stop early once the
-                # outstanding blocks already cover every active
-                # request's remaining budget (EOS can still cut a
-                # stream shorter; that overshoot is discarded).
-                remaining = max(
-                    self.slots[i].max_new_tokens - self.slots[i].generated
-                    for i in decoding if self.slots[i] is not None)
-                blocks = 0
-                while (len(self._inflight) < self.inflight
-                       and len(self._inflight) * self.decode_block
-                       < remaining):
-                    if self._dispatch_block(decoding) is False:
-                        break
-                    blocks += 1
-                if traced:
-                    self._phase("dispatch", {"blocks": blocks,
-                                             "slots": len(decoding)})
-            if self._inflight:
-                self._retire_block()
         elif decoding:
             self._decode_tick(decoding)
-        return sum(1 for r in self.slots if r is not None)
+        return self.active_count
 
     def _phase(self, name: str, info: dict | None = None) -> None:
         """A tick phase ended now: hand its name and length to the
@@ -804,96 +745,6 @@ class ContinuousBatcher:
             token = int(next_tokens[i])
             self.current[i] = token
             self._emit(request, token)
-        if self.trace is not None:
-            self._phase("demux")
-
-    def _dispatch_block(self, decoding: list[int]):
-        """Enqueue one fused decode block chained off the previous
-        block's device carries.  No host synchronization: tokens and
-        lengths come from the chain (with prefill-completion overrides
-        applied on device), the key chains through the kernel, and the
-        emitted tokens start copying to the host asynchronously."""
-        if self._pages is not None:
-            for slot in decoding:
-                if not self._ensure_pages(
-                        slot, int(self._lengths_upper[slot])
-                        + self.decode_block + 1):
-                    return False        # retire in-flight blocks first
-            self._sync_page_table()
-        if self._chain is None:
-            tokens = jnp.asarray(self.current)
-            lengths = jnp.asarray(self.lengths)
-        else:
-            tokens, lengths = self._chain
-        first_meta, first_vals = [], []
-        for slot in sorted(self._pending_first):
-            request, first = self._pending_first[slot]
-            tokens = tokens.at[slot].set(first[0])
-            lengths = lengths.at[slot].set(len(request.prompt_tokens))
-            first_meta.append((slot, request))
-            first_vals.append(first)
-        self._pending_first.clear()
-        if first_vals:
-            # ONE device array for all admissions folded into this
-            # block: the retire then pays a single host fetch instead of
-            # one blocking device-to-host copy per admitted request.
-            firsts_dev = jnp.concatenate(first_vals)
-            _prefetch(firsts_dev)
-            firsts = (first_meta, firsts_dev)
-        else:
-            firsts = None
-        if self._active_dev is None:
-            self._active_dev = jnp.asarray(self.decoding)
-        if self._temps_dev is None:
-            self._temps_dev = jnp.asarray(self.temperatures)
-        emitted, tokens_n, lengths_n, self._key, self.cache = \
-            self._family.decode_block(
-                self.params, self.config, tokens, self.cache, lengths,
-                self._active_dev, self._temps_dev, self._key,
-                num_steps=self.decode_block,
-                top_k=self.sample_top_k)
-        _prefetch(emitted)
-        self._chain = (tokens_n, lengths_n)
-        for i in decoding:                      # host mirror (clamped)
-            self.lengths[i] = min(self.lengths[i] + self.decode_block,
-                                  self.max_seq - 1)
-        for i in decoding:
-            self._lengths_upper[i] = min(
-                int(self._lengths_upper[i]) + self.decode_block,
-                self.max_seq)
-        self._inflight.append(_InflightBlock(
-            emitted, [(i, self.slots[i]) for i in decoding], firsts,
-            self.decode_block))
-
-    def _retire_block(self):
-        """Fetch the OLDEST in-flight block's tokens (the async copy
-        has been overlapping newer blocks' compute) and de-multiplex
-        host-side, truncating each request at its EOS/budget (overshoot
-        KV lands beyond the freed slot's next occupant's length mask,
-        so it is never read).  A slot freed and re-admitted while this
-        block was in flight is skipped via the request snapshot."""
-        blk = self._inflight.popleft()
-        emitted = np.asarray(blk.emitted)       # [steps, B]
-        if self.trace is not None:
-            self._phase("retire_wait", {"slots": len(blk.snapshot)})
-        self.steps += 1
-        if blk.firsts is not None:
-            first_meta, firsts_dev = blk.firsts
-            first_tokens = np.asarray(firsts_dev)    # one fetch for all
-            for (slot, request), token in zip(first_meta, first_tokens):
-                if self.slots[slot] is request and not request.done:
-                    token = int(token)
-                    self.current[slot] = token
-                    self._emit(request, token)
-        for slot, request in blk.snapshot:
-            if request is None or self.slots[slot] is not request:
-                continue
-            for block_step in range(blk.steps):
-                if self.slots[slot] is not request:     # finished
-                    break
-                token = int(emitted[block_step, slot])
-                self.current[slot] = token
-                self._emit(request, token)
         if self.trace is not None:
             self._phase("demux")
 
@@ -1168,7 +1019,7 @@ class ContinuousBatcher:
             min(int(upto_tokens), self.max_seq), self.kv_page_tokens)
         if self._pages.ensure(slot, pages):
             return True
-        if self._inflight or self._loop_inflight:
+        if self._loop_inflight:
             return False
         while True:
             victims = [(occupant.admit_seq, index)
@@ -1238,12 +1089,8 @@ class ContinuousBatcher:
         self.pending = revived + self.pending
         self._prefilling.clear()
         self._pending_first.clear()
-        self._inflight.clear()
         self._loop_inflight.clear()
-        self._chain = None
         self._loop_chain = None
-        self._active_dev = None
-        self._temps_dev = None
         self._force_inactive.clear()
         self.lengths[:] = 0
         self._lengths_upper[:] = 0
@@ -1354,12 +1201,6 @@ class ContinuousBatcher:
         lookups = self.prefix_lookups
         return self.prefix_hits / lookups if lookups else 0.0
 
-    def reset_prefix_stats(self) -> None:
-        """Zero the hit/lookup counters (bench warm-phase isolation)."""
-        if self._pages is not None:
-            self._pages.prefix_hits = 0
-            self._pages.prefix_lookups = 0
-
     def take_request_stats(self) -> list[dict]:
         """Drain per-request latency stamps ({"ttft_ms", "queue_ms",
         "admit_to_first_ms", "tpot_ms", "tokens"}) recorded at finish
@@ -1428,9 +1269,7 @@ class ContinuousBatcher:
         self._lengths_upper[slot] = 0
         self.current[slot] = 0
         self.temperatures[slot] = 0.0
-        self._temps_dev = None
         self.decoding[slot] = False
-        self._active_dev = None
         if self.device_loop:
             self._force_inactive.add(slot)
         if self._pages is not None:
@@ -1440,7 +1279,7 @@ class ContinuousBatcher:
         """Abandon a request by id: pending requests leave the queue; an
         admitted request frees its slot immediately, so it stops
         occupying a device batch row from the next dispatch on.  Tokens
-        for it inside already-in-flight fused blocks are discarded at
+        for it inside already-in-flight blocks are discarded at
         retire via the snapshot identity check -- the same overshoot
         semantics a finished request has.  ``emit`` is never called for
         a cancelled request.  Returns True when a request was found."""
@@ -1473,13 +1312,13 @@ class ContinuousBatcher:
 
     @property
     def blocks_in_flight(self) -> int:
-        """Dispatched-but-unretired fused/loop decode blocks; drive
-        step() until this reaches 0 to drain them."""
-        return len(self._inflight) + len(self._loop_inflight)
+        """Dispatched-but-unretired device-loop blocks; drive step()
+        until this reaches 0 to drain them."""
+        return len(self._loop_inflight)
 
     def run_until_drained(self, max_steps: int = 100_000) -> int:
         steps = 0
-        while (self.pending or self.active_count or self._inflight
+        while (self.pending or self.active_count
                or self._loop_inflight) and steps < max_steps:
             self.step()
             steps += 1
@@ -1521,7 +1360,7 @@ class MicroBatcher:
     (device work pipelines across groups).  Submit/flush/stop run on
     the event loop; only the queue crosses threads.
 
-    Scope note (found by the r07 bench attempt): a micro-batched
+    Scope note: a micro-batched
     element on a REPLICATED placed stage is not yet supported -- the
     replica hop lands each parked frame's inputs on ITS replica's
     submesh, and a cross-replica group would stack arrays from

@@ -44,9 +44,9 @@ benchmark's readers look for (``_prefill_into_slot_jit``,
 ``_decode_loop_jit``).
 
 What this family does not serve raises at create time and names the
-parameter (:func:`check_serving`): an int8 cache, speculation, the
-fused ``decode_block``, a dense (non-paged) cache, the prefix cache, a
-multi-chip placement (``elements/llm.py``).
+parameter (:func:`check_serving`): an int8 cache, speculation, a dense
+(non-paged) cache, the prefix cache, a multi-chip placement
+(``elements/llm.py``).
 """
 
 from __future__ import annotations
@@ -308,7 +308,7 @@ def cache_extent(cache: dict) -> int:
 
 
 def check_serving(*, speculative: str, prefix_cache: bool,
-                  kv_page_tokens: int, decode_block: int) -> None:
+                  kv_page_tokens: int) -> None:
     """What the latent family does not serve, refused when the batcher
     is created, each by its parameter's name."""
     if not kv_page_tokens:
@@ -318,11 +318,6 @@ def check_serving(*, speculative: str, prefix_cache: bool,
             f"speculative={speculative!r}: the deepseek_v3 family has "
             f"no draft or chunk-verify body over a latent cache; use "
             f"speculative: off")
-    if decode_block > 1:
-        raise ValueError(
-            f"decode_block={decode_block}: the deepseek_v3 family "
-            f"decodes step by step or in the device loop "
-            f"(decode_block_tokens > 0), not in fused blocks")
     if prefix_cache:
         raise ValueError(
             "prefix_cache=on: a clamped admission chunk re-writes "

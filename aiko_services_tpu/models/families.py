@@ -60,8 +60,6 @@ FAMILY_REFUSES: dict[str, dict[str, str]] = {
                        "cache",
         "spec_tokens": "speculation is not served",
         "spec_window": "speculation is not served",
-        "decode_block": "decode is step by step or the device loop "
-                        "(decode_block_tokens)",
         "prefix_cache": "re-written shared pages are not bit-equal "
                         "under grouped expert matmuls",
         "model": "a family is built from widths, not from a preset",
@@ -89,8 +87,6 @@ _OFF = ("", "off", "false", "0", "no", "none", "auto")
 
 
 def _is_default(name: str, value) -> bool:
-    if name in ("decode_block",):
-        return str(value).strip() in ("1", "1.0")
     if name in ("spec_tokens", "spec_window", "model"):
         return False
     return str(value).strip().lower() in _OFF
@@ -100,7 +96,13 @@ def family_spec_error(parameters: dict) -> str | None:
     """What is wrong with an LLM element's ``family`` / ``widths`` pair
     and the parameters beside it, or None: an unknown family, widths
     without a family (or not a mapping of numbers), a width the family
-    lacks, a parameter the family refuses."""
+    lacks, a parameter the family refuses -- and ``decode_block``,
+    which no family serves any more (an unknown parameter is ignored,
+    and this one would then decode by the per-token tick)."""
+    if "decode_block" in parameters:
+        return f"decode_block={parameters['decode_block']!r}: the " \
+               f"fused-block driver is gone; set decode_block_tokens " \
+               f"(the device loop)"
     family = parameters.get("family")
     widths = parameters.get("widths")
     if family is None:

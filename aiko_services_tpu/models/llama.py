@@ -31,7 +31,7 @@ from .quant import dequantize_kv, is_quantized, quantize_kv
 __all__ = ["LlamaConfig", "init_params", "partition_specs",
            "cache_specs", "init_cache", "cache_array", "cache_extent",
            "prefill", "prefill_with_aux", "prefill_into_slot",
-           "prefill_into_slots", "decode_step", "decode_block",
+           "prefill_into_slots", "decode_step",
            "decode_loop", "greedy_sample", "select_tokens",
            "resolve_decode_backend", "paged_decode_pages"]
 
@@ -67,8 +67,8 @@ class LlamaConfig:
     # (single fused dispatch).
     # NOTE: pallas_call has no GSPMD
     # partitioning rules, so under a tp-sharded cache keep "dense" (or
-    # shard_map the layer); single-chip and dp-sharded serving -- the
-    # benched configs -- compose fine.
+    # shard_map the layer); single-chip and dp-sharded serving
+    # compose fine.
     decode_attention: str = "auto"
     flash_decode_threshold: int = 1024
     # Weight-only-int8 matmul implementation for UNSTACKED quantized
@@ -779,8 +779,6 @@ def prefill_into_slot(params: dict, config: LlamaConfig,
         slot, start)
 
 
-prefill_into_slot.__wrapped__ = _prefill_into_slot_jit.__wrapped__
-
 
 @partial(jax.jit, static_argnames=("config",), donate_argnames=("cache",))
 def _prefill_into_slots_jit(params: dict, config: LlamaConfig,
@@ -817,8 +815,6 @@ def prefill_into_slots(params: dict, config: LlamaConfig,
         params, _matmul_safe_config(config, params), tokens, cache,
         slots, starts)
 
-
-prefill_into_slots.__wrapped__ = _prefill_into_slots_jit.__wrapped__
 
 
 def _cache_distributed(cache) -> bool:
@@ -936,9 +932,9 @@ def _decode_step_impl(params: dict, config: LlamaConfig,
     paged = is_paged(cache)
     extent = cache_extent(cache)
     if use_flash is None:
-        # In-jit callers (decode_block's scan, bench loops) have no
-        # sharding to inspect; resolve on static structure alone
-        # through the same ops capability probe the eager path uses.
+        # In-jit callers have no sharding to inspect; resolve on
+        # static structure alone through the same ops capability probe
+        # the eager path uses.
         use_flash = decode_backend(
             c.decode_attention, paged=paged, extent=extent,
             threshold=c.flash_decode_threshold,
@@ -1085,9 +1081,6 @@ def decode_step(params: dict, config: LlamaConfig, tokens: jax.Array,
                             use_flash=_resolve_decode_flash(config, cache))
 
 
-# In-jit composition hook (bench loops fuse N steps in one dispatch).
-decode_step.__wrapped__ = _decode_step_impl
-
 
 def greedy_sample(logits: jax.Array) -> jax.Array:
     return jnp.argmax(logits, axis=-1)
@@ -1122,71 +1115,6 @@ def select_tokens(key: jax.Array, logits: jax.Array,
         sampled = jax.random.categorical(
             key, logits.astype(jnp.float32) / safe, axis=-1)
     return jnp.where(temperatures > 0, sampled, greedy)
-
-
-@partial(jax.jit, static_argnames=("config", "num_steps", "use_flash",
-                                   "top_k"),
-         donate_argnames=("cache",))
-def _decode_block_jit(params: dict, config: LlamaConfig, tokens: jax.Array,
-                      cache: dict, lengths: jax.Array, active: jax.Array,
-                      temperatures: jax.Array, key: jax.Array, *,
-                      num_steps: int, use_flash: bool,
-                      top_k: int = 0) \
-        -> tuple[jax.Array, jax.Array, jax.Array, jax.Array, dict]:
-    """``num_steps`` decode iterations fused into ONE dispatch
-    (sampling included): a per-step host loop pays one dispatch and
-    one blocking token fetch per token, which this amortizes over the
-    block.
-
-    tokens: [B] current tokens; lengths: [B] write positions of ACTIVE
-    rows; active: [B] bool (inactive rows -- empty or mid-prefill slots
-    -- write to the trash position T-1 every step, exactly like the
-    single-step batcher tick, and attend nothing).  Returns
-    ``(emitted [num_steps, B], tokens' [B], lengths' [B], key', cache)``
-    -- the final carries come back as DEVICE arrays so the batcher can
-    dispatch block k+1 from block k's outputs without a host round trip
-    (the in-flight pipelining the serving loop is built on); the host
-    discards a row's tail after its EOS / budget and frees the slot --
-    the garbage KV written past that point sits beyond the freed slot's
-    next occupant's length mask.  Write positions clamp to the trash
-    position so a speculative block dispatched near the cache boundary
-    can never scatter out of bounds.
-    """
-    trash = cache_extent(cache) - 1
-
-    def body(carry, _):
-        tokens, cache, lengths, key = carry
-        positions = jnp.where(active, jnp.minimum(lengths, trash), trash)
-        logits, cache = _decode_step_impl(
-            params, config, tokens, cache, positions,
-            use_flash=use_flash, attend=jnp.where(active, positions, 0))
-        key, sub = jax.random.split(key)
-        tokens = select_tokens(sub, logits, temperatures,
-                               top_k=top_k).astype(jnp.int32)
-        lengths = lengths + active.astype(lengths.dtype)
-        return (tokens, cache, lengths, key), tokens
-
-    (tokens, cache, lengths, key), emitted = jax.lax.scan(
-        body, (tokens, cache, lengths, key), None, length=num_steps)
-    return emitted, tokens, lengths, key, cache
-
-
-def decode_block(params: dict, config: LlamaConfig, tokens: jax.Array,
-                 cache: dict, lengths: jax.Array, active: jax.Array,
-                 temperatures: jax.Array, key: jax.Array, *,
-                 num_steps: int, top_k: int = 0) \
-        -> tuple[jax.Array, jax.Array, jax.Array, jax.Array, dict]:
-    """num_steps fused decode iterations (see _decode_block_jit); the
-    flash-vs-dense choice resolves here on the concrete cache's
-    sharding, exactly as in :func:`decode_step`."""
-    config = _matmul_safe_config(config, params)
-    return _decode_block_jit(params, config, tokens, cache, lengths,
-                             active, temperatures, key,
-                             num_steps=num_steps, top_k=int(top_k),
-                             use_flash=_resolve_decode_flash(config, cache))
-
-
-decode_block.__wrapped__ = _decode_block_jit.__wrapped__
 
 
 # ---------------------------------------------------------------------------
@@ -1362,7 +1290,7 @@ def _chunk_verify(params, config: LlamaConfig, chunk, cache, starts,
     prefill pays.  Rejected drafts leave garbage KV beyond the
     advanced length, which the length masks never admit and later
     decode overwrites before exposing -- the same overshoot contract
-    the fused block path established.  Positions clamp to the trash
+    as a plain loop block's.  Positions clamp to the trash
     position at the cache boundary (rows there stop this iteration,
     and their clamped-position tokens are cut before emission).
 
@@ -1627,6 +1555,3 @@ def decode_loop(params: dict, config: LlamaConfig, tokens: jax.Array,
                             spec_window=max(1, int(spec_window)),
                             top_k=int(top_k),
                             use_flash=_resolve_decode_flash(config, cache))
-
-
-decode_loop.__wrapped__ = _decode_loop_jit.__wrapped__

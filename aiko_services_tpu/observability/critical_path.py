@@ -39,7 +39,8 @@ Buckets:
 Sums are honest, not residual-balanced: ``unattributed_ms`` reports
 what the evidence did not cover instead of silently inflating a
 bucket.  The acceptance bar (bucket totals within 5% of measured e2e
-on the bench pipeline) is enforced by ``tests/test_flight_recorder``.
+on a two-stage placed pipeline) is enforced by
+``tests/test_flight_recorder``.
 """
 
 from __future__ import annotations

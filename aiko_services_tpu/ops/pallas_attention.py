@@ -152,16 +152,15 @@ def flash_attention(q, k, v, q_offset=0, *, causal: bool = True,
     rounding; see _online_update).
 
     Default blocks (512 x 2048) are tuned on v5e at head_dim 64 / 8k
-    context -- the round-5 sweep with 600-iteration amortized min-of-3
+    context -- a sweep with 600-iteration amortized min-of-3
     timing: 30.3% of chip peak at 512x2048 vs 26.0% at the old 512x1024
     default, 29.4% at 1024x1024, 15.8% at 512x512; non-power-of-two and
     larger-k blocks all lose (640x2048 23.8%, 768x2048 26.6%, 896x2048
     22.9%, 512x3072 23.4%); 1024x2048 exceeds VMEM (the f32 [block_q,
     block_k] score tile is the binding constraint: 512x2048x4 B = 4 MB
     fits, 8 MB does not).  Earlier
-    rounds' claims of ~41% did not reproduce under this methodology and
-    were revised down (BENCH_r03-r05: 28-30%).  The non-matmul gap is VPU softmax
-    work, cut by the interior/boundary split (most blocks skip masking
+    claims of ~41% did not reproduce under this methodology and were
+    revised down (28-30%).  The non-matmul gap is VPU softmax work, cut by the interior/boundary split (most blocks skip masking
     entirely), the bf16 exp, and folding the scale into q; the d=64
     contraction half-feeds the 128-wide MXU, putting the practical
     ceiling near 50%.

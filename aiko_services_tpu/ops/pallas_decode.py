@@ -6,7 +6,7 @@ once -- but the dense path (ops/layers.py:attention_decode_append)
 materializes the [B, H, T] score/weight intermediates in HBM: at 8k
 context that chain (logits write, mask, max, exp, sum, cast, dot) moves
 more bytes than the cache itself, which is why measured HBM utilization
-collapsed from 0.78 at 1k to 0.44 at 8k (BENCH_r03).  Here the cache is
+collapsed from 0.78 at 1k to 0.44 at 8k.  Here the cache is
 the ONLY large HBM traffic: K/V blocks stream HBM->VMEM through the
 BlockSpec pipeline, scores and online-softmax statistics live in VMEM
 scratch across the T grid axis, and one [H, K*hd] accumulator is written
@@ -31,8 +31,8 @@ it is the MXU-friendly formulation):
   last live block (fetch skipped, compute skipped via pl.when), so
   short rows in a ragged batch do not pay full-T bandwidth;
 - block_t defaults to 2048 in the flat and stacked kernels: a grid
-  step's fixed cost dominates below that (v5e at 8k, BENCH_r03: 233
-  GB/s at 512, 367 at 1024, 410+ at 2048).  A dead step is not free
+  step's fixed cost dominates below that (v5e at 8k: 233 GB/s at
+  512, 367 at 1024, 410+ at 2048).  A dead step is not free
   either -- the pipeline evaluates every operand's index map and
   waits on its semaphore each step, ~0.2 us an operand (PR 30) -- which
   is why the PAGED kernel, whose blocks are pages of 128 tokens, does

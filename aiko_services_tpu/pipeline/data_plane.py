@@ -318,7 +318,8 @@ class PipeSender:
                     if tag:
                         meta["v"] = tag
                     # send() reports the exact wire bytes (prefix +
-                    # header + payload) -- the bench's byte accounting.
+                    # header + payload): the byte accounting of
+                    # ``data_plane_stats``.
                     total += self._client.send(view,
                                                name=json.dumps(meta))
             except (ConnectionError, OSError) as error:

@@ -105,8 +105,7 @@ class TransferLedger:
     counter there.  ``explicit`` counts engine-initiated fetches
     (host-typed inputs, process-boundary encodes), each ONE
     ``jax.device_get`` of the whole tree regardless of leaf count.
-    Healthy pipelines keep ``implicit`` at 0; the bench reports it as
-    ``swag_host_transfers``.
+    Healthy pipelines keep ``implicit`` at 0.
     """
 
     def __init__(self, policy: str = "allow"):
@@ -118,9 +117,9 @@ class TransferLedger:
         self.implicit = 0
         self.explicit = 0
         # Labeled sub-counts of ``explicit`` (e.g. the LLM element's
-        # per-decode-block fetch, label "llm_block"): lets tests and
-        # the bench assert a path pays EXACTLY one fetch per unit of
-        # work, not merely "some" fetches.
+        # per-decode-block fetch, label "llm_block"): lets tests
+        # assert a path pays EXACTLY one fetch per unit of work, not
+        # merely "some" fetches.
         self.explicit_by_label: dict = {}
         # Counters are bumped from the event loop AND stage-worker
         # threads (pipeline/stages.py): unsynchronized += would lose

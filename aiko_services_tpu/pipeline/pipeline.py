@@ -1530,8 +1530,7 @@ class Pipeline(Actor):
 
     def transfer_stats(self) -> dict:
         """Device-resident swag accounting: the TransferLedger counters
-        plus the live streams' dispatch-window stats (bench.py reports
-        ``implicit`` as ``swag_host_transfers``)."""
+        plus the live streams' dispatch-window stats."""
         stats = dict(self.transfer_ledger.stats)
         stats["window"] = {stream_id: stream.device_window.stats
                            for stream_id, stream in self.streams.items()}
@@ -1569,8 +1568,7 @@ class Pipeline(Actor):
     def stage_stats(self) -> dict:
         """Stage-parallel accounting: per-stage admission window state,
         occupancy over the scheduler's window, placed chip counts and
-        the measured cost profile (the bench's ``stage_occupancy_*``
-        keys read the occupancy values)."""
+        the measured cost profile."""
         if self.stage_scheduler is None:
             return {}
         stats = self.stage_scheduler.stats
@@ -1584,8 +1582,7 @@ class Pipeline(Actor):
         return stats
 
     def fusion_stats(self) -> dict:
-        """Fused-segment accounting: segment/dispatch totals the bench
-        reports as ``fused_segments`` / ``fused_dispatches_per_frame``."""
+        """Fused-segment accounting: segment/dispatch totals."""
         return {"segments": len(self.fused_segments),
                 "fused_elements": sum(len(s.nodes)
                                       for s in self.fused_segments),
@@ -1597,7 +1594,7 @@ class Pipeline(Actor):
     # -- binary data plane (ISSUE 9) ---------------------------------------
 
     def data_plane_stats(self) -> dict:
-        """The control/data-split accounting the bench and tests read:
+        """The control/data-split accounting:
         frames/bytes per path, negotiated fallbacks, endpoint drops and
         expired claims, per-peer sender state."""
         stats = dict(self._plane_counts)

@@ -163,8 +163,8 @@ class JitCache:
 
     ``cache(fn)(*args)`` compiles once per distinct (shape, dtype)
     signature and replays thereafter; ``stats`` exposes hit/miss/entry
-    counters for the Metrics element, the dashboard share dict
-    (``Pipeline.jit_stats``) and the bench's ``jit_cache_*`` keys.
+    counters for the Metrics element and the dashboard share dict
+    (``Pipeline.jit_stats``).
     Donation and shardings pass through to ``jax.jit``.
     """
 

@@ -93,9 +93,9 @@ def _element(name, cls, module, inputs, outputs, parameters=None,
 
 
 def definition(settings: dict, placed: bool) -> dict:
-    """video -> detect -> caption -> LLM (bench_pipeline_e2e's graph)
-    behind the gateway, with a micro-batched resize and a fusable
-    two-resize chain ahead of the detector: R0 parks frames (a fusion
+    """video -> detect -> caption -> LLM behind the gateway, with a
+    micro-batched resize and a fusable two-resize chain ahead of the
+    detector: R0 parks frames (a fusion
     boundary) and leaves a frame-produced device ``image``; R1+R2 fuse
     into ONE dispatch that consumes and overwrites it -- the donation
     case, which only a non-CPU backend takes."""

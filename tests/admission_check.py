@@ -11,8 +11,16 @@ flips near-tie argmaxes intermittently (~1-in-7 under a loaded host;
 reproduced round 5 in fresh processes, so this, not cross-test buffer
 state, was the flake's root cause).  Single-threaded GEMMs + highest
 matmul precision make both shapes round identically run-to-run
-(0 failures across repeated loaded-host trials)."""
+(0 failures across repeated loaded-host trials).
 
+ISSUE 31: the batched side is the device loop (the fused-block driver
+it used to run is gone), and the model is float32, as in the other
+equivalence tests: under six test workers the bfloat16 check still
+failed one run in three or four (never alone), and the cache can only
+agree over the decode positions where the streams do, so the streams
+stay compared and bfloat16's near-ties go."""
+
+import dataclasses
 import os
 import pathlib
 import sys
@@ -33,7 +41,8 @@ from aiko_services_tpu.models.batching import ContinuousBatcher, Request
 
 
 def main() -> int:
-    config = llama.LlamaConfig.tiny()
+    config = dataclasses.replace(llama.LlamaConfig.tiny(),
+                                 dtype="float32")
     params = llama.init_params(jax.random.PRNGKey(0), config)
     prompts = [[1, 2, 3], list(range(1, 41)), list(range(5, 22)), [7]]
 
@@ -41,7 +50,7 @@ def main() -> int:
         streams = {}
         batcher = ContinuousBatcher(params, config, max_slots=4,
                                     max_seq=64, prefill_chunk=16,
-                                    decode_block=block,
+                                    decode_block_tokens=block,
                                     inflight=inflight)
         for i, prompt in enumerate(prompts):
             batcher.submit(Request(
@@ -51,7 +60,7 @@ def main() -> int:
         assert steps < 400, f"did not drain in {steps} steps"
         return batcher, streams
 
-    single, single_streams = run(1, 1)
+    single, single_streams = run(0, 1)
     batched, batched_streams = run(4, 3)
     if single_streams != batched_streams:
         print(f"token stream mismatch: single={single_streams} "
@@ -62,7 +71,7 @@ def main() -> int:
         return 1
     # And the caches agree over the prompt plus every decode position
     # BOTH paths define: tokens t1..t5 write positions P..P+4; the
-    # final token t6's KV at P+5 is written only by the blocked path's
+    # final token t6's KV at P+5 is written only by the device loop's
     # overshoot (the single path frees the slot at budget before
     # processing t6) -- a don't-care position beyond the freed slot's
     # live region, excluded here.
@@ -72,7 +81,7 @@ def main() -> int:
         extent = len(prompt) + 5
         a = batched_k[:, i, :extent]
         b = single_k[:, i, :extent]
-        if not np.allclose(a, b, atol=2e-2, rtol=2e-2):
+        if not np.allclose(a, b, atol=1e-4, rtol=1e-4):
             print(f"slot {i} KV mismatch: max diff "
                   f"{np.abs(a - b).max()}")
             return 1

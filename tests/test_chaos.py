@@ -836,7 +836,7 @@ def test_device_window_invalidate_drops_dead_leaves():
 
 def replicated_chaos_definition(parameters=None):
     """detect at ``replicas: 3`` (2 chips each) feeding an unreplicated
-    placed llm -- the BENCH e2e shape, 8 chips total on the CPU mesh."""
+    placed llm, 8 chips total on the CPU mesh."""
     return {
         "version": 0, "name": "p_replica_chaos", "runtime": "jax",
         "graph": ["(detect llm)"],

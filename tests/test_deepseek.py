@@ -299,7 +299,6 @@ def test_decode_loop_counts_experts(weights):
     ({"speculative": "ngram", "decode_block_tokens": 8}, "speculative"),
     ({"speculative": "draft", "decode_block_tokens": 8}, "speculative"),
     ({"prefix_cache": "on"}, "prefix_cache"),
-    ({"decode_block": 4}, "decode_block"),
 ])
 def test_batcher_refuses_by_name(weights, settings, named):
     config, params = weights()
@@ -331,6 +330,7 @@ def test_latent_pool_refuses_int8():
     ({"family": "deepseek_v3", "speculative": "ngram"}, "speculative"),
     ({"family": "deepseek_v3", "prefix_cache": "on"}, "prefix_cache"),
     ({"family": "llama", "model": "tiny"}, "model"),
+    ({"family": "deepseek_v3", "decode_block": 1}, "decode_block_tokens"),
 ])
 def test_family_parameters_refused_by_name(parameters, named):
     assert named in family_spec_error(parameters)

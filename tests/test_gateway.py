@@ -395,8 +395,8 @@ def test_ws_rate_limit_rejected_and_observability(runtime):
 def test_loadgen_overload_sheds_batch_not_interactive(runtime):
     """2x overload through the REAL gateway: the interactive tenant
     (in budget) keeps 100% goodput while the over-budget batch tenant
-    absorbs every shed -- the Vortex contract, measured by the same
-    loadgen the bench drives."""
+    absorbs every shed -- the Vortex contract, measured by the
+    ``loadgen`` command's generator."""
     pipeline = gateway_pipeline(
         runtime,
         qos={"classes": {"batch": {"device_inflight": 1}},

@@ -148,7 +148,7 @@ def test_moe_serving_through_batcher():
     params = llama.init_params(jax.random.PRNGKey(0), config)
     emitted = {}
     batcher = ContinuousBatcher(params, config, max_slots=2, max_seq=64,
-                                prefill_chunk=16, decode_block=4,
+                                prefill_chunk=16, decode_block_tokens=4,
                                 inflight=2)
     for i in range(3):
         batcher.submit(Request(

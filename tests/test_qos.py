@@ -169,7 +169,7 @@ def test_stage_credit_promotion_fires_on_promote_once():
     STAGE-CREDIT SEAM -- `_pop_ranked` lifts it over a standard frame
     queued ahead of it and fires ``on_promote`` exactly once (the
     callback Pipeline wires into ``share['qos_promotions']``), so the
-    counter the gateway bench reports is reachable deterministically."""
+    counter is reachable deterministically."""
     qos = QosScheduler({"promote_ms": 50, "age_ms": 0})
     promoted = []
     scheduler = StageScheduler(

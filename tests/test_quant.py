@@ -270,8 +270,8 @@ def test_kv_cache_int8_halves_cache_bytes():
 
 
 def test_batcher_serves_int8_kv_cache():
-    """End-to-end serving on int8 weights AND int8 KV cache, pipelined
-    fused-block path included; token streams keep their budget/EOS
+    """End-to-end serving on int8 weights AND int8 KV cache, chained
+    device-loop blocks included; token streams keep their budget/EOS
     semantics."""
     from aiko_services_tpu.models import ContinuousBatcher, Request
     from aiko_services_tpu.models.tokenizer import ByteTokenizer
@@ -287,7 +287,7 @@ def test_batcher_serves_int8_kv_cache():
         emitted.setdefault(request_id, []).append(token)
 
     batcher = ContinuousBatcher(params, config, max_slots=2, max_seq=64,
-                                prefill_chunk=16, decode_block=4,
+                                prefill_chunk=16, decode_block_tokens=4,
                                 inflight=2)
     for i in range(3):
         batcher.submit(Request(f"r{i}", tok.encode(f"aloha {i}"),
@@ -315,7 +315,7 @@ def test_batcher_tp_sharded_quantized_serving():
     out = []
     batcher = ContinuousBatcher(
         sharded, config, max_slots=2, max_seq=64, prefill_chunk=16,
-        decode_block=4, inflight=2,
+        decode_block_tokens=4, inflight=2,
         cache_put=lambda c: jax.device_put(c, cache_sharding))
     batcher.submit(Request("r", [1, 2, 3], max_new_tokens=6,
                            emit=lambda r, t, f: out.append(t)))

@@ -57,9 +57,8 @@ def _busy(batcher):
 @pytest.mark.parametrize("mode", [
     {"decode_block_tokens": 8},
     {"decode_block_tokens": 8, "kv_page_tokens": 16},
-    {"decode_block": 4},
     {},
-], ids=["device-loop", "device-loop-paged", "fused-blocks", "per-token"])
+], ids=["device-loop", "device-loop-paged", "per-token"])
 def test_tick_phases_tile_the_step(tiny, mode):
     """Each phase starts where the last one ended, so the phases of a
     step add up to the step; every ``retire_wait`` is one retired

@@ -193,6 +193,28 @@ def test_speculative_requires_device_loop(tiny):
                           speculative="ngram", spec_tokens=4)
 
 
+@pytest.mark.parametrize("settings,probed", [
+    ({}, False),                                    # no device loop
+    ({"decode_block_tokens": 8, "spec_autoprobe": "off"}, False),
+    ({"decode_block_tokens": 4, "spec_tokens": 4}, False),   # ring < k+1
+    ({"decode_block_tokens": 8}, True),
+], ids=["host-loop", "probe-off", "ring-too-small", "probed"])
+def test_speculative_auto_resolves_at_build(tiny_f32, settings, probed):
+    """``speculative: auto`` never raises and never stays ``auto``: a
+    configuration explicit ``draft`` would refuse resolves to ``off``
+    unprobed; otherwise the startup probe measures draft against plain
+    decode and commits to one of them, and the batcher then serves the
+    host loop's streams either way (speculation is lossless)."""
+    config, params = tiny_f32
+    host, _ = _run(params, config)
+    auto, batcher = _run(params, config, speculative="auto", **settings)
+    assert auto == host
+    assert batcher.speculative in (("draft", "off") if probed
+                                   else ("off",))
+    assert (batcher.spec_probe_ratio > 0.0) == probed
+    assert (batcher.draft_tokens > 0) == (batcher.speculative == "draft")
+
+
 # -- paged KV cache invariants ---------------------------------------------
 
 
@@ -567,12 +589,16 @@ def test_recover_paged_speculative(tiny_f32):
     steps = 0
     while (batcher.pending or batcher.active_count
            or batcher.blocks_in_flight) and steps < 2000:
-        if steps == 6:
+        if steps == 1:
+            # The first wave is mid-generation here: each request has
+            # its first block (<= 9 of 11 tokens) and none is done.  (At
+            # step 6, where this used to arm, speculation has drained
+            # all six and the probe never fires.)
             boom["armed"] = True
         try:
             batcher.step()
         except RuntimeError:
-            batcher.recover()
+            assert any(emitted.values()) and batcher.recover() >= 1
         steps += 1
     assert steps < 2000
     assert emitted == host
@@ -954,3 +980,31 @@ def test_llm_element_rejects_bad_mode_at_create(runtime):
     with pytest.raises(DefinitionError, match="off|ngram|draft"):
         Pipeline(_llm_definition("llm_bad", {"speculative": "banana"}),
                  runtime=runtime)
+
+
+@pytest.mark.parametrize("where", ["batcher", "create", "model-build"])
+def test_decode_block_is_refused_by_name(tiny, runtime, where):
+    """``decode_block`` chose the fused-block driver, which is gone.
+    An unknown element parameter is ignored, and this one would then
+    decode by the per-token tick: it is refused instead, at create
+    time and at model build, by a message that names
+    ``decode_block_tokens``; the batcher no longer takes it."""
+    from aiko_services_tpu.pipeline import DefinitionError, Pipeline
+
+    if where == "batcher":
+        config, params = tiny
+        with pytest.raises(TypeError, match="decode_block"):
+            ContinuousBatcher(params, config, decode_block=4)
+    elif where == "create":
+        with pytest.raises(DefinitionError, match="decode_block_tokens"):
+            Pipeline(_llm_definition("llm_old_knob", {"decode_block": 4}),
+                     runtime=runtime)
+    else:
+        pipeline = Pipeline(
+            _llm_definition("llm_old_knob_build", {"decode_block": 4},
+                            pipeline_parameters={"preflight": "off"}),
+            runtime=runtime)
+        element = pipeline.graph.get_node("llm").element
+        with pytest.raises(ValueError, match="decode_block_tokens"):
+            element._ensure_model(element._resolve_model_params())
+        pipeline.stop()

@@ -37,6 +37,7 @@ DEFINITION_FIXTURES = {
     "bad_element_parameter.json": "bad-parameter",
     "bad_prefix_cache.json": "bad-parameter",
     "bad_llm_family.json": "bad-parameter",
+    "bad_llm_decode_block.json": "bad-parameter",
     "bad_data_plane.json": "bad-parameter",
     "bad_qos.json": "bad-parameter",
     "bad_qos_tenant.json": "bad-parameter",
@@ -261,7 +262,7 @@ def test_framework_self_check_clean():
 
 def test_preflight_cost_is_create_time_cheap():
     """The e2e-style definition pre-flights in well under 100 ms once
-    the module index is warm (bench records the cold number)."""
+    the module index is warm."""
     definition = load_pipeline_definition(
         str(REPO / "examples" / "speech" / "pipeline_speech.json"))
     lint_definition(definition)                     # warm the AST cache
