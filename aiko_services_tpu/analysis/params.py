@@ -328,7 +328,8 @@ ELEMENT_PARAMETERS: dict[tuple[str, str], dict[str, ParamSpec]] = {
             "shortest prompt the prefix cache will index or match",
             number=True, minimum=1),
         "inflight": ParamSpec(
-            "decode blocks kept in flight, chained device-side",
+            "decode blocks kept in flight, chained device-side, while "
+            "requests wait for a slot (one otherwise)",
             number=True, minimum=1),
         "max_slots": ParamSpec(
             "device batch width (concurrent request slots)",

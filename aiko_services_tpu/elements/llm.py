@@ -202,8 +202,8 @@ class LLM(PipelineElement):
     ``attention`` (``dense`` | ``flash`` -- the Pallas long-context
     prefill path, 2.5x dense at 8k context), ``quantize`` (weight-only
     int8: halves decode's HBM stream), ``inflight`` (keep N device-loop
-    blocks in flight, chained device-side: hides the dispatch round
-    trip behind device compute), ``max_slots`` (device batch width:
+    blocks in flight while requests wait for a slot, chained
+    device-side: hides the dispatch round trip behind device compute), ``max_slots`` (device batch width:
     size to the expected concurrent-frame count; decode is
     weight-HBM-bound at short context, so wider batches decode more
     frames' requests per block at nearly the same step time).

@@ -31,10 +31,11 @@ token streams.
   (EOS + budget + cache boundary) and an emitted-token ring in the
   carry -- and the host pays ONE counted fetch per retired block (the
   ``fetch`` hook, wired to the pipeline's TransferLedger by the LLM
-  element) instead of one round trip per token.  The batcher keeps
-  ``inflight`` blocks in flight, chaining each dispatch off the
-  previous block's DEVICE-side carries, so the host never blocks on a
-  device result between dispatches; a request's tokens past its
+  element) instead of one round trip per token.  While requests wait
+  for a slot the batcher keeps ``inflight`` blocks in flight (one
+  otherwise), chaining each dispatch off the previous block's
+  DEVICE-side carries, so the host never blocks on a device result
+  between dispatches; a request's tokens past its
   EOS/budget inside in-flight blocks are discarded host-side.
   Admission and eviction happen only at block boundaries; ``speculative:
   ngram|draft`` layers multi-token decoding onto the loop with
@@ -148,6 +149,50 @@ class Request:
 _select_tokens = jax.jit(llama.select_tokens,
                          static_argnames=("top_k",))
 
+# Columns of the device loop's packed fold input, one row a slot; the
+# slot's stop tokens follow (``eos_width`` columns), then its n-gram
+# history tail (``spec_window`` columns, ``speculative: ngram`` only).
+_FOLD_JOIN, _FOLD_PLEN, _FOLD_BUDGET, _FOLD_MAY_DECODE, \
+    _FOLD_FORCE_INACTIVE, _FOLD_TEMPERATURE, _FOLD_EOS = range(7)
+
+
+@partial(jax.jit, static_argnames=("eos_width",))
+def _fold_joiners(tokens, lengths, active, budget, history, firsts,
+                  packed, *, eos_width: int):
+    """The device loop's fold-in as ONE program of fixed shape: the
+    chained carries ``[max_slots]`` (``history`` ``[max_slots, W]``),
+    the first tokens admission left on the device and the host's packed
+    int32 rows (the ``_FOLD_*`` columns) give the carries the next
+    block starts from, plus the temperatures and the stop table its
+    loop reads.  A freed slot goes inactive; a joiner takes its first
+    token, prompt length, budget and history tail, and decodes on
+    unless the host says it may not or its first token is one of its
+    stop tokens -- compared here, so the token is never fetched.  A
+    slot freed and joined again in the same block is a joiner."""
+    join = packed[:, _FOLD_JOIN] != 0
+    eos = packed[:, _FOLD_EOS:_FOLD_EOS + eos_width]
+    tail = packed[:, _FOLD_EOS + eos_width:]
+    may_decode = (packed[:, _FOLD_MAY_DECODE] != 0) \
+        & ~(firsts[:, None] == eos).any(axis=1)
+    active = jnp.where(
+        join, may_decode,
+        active & (packed[:, _FOLD_FORCE_INACTIVE] == 0))
+    if tail.shape[1]:
+        history = jnp.where(join[:, None], tail, history)
+    temperatures = jax.lax.bitcast_convert_type(
+        packed[:, _FOLD_TEMPERATURE], jnp.float32)
+    return (jnp.where(join, firsts, tokens),
+            jnp.where(join, packed[:, _FOLD_PLEN], lengths), active,
+            jnp.where(join, packed[:, _FOLD_BUDGET], budget), history,
+            temperatures, eos)
+
+
+@jax.jit
+def _set_first(firsts, slot, first):
+    """One admission's sampled first token ``[1]`` into the
+    ``[max_slots]`` vector the fold reads."""
+    return firsts.at[slot].set(first[0])
+
 
 def model_family(config):
     """The model family that serves ``config``, chosen by its type: the
@@ -186,8 +231,9 @@ def _prefetch(tree) -> None:
 class _LoopBlock:
     """One dispatched-but-unretired device-resident generation block
     (llama.decode_loop).  ``tree`` holds every device array the retire
-    needs -- emitted ring, counts, carries, accept counters, folded
-    first tokens -- fetched in ONE counted host copy."""
+    needs -- emitted ring, counts, carries, accept counters, the
+    first-token vector its joiners were folded in from -- fetched in
+    ONE counted host copy."""
     __slots__ = ("tree", "snapshot", "firsts_meta", "grid")
 
     def __init__(self, tree, snapshot, firsts_meta, grid=None):
@@ -226,7 +272,8 @@ class ContinuousBatcher:
         self.max_slots = max_slots
         self.max_seq = max_seq or config.max_seq
         self.prefill_chunk = min(prefill_chunk, self.max_seq)
-        # How many device-loop blocks to keep in flight.  Each dispatch
+        # How many device-loop blocks to keep in flight while requests
+        # wait for a slot (one otherwise: ``step``).  Each dispatch
         # chains off the previous block's device carries, so depth d
         # hides up to d * block_compute of host round-trip latency
         # behind device work.
@@ -349,8 +396,9 @@ class ContinuousBatcher:
         self._fault_probe = fault_probe
         # Host-timeline tap (ISSUE 26): ``trace(name, ms, info)`` fires
         # at the END of every phase of ``step()`` -- ``admit``,
-        # ``prefill``, ``fold`` (the device loop's eager fold-in of
-        # joiners, up to the block's enqueue), ``dispatch`` (the
+        # ``prefill``, ``fold`` (the device loop's fold-in of joiners
+        # and freed slots, up to the block's enqueue; its ``launches``
+        # counts the device calls it made), ``dispatch`` (the
         # enqueue and the start of its host copies), ``retire_wait``
         # (only the blocking fetch of the oldest block), ``demux`` --
         # each phase starting where the last one ended, so they tile
@@ -371,13 +419,21 @@ class ContinuousBatcher:
         self.pending: list[Request] = []
         self._prefilling: list[int] = []      # slot FIFO, round-robin
         self._key = jax.random.PRNGKey(rng_seed)
-        # device-loop state: first-token futures from prefill
-        # completions not yet folded into a dispatch, the chained
-        # carries of the latest loop block, the in-flight loop-block
-        # queue, host mirrors of per-slot eos rows and a conservative
-        # length upper bound for page allocation while blocks are in
-        # flight.
-        self._pending_first: dict[int, tuple] = {}   # slot -> (req, dev)
+        # device-loop state: the admissions whose prefill completed
+        # and that no dispatch has folded in yet, their sampled first
+        # tokens (on the device, unfetched, one entry a slot), the
+        # chained carries of the latest loop block, the in-flight
+        # loop-block queue, host mirrors of per-slot eos rows and of
+        # the page table, and a conservative length upper bound for
+        # page allocation while blocks are in flight.
+        self._pending_first: dict[int, Request] = {}     # by slot
+        self._firsts = jnp.zeros(max_slots, dtype=jnp.int32)
+        self._slot_index = [jnp.int32(slot) for slot in range(max_slots)]
+        self._page_rows = None if self._pages is None else np.zeros(
+            (max_slots, self._pages.pps), dtype=np.int32)
+        # Device calls (programs and explicit uploads) made by the
+        # fold-in and the page-table sync, counted where they are made.
+        self._launches = 0
         self._loop_chain: dict | None = None
         self._loop_inflight: deque[_LoopBlock] = deque()
         self._eos_width = 1
@@ -626,9 +682,12 @@ class ContinuousBatcher:
         self._lengths_upper[slot] = len(prompt)
         self.decoding[slot] = True
         if self.device_loop:
-            # No host copy here: the retire fetches the CONCATENATED
-            # firsts array of the block this admission folds into.
-            self._pending_first[slot] = (request, first)
+            # No host copy here: the token stays on the device, in the
+            # vector the next dispatch folds in and its block's retire
+            # fetches whole.
+            self._firsts = _set_first(self._firsts,
+                                      self._slot_index[slot], first)
+            self._pending_first[slot] = request
         else:
             first_token = int(jax.device_get(first)[0])
             self.current[slot] = first_token
@@ -675,7 +734,18 @@ class ContinuousBatcher:
         if self.device_loop:
             if decoding or self._pending_first or self._loop_inflight:
                 blocks = 0
-                while len(self._loop_inflight) < self.inflight:
+                # Blocks queued ahead of the running one hide the
+                # host's time between blocks, and make everything
+                # enqueued after them -- a joiner's prefill, another
+                # element's program on the same chip -- wait a block
+                # longer.  Worth it only where requests wait for a
+                # slot: there the batcher is what bounds throughput.
+                # (Until the fold-in was one launch the worker never
+                # got ahead of the chip; once it did, a part-full
+                # batch beside a detector took the whole chip for
+                # near-empty blocks: PERF.md section 6, PR 32.)
+                depth = self.inflight if self.pending else 1
+                while len(self._loop_inflight) < depth:
                     if not self._dispatch_loop_block():
                         break
                     blocks += 1
@@ -816,6 +886,7 @@ class ContinuousBatcher:
     def _host_state(self):
         """Fresh device carries from the host mirrors (first dispatch
         and post-recover; every later block chains device-side)."""
+        self._launches += 6             # one a statement below
         self._key, loop_key = jax.random.split(self._key)
         history_width = self.spec_window \
             if self.speculative == "ngram" else 1
@@ -829,14 +900,53 @@ class ContinuousBatcher:
             "key": loop_key,
         }
 
+    def _fold_rows(self, firsts_meta: list) -> np.ndarray:
+        """The fold-in's host side, packed for one upload: a row a
+        slot (the ``_FOLD_*`` columns) saying which slots were freed,
+        which join with what prompt length and budget, whether a
+        joiner may decode past its first token at all (budget and
+        cache boundary; the stop-token part of that verdict is the
+        device's), every slot's temperature (as its bits) and stop
+        tokens, and under ``speculative: ngram`` each joiner's history
+        tail.  Clears the freed set."""
+        window = self.spec_window if self.speculative == "ngram" else 0
+        eos_end = _FOLD_EOS + self._eos_width
+        packed = np.zeros((self.max_slots, eos_end + window),
+                          dtype=np.int32)
+        packed[list(self._force_inactive), _FOLD_FORCE_INACTIVE] = 1
+        self._force_inactive.clear()
+        packed[:, _FOLD_TEMPERATURE] = self.temperatures.view(np.int32)
+        packed[:, _FOLD_EOS:eos_end] = self._eos_rows
+        for slot, request in firsts_meta:
+            plen = len(request.prompt_tokens)
+            left = request.max_new_tokens - request.generated
+            packed[slot, _FOLD_JOIN] = 1
+            packed[slot, _FOLD_PLEN] = plen
+            packed[slot, _FOLD_BUDGET] = left - 1
+            packed[slot, _FOLD_MAY_DECODE] = \
+                left > 1 and plen + 1 < self.max_seq
+            if window:
+                recent = request.prompt_tokens[-window:]
+                packed[slot, eos_end:] = -1
+                packed[slot, packed.shape[1] - len(recent):] = recent
+        return packed
+
     def _dispatch_loop_block(self) -> bool:
-        """Chain one llama.decode_loop block off the previous block's
-        device carries, folding completed admissions in (their first
-        token, budget, stop set and draft history ride device-side --
-        no host round trip).  Returns False when there is nothing to
-        decode, outstanding blocks already cover every request's
-        budget, or page-pool pressure wants the in-flight blocks
-        retired before an eviction can free room."""
+        """Chain one ``decode_loop`` block off the previous block's
+        device carries.  Completed admissions, freed slots and new
+        page-table rows are folded in first, by host arithmetic on the
+        numpy mirrors and a fixed number of device calls whatever the
+        number of joiners: the page table's upload where a row changed
+        (:meth:`_sync_page_table`), ONE packed upload
+        (:meth:`_fold_rows`) and ONE program (:func:`_fold_joiners`;
+        the first token, budget, stop verdict and draft history ride
+        device-side -- no host round trip).  The v5e runtime lets 32
+        launches be outstanding and the chip waits for the rest, so a
+        fold of a few launches a joiner left it idle (PERF.md section
+        6, PR 26/32).  Returns False when there is nothing to decode,
+        outstanding blocks already cover every request's budget, or
+        page-pool pressure wants the in-flight blocks retired before
+        an eviction can free room."""
         ring = self.decode_block_tokens
         spec_extra = self.spec_tokens + 1 \
             if self.speculative != "off" else 1
@@ -870,42 +980,22 @@ class ContinuousBatcher:
             return False
         if self._fault_probe is not None:
             self._fault_probe("decode_block")
+        launched = self._launches
         state = self._loop_chain or self._host_state()
-        tokens, lengths = state["tokens"], state["lengths"]
-        active, budget = state["active"], state["budget"]
-        history, key = state["history"], state["key"]
-        for slot in self._force_inactive:
-            active = active.at[slot].set(False)
-        self._force_inactive.clear()
-        eos_dev = jnp.asarray(self._eos_rows)
-        temps_dev = jnp.asarray(self.temperatures)
-        firsts_meta, first_vals = [], []
-        for slot in joining:
-            request, first = self._pending_first.pop(slot)
-            plen = len(request.prompt_tokens)
-            tokens = tokens.at[slot].set(first[0])
-            lengths = lengths.at[slot].set(plen)
-            budget = budget.at[slot].set(
-                request.max_new_tokens - request.generated - 1)
-            # The slot decodes on unless its FIRST token already
-            # finishes it; the EOS part of that verdict folds in
-            # device-side (the first token is an unfetched scalar).
-            if (request.max_new_tokens - request.generated > 1
-                    and plen + 1 < self.max_seq):
-                active = active.at[slot].set(
-                    jnp.logical_not((first[0] == eos_dev[slot]).any()))
-            else:
-                active = active.at[slot].set(False)
-            if self.speculative == "ngram":
-                tail = np.full(self.spec_window, -1, dtype=np.int32)
-                recent = request.prompt_tokens[-self.spec_window:]
-                tail[len(tail) - len(recent):] = recent
-                history = history.at[slot].set(jnp.asarray(tail))
-            firsts_meta.append((slot, request))
-            first_vals.append(first)
+        firsts_meta = [(slot, self._pending_first.pop(slot))
+                       for slot in joining]
         self._sync_page_table()
+        packed = jnp.asarray(self._fold_rows(firsts_meta))
+        (tokens, lengths, active, budget, history, temps_dev,
+         eos_dev) = _fold_joiners(
+            state["tokens"], state["lengths"], state["active"],
+            state["budget"], state["history"], self._firsts, packed,
+            eos_width=self._eos_width)
+        key = state["key"]
+        self._launches += 2
         if self.trace is not None:
-            self._phase("fold", {"joining": len(firsts_meta)})
+            self._phase("fold", {"joining": len(firsts_meta),
+                                 "launches": self._launches - launched})
         (emitted, counts, tokens_next, lengths_next, active_next,
          budget_next, history_next, key_next, accepted, drafted, steps,
          self.cache, *stats) = self._family.decode_loop(
@@ -922,8 +1012,8 @@ class ContinuousBatcher:
                 "accepted": accepted, "drafted": drafted, "steps": steps}
         if stats:
             tree["stats"] = stats[0]    # the family's block statistics
-        if first_vals:
-            tree["firsts"] = jnp.concatenate(first_vals)
+        if firsts_meta:
+            tree["firsts"] = self._firsts
         _prefetch(tree)                 # overlap newer blocks
         self._loop_chain = {"tokens": tokens_next,
                             "lengths": lengths_next,
@@ -967,12 +1057,11 @@ class ContinuousBatcher:
         self.blocks_retired += 1
         self.accepted_tokens += int(np.asarray(fetched["accepted"]).sum())
         self.draft_tokens += int(np.asarray(fetched["drafted"]).sum())
-        if "firsts" in fetched:
+        if blk.firsts_meta:
             first_tokens = np.asarray(fetched["firsts"])
-            for (slot, request), token in zip(blk.firsts_meta,
-                                              first_tokens):
+            for slot, request in blk.firsts_meta:
                 if self.slots[slot] is request and not request.done:
-                    token = int(token)
+                    token = int(first_tokens[slot])
                     self.current[slot] = token
                     self._emit(request, token)
         for slot, request in blk.snapshot:
@@ -1032,16 +1121,25 @@ class ContinuousBatcher:
                 return True
 
     def _sync_page_table(self) -> None:
-        """Fold the allocator's dirty rows into the device page table
-        (tiny int32 uploads that ride the next dispatch)."""
+        """Bring the device page table to the allocator's rows: its
+        dirty rows go into the host mirror, and the mirror goes up
+        whole, in ONE explicit copy (it rides the next dispatch);
+        nothing where no row changed.  The copy is placed as the table
+        it replaces -- across the serving mesh where ``cache_put`` put
+        that one there, uncommitted otherwise -- so the programs that
+        take the cache see the arguments they were built for."""
         if self._pages is None or not self._pages.dirty:
             return
-        table = self.cache["page_table"]
         for slot, row in self._pages.dirty.items():
-            table = table.at[slot].set(
-                jnp.asarray(row, dtype=jnp.int32))
+            self._page_rows[slot] = row
         self._pages.dirty.clear()
-        self.cache["page_table"] = table
+        table = self.cache["page_table"]
+        # (a copy of the mirror: the device may read the host's buffer
+        # after the call returns)
+        self.cache["page_table"] = jax.device_put(
+            self._page_rows.copy(),
+            table.sharding if table.committed else None)
+        self._launches += 1
 
     def _evict_slot(self, slot: int) -> None:
         """Preempt one slot for its pages: rebase the request onto its
@@ -1089,6 +1187,7 @@ class ContinuousBatcher:
         self.pending = revived + self.pending
         self._prefilling.clear()
         self._pending_first.clear()
+        self._firsts = jnp.zeros(self.max_slots, dtype=jnp.int32)
         self._loop_inflight.clear()
         self._loop_chain = None
         self._force_inactive.clear()
@@ -1099,6 +1198,7 @@ class ContinuousBatcher:
         self.decoding[:] = False
         if self._pages is not None:
             self._pages.reset()
+            self._page_rows[:] = 0
             self.cache = init_paged_cache(
                 self.config, self.max_slots, self.max_seq,
                 self.kv_page_tokens, self._pages.total)

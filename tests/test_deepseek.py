@@ -396,7 +396,10 @@ def test_llm_element_serves_the_latent_family(runtime):
     imbalance = registry.quantile("llm_moe_load_imbalance", 0.5, None,
                                   windowed=False)
     assert 1.0 <= touched <= 8.0 * 1.1 and imbalance >= 0.9
-    assert "llm_ttft_ms" in pipeline.metrics_text()
+    # (the worker publishes a request's stamps after the tick that
+    # finished it: the response may reach this thread first)
+    assert run_until(runtime,
+                     lambda: "llm_ttft_ms" in pipeline.metrics_text())
     demux = [event for event in pipeline.recorder.snapshot()
              if event[1] == "llm_tick" and event[4] == "demux"
              and event[6]]

@@ -87,11 +87,16 @@ def test_tick_phases_tile_the_step(tiny, mode):
     assert sum(info["blocks"] for info in dispatches) == retired
     assert max(info["slots"] for info in dispatches) == batcher.max_slots
     if batcher.device_loop:
-        # Every block's eager fold-in is stamped apart from its enqueue.
+        # Every block's fold-in is stamped apart from its enqueue, with
+        # the device calls it made: the packed upload and the program,
+        # the page table where a row changed, and the first block's
+        # fresh carries -- never more for more joiners.
         folds = [info for name, _, info in phases if name == "fold"]
         assert len(folds) == retired
         assert sum(info["joining"] for info in dispatches) == 6 \
             == sum(info["joining"] for info in folds)
+        assert folds[0]["launches"] in (8, 9)
+        assert {info["launches"] for info in folds[1:]} <= {2, 3}
     chunks = sum(info["chunks"] for name, _, info in phases
                  if name == "prefill")
     assert chunks == 6              # one 16-token chunk a request

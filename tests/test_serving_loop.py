@@ -151,6 +151,243 @@ def test_device_loop_respects_eos(tiny):
         assert len(tokens) <= 12
 
 
+# -- the fold-in: one program whatever the joiners (ISSUE 32) --------------
+
+FOLD_SLOTS = 8
+
+
+def _fold_batcher(params, config, emitted, phases=None, **kw):
+    """Eight slots, chunks of 32 over pages of 16: a short prompt's
+    admission takes both pages its whole generation needs, so no fold
+    of these tests finds the page table changed."""
+    def emit(request_id, token, finished):
+        emitted.setdefault(request_id, []).append(token)
+
+    trace = None if phases is None else \
+        lambda name, ms, info: phases.append((name, info))
+    batcher = ContinuousBatcher(params, config, max_slots=FOLD_SLOTS,
+                                max_seq=64, prefill_chunk=32,
+                                trace=trace, **kw)
+    return batcher, emit
+
+
+@pytest.mark.parametrize("paged", [0, 16], ids=["dense", "paged"])
+@pytest.mark.parametrize("joiners", [0, 1, 2, 5, FOLD_SLOTS])
+def test_fold_is_two_launches_whatever_the_joiners(tiny, joiners, paged):
+    """``joiners`` admissions complete in one tick and fold into one
+    block: the streams are the per-token tick's, the fold phase makes
+    the same two device calls (one packed upload, one program) for
+    every count, and once a first round has run, a second builds no
+    program -- there is none per count of joiners."""
+    from aiko_services_tpu.observability.recorder import FlightRecorder
+    config, params = tiny
+    tok = ByteTokenizer()
+    count = max(joiners, 1)     # 0: the second tick of a lone joiner
+
+    def submit(batcher, emit, tag):
+        for i in range(count):
+            batcher.submit(Request(
+                request_id=f"{tag}{i}", prompt_tokens=tok.encode(f"fold {i}"),
+                max_new_tokens=12, emit=emit))
+
+    host = {}
+    batcher, emit = _fold_batcher(params, config, host)
+    submit(batcher, emit, "a")
+    assert batcher.run_until_drained(max_steps=800) < 800
+
+    loop, phases = {}, []
+    ring = FlightRecorder(capacity=4096)
+    batcher, emit = _fold_batcher(params, config, loop, phases,
+                                  decode_block_tokens=4,
+                                  kv_page_tokens=paged)
+
+    def builds():
+        return [event[4] for event in ring.snapshot()
+                if event[1] == "build"]
+
+    for tag in "ab":
+        seen, built = len(phases), builds()
+        submit(batcher, emit, tag)
+        for _ in range(1 if joiners else 2):
+            batcher.step()
+        measured = [info for name, info in phases[seen:] if name == "fold"]
+        assert batcher.run_until_drained(max_steps=800) < 800
+    assert builds() == built                # the second round built none
+    assert joiners in [info["joining"] for info in measured]
+    assert measured[0]["joining"] == count
+    assert [info["launches"] for info in measured] == [2] * len(measured)
+    chained = [info["launches"] for name, info in phases
+               if name == "fold"][1:]       # the first made the carries
+    assert set(chained) <= {2, 3}           # 3: the page table went up too
+    for tag in "ab":
+        for i in range(count):
+            assert loop[f"{tag}{i}"] == host[f"a{i}"]
+
+
+def test_fold_joiner_that_finishes_on_its_first_token(tiny):
+    """A joiner whose first token is a stop token, one with
+    ``max_new_tokens`` 1 and one whose prompt ends a position short of
+    ``max_seq`` emit that one token and leave the fold inactive on the
+    device; the joiner beside them decodes on."""
+    config, params = tiny
+    tok = ByteTokenizer()
+    prompts = {"stop": tok.encode("stops at once"),
+               "one": tok.encode("one token"),
+               "edge": [1 + i % 200 for i in range(63)],
+               "goes": tok.encode("goes on")}
+
+    def run(stop=(), **kw):
+        emitted = {}
+        batcher, emit = _fold_batcher(params, config, emitted, **kw)
+        requests = {
+            name: Request(request_id=name, prompt_tokens=list(prompt),
+                          max_new_tokens=1 if name == "one" else 6,
+                          eos_tokens=stop if name == "stop" else (),
+                          emit=emit)
+            for name, prompt in prompts.items()}
+        for request in requests.values():
+            batcher.submit(request)
+        return emitted, batcher, requests
+
+    free, batcher, _ = run()
+    assert batcher.run_until_drained(max_steps=800) < 800
+    stop = (free["stop"][0],)
+    host, batcher, _ = run(stop)
+    assert batcher.run_until_drained(max_steps=800) < 800
+    loop, batcher, requests = run(stop, decode_block_tokens=4, inflight=1)
+    inactive_at_join = {}
+
+    def tap(name, ms, info):
+        """After each block's enqueue: the carries it hands on."""
+        if name == "dispatch" and info["joining"]:
+            active = np.asarray(batcher._loop_chain["active"])
+            for slot, request in batcher._loop_inflight[-1].firsts_meta:
+                inactive_at_join[request.request_id] = not active[slot]
+
+    batcher.trace = tap
+    assert batcher.run_until_drained(max_steps=800) < 800
+    assert loop == host
+    assert inactive_at_join == {"stop": True, "one": True, "edge": True,
+                                "goes": False}
+    assert [len(loop[name]) for name in ("stop", "one", "edge")] == [1, 1, 1]
+    assert len(loop["goes"]) == 6
+    assert all(request.done for request in requests.values())
+
+
+def test_fold_freed_and_rejoined_slot_decodes_the_new_occupant(tiny):
+    """One slot: its occupant is cancelled mid-generation and the next
+    request admitted into it within the same tick, so the fold sees the
+    slot both freed and joining.  The joiner wins: its stream is its
+    own per-token stream, and the cancelled request emits nothing
+    more."""
+    config, params = tiny
+    tok = ByteTokenizer()
+
+    def run(**kw):
+        emitted = {}
+
+        def emit(request_id, token, finished):
+            emitted.setdefault(request_id, []).append(token)
+
+        batcher = ContinuousBatcher(params, config, max_slots=1,
+                                    max_seq=64, prefill_chunk=16, **kw)
+        old = Request(request_id="old", prompt_tokens=tok.encode("leaves"),
+                      max_new_tokens=40, emit=emit)
+        new = Request(request_id="new", prompt_tokens=tok.encode("arrives"),
+                      max_new_tokens=9, emit=emit)
+        return emitted, batcher, old, new
+
+    host, batcher, _, new = run()
+    batcher.submit(new)
+    assert batcher.run_until_drained(max_steps=800) < 800
+
+    loop, batcher, old, new = run(decode_block_tokens=4, inflight=2)
+    batcher.submit(old)
+    batcher.step()
+    batcher.submit(new)             # waits for the slot: blocks run ahead
+    for _ in range(2):
+        batcher.step()
+    assert batcher.blocks_in_flight and not old.done
+    before = list(loop["old"])
+    assert batcher.cancel("old")
+    assert batcher._force_inactive == {0}
+    batcher.step()      # re-admitted behind the block still in flight
+    assert new.slot == 0 and 0 in batcher._pending_first
+    assert batcher._force_inactive == {0}
+    batcher.step()                  # freed and joining: one fold
+    assert not batcher._force_inactive and not batcher._pending_first
+    assert batcher.run_until_drained(max_steps=800) < 800
+    assert loop["old"] == before
+    assert loop["new"] == host["new"]
+
+
+def test_blocks_run_ahead_only_while_requests_wait(tiny):
+    """``inflight`` blocks are kept queued on the device while a request
+    waits for a slot; with none waiting the next block is enqueued when
+    the last has retired, so whatever else was enqueued meanwhile (a
+    joiner's prefill, another element's program) runs before it.  The
+    streams are the per-token tick's either way."""
+    config, params = tiny
+    host, _ = _run(params, config, n_requests=6, max_new=17)
+    depths = []
+
+    def tap(name, ms, info):
+        if name == "dispatch":
+            depths.append((bool(batcher.pending), batcher.blocks_in_flight))
+
+    tok = ByteTokenizer()
+    loop = {}
+    batcher = ContinuousBatcher(params, config, max_slots=4, max_seq=64,
+                                prefill_chunk=16, decode_block_tokens=4,
+                                inflight=3, trace=tap)
+    for i in range(6):
+        batcher.submit(Request(
+            request_id=f"r{i}", prompt_tokens=tok.encode(f"hello world {i}"),
+            max_new_tokens=17,
+            emit=lambda rid, token, done: loop.setdefault(rid, [])
+            .append(token)))
+    assert batcher.run_until_drained(max_steps=800) < 800
+    assert loop == host
+    assert max(depth for waiting, depth in depths if waiting) == 3
+    assert {depth for waiting, depth in depths if not waiting} == {1}
+    assert any(not waiting for waiting, _ in depths)
+
+
+def test_device_page_table_follows_the_allocator(tiny_f32):
+    """After every tick the device page table holds the allocator's
+    rows (but those still waiting for the next upload): through
+    admission, decode crossing a page, release at finish and the
+    preemption of a joining slot under pool pressure (the regression
+    scenario above)."""
+    config, params = tiny_f32
+    tok = ByteTokenizer()
+    batcher = ContinuousBatcher(params, config, max_slots=4, max_seq=64,
+                                prefill_chunk=16, decode_block_tokens=8,
+                                kv_page_tokens=16, kv_pages=6)
+    for i in range(4):
+        batcher.submit(Request(request_id=f"r{i}",
+                               prompt_tokens=tok.encode(f"hello world {i}"),
+                               max_new_tokens=12))
+    pages, held = batcher._pages, set()
+    for _ in range(3000):
+        if not (batcher.pending or batcher.active_count
+                or batcher.blocks_in_flight):
+            break
+        batcher.step()
+        table = np.asarray(batcher.cache["page_table"])
+        assert table.shape == (4, pages.pps) and table.dtype == np.int32
+        for slot in range(4):
+            if slot not in pages.dirty:
+                assert list(table[slot]) == pages._row(slot)
+            held.add(pages.holds(slot))
+    assert not (batcher.pending or batcher.active_count)
+    assert batcher.evictions >= 1
+    assert held >= {0, 1, 2}        # empty, admitted, grown across a page
+    batcher._sync_page_table()
+    assert not np.asarray(batcher.cache["page_table"]).any()
+    assert not batcher._page_rows.any()
+
+
 # -- speculative decoding --------------------------------------------------
 
 
