@@ -345,10 +345,12 @@ ELEMENT_PARAMETERS: dict[tuple[str, str], dict[str, ParamSpec]] = {
         "family": ParamSpec(
             "model family the element builds from ``widths`` "
             "(models/families.py); absent = the ``model`` presets",
-            choices=("llama", "deepseek_v3")),
+            choices=("llama", "deepseek_v3", "olmo_hybrid")),
         "widths": ParamSpec(
             "published config.json keys of the family (a key it lacks "
-            "is refused; one left out keeps the family's default)",
+            "is refused; one left out keeps the family's default; "
+            "numbers, but for olmo_hybrid's list layer_types and "
+            "boolean linear_allow_neg_eigval)",
             kind="json"),
         "sample_top_k": ParamSpec(
             "restrict sampled rows to the k highest logits via the "

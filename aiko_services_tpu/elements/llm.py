@@ -39,7 +39,7 @@ import time
 
 import jax
 
-from ..models import deepseek, llama
+from ..models import deepseek, llama, olmo_hybrid
 from ..models.batching import ContinuousBatcher, Request
 from ..models.checkpoint import maybe_restore as _restore
 from ..models.families import family_spec_error
@@ -226,10 +226,12 @@ class LLM(PipelineElement):
 
     MODEL FAMILIES: ``model`` picks a preset of the Llama family
     (``tiny`` | ``tiny-moe`` | ``llama3-1b`` | ``llama3-8b``);
-    ``family`` (``llama`` | ``deepseek_v3``) + ``widths`` (the family's
-    published ``config.json`` keys -> numbers, models/families.py)
-    build any config of either family -- a key the family lacks is
-    refused, one left out keeps its default.  ``deepseek_v3``
+    ``family`` (``llama`` | ``deepseek_v3`` | ``olmo_hybrid``) +
+    ``widths`` (the family's published ``config.json`` keys -> numbers;
+    ``olmo_hybrid`` also takes the list ``layer_types`` and the boolean
+    ``linear_allow_neg_eigval``: models/families.py) build any config
+    of a family -- a key the family lacks is refused, one left out
+    keeps its default.  ``deepseek_v3``
     (models/deepseek.py) is latent attention over a paged latent pool,
     a leading dense layer and drop-less routed experts, served by the
     same batcher; ``attention: flash`` and ``decode_kernel`` choose its
@@ -244,6 +246,14 @@ class LLM(PipelineElement):
     recorder's ``llm_tick:demux`` info).  Where the Llama family's
     paged decode kernel serves decode (``decode_kernel``), a retired
     block observes ``llm_decode_live_grid_share`` the same way.
+    ``olmo_hybrid`` (models/olmo_hybrid.py) is gated delta-rule layers
+    with a per-slot recurrent state beside full-attention layers over
+    K/V pages, in the published ``layer_types`` pattern; it refuses the
+    same parameters by name (a prefix or a draft would need a snapshot
+    of the state), its retired decode blocks observe
+    ``llm_state_traffic_share``, and the recorder gets one
+    ``build:llm_cache`` event whose info is the bytes of each cache
+    pool.
 
     ASYNC by default: each frame parks and its request hops to the
     element's device WORKER THREAD, which owns the model and the shared
@@ -352,8 +362,8 @@ class LLM(PipelineElement):
             raise ValueError(problem)
         if settings.get("family") is not None:
             family = str(settings["family"]).strip().lower()
-            if family == "deepseek_v3":
-                return self._ensure_latent_model(settings)
+            if family in self._ONE_CHIP_FAMILIES:
+                return self._ensure_one_chip_family(settings, family)
             base = llama.LlamaConfig.from_widths(
                 settings.get("widths") or {})
             if vocab is not None:
@@ -461,34 +471,52 @@ class LLM(PipelineElement):
         # the chaos probe arms the ``decode_block`` injection point.
         self._build_batcher(params, config, settings, cache_put)
 
-    def _ensure_latent_model(self, settings: dict):
-        """The deepseek_v3 family (latent attention, routed experts;
-        models/deepseek.py) of the published ``widths``: bfloat16
-        weights and latent page pool on ONE chip.  What the family
-        cannot serve was refused above, by the parameter's name."""
+    # The families built from ``widths`` on ONE chip: the module, its
+    # config class, the config field ``decode_kernel`` sets with the
+    # value each choice means, and what has no partition specs.
+    _ONE_CHIP_FAMILIES = {
+        "deepseek_v3": (
+            deepseek, deepseek.DeepseekConfig, "decode_attention",
+            {"auto": "auto", "paged-kernel": "flash",
+             "reference": "dense"},
+            "its latent pool or its experts"),
+        "olmo_hybrid": (
+            olmo_hybrid, olmo_hybrid.OlmoHybridConfig, "kernels",
+            {"auto": "auto", "paged-kernel": "on", "reference": "off"},
+            "its state pool"),
+    }
+
+    def _ensure_one_chip_family(self, settings: dict, family: str):
+        """A family of the published ``widths`` that serves unquantized
+        on ONE chip -- ``deepseek_v3`` (latent attention over a latent
+        page pool, routed experts; models/deepseek.py) or
+        ``olmo_hybrid`` (gated delta-rule layers with a per-slot
+        float32 state beside full-attention layers over K/V pages;
+        models/olmo_hybrid.py).  What the family cannot serve was
+        refused above, by the parameter's name."""
+        module, config_class, kernel_field, kernel_values, unsharded = \
+            self._ONE_CHIP_FAMILIES[family]
         plan = self._stage_plan()
         if plan is not None and plan.mesh.size > 1:
             raise ValueError(
-                f"placement={dict(plan.mesh.shape)}: the deepseek_v3 "
-                f"family has no partition specs for its latent pool or "
-                f"its experts; place it on one chip")
+                f"placement={dict(plan.mesh.shape)}: the {family} "
+                f"family has no partition specs for {unsharded}; place "
+                f"it on one chip")
         decode_kernel = str(settings.get("decode_kernel",
                                          "auto")).strip().lower()
-        kernel_to_attention = {"auto": "auto", "paged-kernel": "flash",
-                               "reference": "dense"}
-        if decode_kernel not in kernel_to_attention:
+        if decode_kernel not in kernel_values:
             raise ValueError(
-                f"decode_kernel={decode_kernel!r}: with the deepseek_v3 "
-                f"family one of {'|'.join(sorted(kernel_to_attention))}")
+                f"decode_kernel={decode_kernel!r}: with the {family} "
+                f"family one of {'|'.join(sorted(kernel_values))}")
         fields = {"max_seq": int(settings.get("max_seq", 256)),
                   "attention": str(settings.get("attention", "dense")),
-                  "decode_attention": kernel_to_attention[decode_kernel]}
+                  kernel_field: kernel_values[decode_kernel]}
         if settings.get("vocab_size") is not None:
             fields["vocab_size"] = int(settings["vocab_size"])
-        config = deepseek.DeepseekConfig.from_widths(
+        config = config_class.from_widths(
             settings.get("widths") or {}, **fields)
         params = _restore(
-            deepseek.init_params(
+            module.init_params(
                 jax.random.PRNGKey(int(settings.get("seed", 0))), config),
             settings.get("checkpoint"))
         self._build_batcher(params, config, settings, None)
@@ -496,6 +524,7 @@ class LLM(PipelineElement):
     def _build_batcher(self, params, config, settings: dict, cache_put):
         ledger = self._ledger()
         kv_pages = settings.get("kv_pages")
+        started = time.perf_counter()
         self._batcher = ContinuousBatcher(
             params, config,
             max_slots=int(settings.get("max_slots", 8)),
@@ -516,6 +545,16 @@ class LLM(PipelineElement):
             fault_probe=self._fault_probe,
             trace=None if self._recorder() is None else self._trace_tick,
             cache_put=cache_put)
+        recorder = self._recorder()
+        if recorder is not None:
+            # What the cache holds, pool by pool, in bytes.
+            pools = {
+                jax.tree_util.keystr(path): int(leaf.nbytes)
+                for path, leaf in jax.tree_util.tree_leaves_with_path(
+                    self._batcher.cache)}
+            recorder.record("build", None, None, "llm_cache",
+                            (time.perf_counter() - started) * 1000.0,
+                            pools)
 
     def _stage_plan(self):
         """The MeshPlan of this element's placed stage (its definition
@@ -842,6 +881,13 @@ class LLM(PipelineElement):
                     telemetry.registry.observe(
                         "llm_moe_load_imbalance",
                         observed["moe_load_imbalance"])
+                if "state_traffic_share" in observed:
+                    # Of the cache bytes a retired block's steps moved,
+                    # the share that was recurrent state
+                    # (models/olmo_hybrid.py:loop_stats).
+                    telemetry.registry.observe(
+                        "llm_state_traffic_share",
+                        observed["state_traffic_share"])
                 if "paged_grid_steps" in observed:
                     # How much of the paged decode kernel's grid had a
                     # live page to stream at the block's first step.

@@ -65,7 +65,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import deepseek, llama
+from . import deepseek, llama, olmo_hybrid
 from .paged import PageAllocator, init_paged_cache, pages_per_slot
 from .quant import draft_params
 from ..utils.misc import next_power_of_two
@@ -201,7 +201,13 @@ def model_family(config):
     ``decode_loop``, ``check_serving``,
     ``_matmul_safe_config``; sampling is shared).  A family may also
     say ``ADMISSION_LOGITS_AT_LAST`` (its admission computes the logits
-    of one chunk position, which the batcher then names), leave out
+    of one chunk position, which the batcher then names) and
+    ``ADMISSION_CARRIES_STATE`` (its chunks hand a recurrent state on:
+    a last chunk is handed over where it starts and not moved back to
+    fit the slot -- the overlap would be applied twice, so the family
+    takes a chunk that spills -- and the recorder's ``prefill`` info
+    counts the chunks that were handed a state: ``state_carried``),
+    leave out
     ``prefill_into_slots`` (admission stays one slot a program) and
     return block statistics after ``decode_loop``'s twelve results,
     which its ``loop_stats`` turns into what the LLM element observes;
@@ -210,6 +216,8 @@ def model_family(config):
     then counts how much of each block's grid streams a live page)."""
     if isinstance(config, deepseek.DeepseekConfig):
         return deepseek
+    if isinstance(config, olmo_hybrid.OlmoHybridConfig):
+        return olmo_hybrid
     return llama
 
 
@@ -247,7 +255,8 @@ class _LoopBlock:
 
 class ContinuousBatcher:
     def __init__(self, params,
-                 config: llama.LlamaConfig | deepseek.DeepseekConfig,
+                 config: llama.LlamaConfig | deepseek.DeepseekConfig
+                 | olmo_hybrid.OlmoHybridConfig,
                  max_slots: int = 8, max_seq: int | None = None,
                  prefill_chunk: int = 512, rng_seed: int = 0,
                  inflight: int = 2,
@@ -434,6 +443,9 @@ class ContinuousBatcher:
         # Device calls (programs and explicit uploads) made by the
         # fold-in and the page-table sync, counted where they are made.
         self._launches = 0
+        # Single-slot chunks that did not start their prompt (a family
+        # with a recurrent state was handed one by the chunk before).
+        self._state_carried = 0
         self._loop_chain: dict | None = None
         self._loop_inflight: deque[_LoopBlock] = deque()
         self._eos_width = 1
@@ -584,6 +596,7 @@ class ContinuousBatcher:
             self._admission_advance(slot, request, start,
                                     len(chunk_tokens), logits)
             chunks += 1
+            self._state_carried += start > 0
         return chunks
 
     def _prefill_tick_batched(self):
@@ -646,9 +659,13 @@ class ContinuousBatcher:
         compiled shape per admission; pad positions hold garbage KV, but
         decode writes each position before the length mask ever admits
         it, and the causal prefill mask never looks past the query
-        position."""
-        start = min(request.prefill_pos,
-                    self.max_seq - self.prefill_chunk)
+        position.  A family with a recurrent state takes the chunk
+        where it starts (``ADMISSION_CARRIES_STATE``): the overlap of
+        a moved chunk would enter the state twice, so its admission
+        copes with a chunk that spills past the slot instead."""
+        start = request.prefill_pos
+        if not getattr(self._family, "ADMISSION_CARRIES_STATE", False):
+            start = min(start, self.max_seq - self.prefill_chunk)
         return start, request.prompt_tokens[
             start:start + self.prefill_chunk]
 
@@ -727,9 +744,13 @@ class ContinuousBatcher:
         self._admit()
         if traced:
             self._phase("admit")
+        carried = self._state_carried
         chunks = self._prefill_tick()
         if traced:
-            self._phase("prefill", {"chunks": chunks})
+            info = {"chunks": chunks}
+            if getattr(self._family, "ADMISSION_CARRIES_STATE", False):
+                info["state_carried"] = self._state_carried - carried
+            self._phase("prefill", info)
         decoding = [i for i in range(self.max_slots) if self.decoding[i]]
         if self.device_loop:
             if decoding or self._pending_first or self._loop_inflight:

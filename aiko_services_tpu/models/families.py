@@ -2,7 +2,8 @@
 ``config.json`` keys (``widths``) each takes and what each refuses.
 Imports nothing of jax, so the create-time parameter check
 (``analysis/params.py``) and the runtime (``elements/llm.py``,
-``models/llama.py``, ``models/deepseek.py``) read ONE table.
+``models/llama.py``, ``models/deepseek.py``, ``models/olmo_hybrid.py``)
+read ONE table.
 
 An LLM element names a family and hands it widths::
 
@@ -12,7 +13,10 @@ An LLM element names a family and hands it widths::
                    "max_seq": 8192, ...}
 
 A key the family lacks is an error; a key left out keeps the family's
-default (``LlamaConfig`` / ``DeepseekConfig``).
+default (``LlamaConfig`` / ``DeepseekConfig`` / ``OlmoHybridConfig``).
+Widths are numbers, but for the keys of ``LIST_WIDTHS`` (a list of
+names from the given set: the ``olmo_hybrid`` family's per-layer
+``layer_types``) and ``BOOL_WIDTHS`` (a boolean).
 """
 
 from __future__ import annotations
@@ -45,7 +49,27 @@ FAMILY_WIDTHS: dict[str, dict[str, str]] = {
         "first_k_dense_replace": "first_dense_layers",
         "routed_scaling_factor": "routed_scaling_factor",
         "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps"},
+    "olmo_hybrid": {
+        "vocab_size": "vocab_size", "hidden_size": "dim",
+        "num_hidden_layers": "n_layers",
+        "num_attention_heads": "n_heads",
+        "num_key_value_heads": "n_kv_heads",
+        "intermediate_size": "hidden_dim",
+        "layer_types": "layer_types",
+        "linear_num_key_heads": "linear_key_heads",
+        "linear_num_value_heads": "linear_value_heads",
+        "linear_key_head_dim": "linear_key_dim",
+        "linear_value_head_dim": "linear_value_dim",
+        "linear_conv_kernel_dim": "linear_conv_kernel",
+        "linear_allow_neg_eigval": "linear_allow_neg_eigval",
+        "rms_norm_eps": "norm_eps"},
 }
+
+#: width key -> the names its list may hold (every other width is a
+#: number or, for ``BOOL_WIDTHS``, a boolean)
+LIST_WIDTHS: dict[str, tuple] = {
+    "layer_types": ("linear_attention", "full_attention")}
+BOOL_WIDTHS = ("linear_allow_neg_eigval",)
 
 #: family -> {parameter: why it is refused} for every parameter whose
 #: mere presence with a non-default value the family cannot serve
@@ -64,6 +88,17 @@ FAMILY_REFUSES: dict[str, dict[str, str]] = {
                         "under grouped expert matmuls",
         "model": "a family is built from widths, not from a preset",
     },
+    "olmo_hybrid": {
+        "quantize": "the family serves bfloat16 weights, bfloat16 K/V "
+                    "pages and a float32 recurrent state (no int8)",
+        "speculative": "a rejected draft would have to roll the "
+                       "recurrent state back; no snapshot of it is kept",
+        "spec_tokens": "speculation is not served",
+        "spec_window": "speculation is not served",
+        "prefix_cache": "a shared prefix is a snapshot of the recurrent "
+                        "state, not pages; none is kept",
+        "model": "a family is built from widths, not from a preset",
+    },
 }
 
 _FLOAT_FIELDS = ("rope_theta", "norm_eps", "routed_scaling_factor")
@@ -79,8 +114,32 @@ def config_fields(family: str, widths: dict) -> dict:
         raise ValueError(
             f"widths: the {family} family has no {unknown} "
             f"(has: {sorted(known)})")
-    return {known[key]: float(value) if known[key] in _FLOAT_FIELDS
-            else int(value) for key, value in widths.items()}
+    return {known[key]: _field_value(key, known[key], value)
+            for key, value in widths.items()}
+
+
+def _field_value(key: str, field: str, value):
+    if key in LIST_WIDTHS:
+        return tuple(str(item) for item in value)
+    if key in BOOL_WIDTHS:
+        return bool(value)
+    return float(value) if field in _FLOAT_FIELDS else int(value)
+
+
+def _width_error(key: str, value) -> str | None:
+    """What is wrong with one width's value, or None."""
+    if key in LIST_WIDTHS:
+        names = LIST_WIDTHS[key]
+        if not isinstance(value, (list, tuple)) or not value \
+                or any(item not in names for item in value):
+            return f"widths: {key} must be a list of {'|'.join(names)}"
+        return None
+    if key in BOOL_WIDTHS:
+        return None if isinstance(value, bool) \
+            else f"widths: {key} must be true or false"
+    if isinstance(value, bool) or not isinstance(value, (int, float)):
+        return f"widths: ['{key}'] must be numbers"
+    return None
 
 
 _OFF = ("", "off", "false", "0", "no", "none", "auto")
@@ -95,7 +154,8 @@ def _is_default(name: str, value) -> bool:
 def family_spec_error(parameters: dict) -> str | None:
     """What is wrong with an LLM element's ``family`` / ``widths`` pair
     and the parameters beside it, or None: an unknown family, widths
-    without a family (or not a mapping of numbers), a width the family
+    without a family (or not a mapping of numbers -- a list of layer
+    names or a boolean where ``LIST_WIDTHS`` / ``BOOL_WIDTHS`` say so), a width the family
     lacks, a parameter the family refuses -- and ``decode_block``,
     which no family serves any more (an unknown parameter is ignored,
     and this one would then decode by the per-token tick)."""
@@ -123,11 +183,10 @@ def family_spec_error(parameters: dict) -> str | None:
         if unknown:
             return f"widths: the {family} family has no {unknown} " \
                    f"(has: {sorted(known)})"
-        bad = sorted(key for key, value in widths.items()
-                     if isinstance(value, bool)
-                     or not isinstance(value, (int, float)))
-        if bad:
-            return f"widths: {bad} must be numbers"
+        for key in sorted(widths):
+            problem = _width_error(key, widths[key])
+            if problem is not None:
+                return problem
     for name, why in FAMILY_REFUSES[family].items():
         if name in parameters and not _is_default(name, parameters[name]):
             return f"{name}={parameters[name]!r}: not with family " \

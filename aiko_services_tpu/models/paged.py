@@ -11,7 +11,13 @@ KV in fixed-size **pages** instead:
   -- the per-token-per-head scales ride their page); a LATENT cache
   (models/deepseek.py) is ONE pool under the key ``latent``, ``[L, P,
   W, page_tokens]`` -- a page's tokens lie along the LAST axis -- behind
-  the same table and allocator;
+  the same table and allocator; a config that says which of its layers
+  own pages (``n_paged_layers``: models/olmo_hybrid.py, whose recurrent
+  layers own none) gets pools that deep, and what it keeps PER SLOT
+  instead (``slot_state``: the recurrent state and the convolution's
+  tail, ``[L_lin, slots, ...]``, addressed by the slot and by no page
+  table) rides the same cache dict -- one ``init_paged_cache``, one
+  allocator, one ``recover()``;
 - a device **page table** ``[B, pages_per_slot] int32`` mapping each
   slot's logical pages to physical pages.  Entry 0 is the reserved
   TRASH page: unallocated logical pages point at it, and inactive
@@ -58,6 +64,8 @@ both.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -85,7 +93,12 @@ def init_paged_cache(config, batch: int, max_seq: int | None = None,
     or, for a config with a ``latent_width`` (latent attention: one
     payload a token, no heads, no k/v pair), ``{"latent": pool,
     "page_table"}`` with the pool ``[L, P, latent_width, page_tokens]``
-    behind the same table and allocator.  A latent page is stored
+    behind the same table and allocator.  A config with
+    ``n_paged_layers`` (layers of two kinds: only some own pages) gets
+    k/v pools that deep, and its ``slot_state`` (``name -> (leading
+    shape, shape a slot, dtype)``) laid out ``[*leading, batch, ...]``
+    under each name beside them, zero: what a slot holds that no page
+    addresses.  A latent page is stored
     TOKENS-MINOR: a width that is no multiple of the 128 lanes (576)
     makes that the v5e's own default layout of the array, and a pool
     declared rows-minor was transposed -- three passes over 2.4 GB --
@@ -112,8 +125,8 @@ def init_paged_cache(config, batch: int, max_seq: int | None = None,
                     (c.n_layers, pool_pages, latent_width, page_tokens),
                     dtype=jnp.dtype(c.dtype)),
                 "page_table": table}
-    shape = (c.n_layers, pool_pages, page_tokens,
-             c.n_kv_heads * c.head_dim)
+    shape = (getattr(c, "n_paged_layers", c.n_layers), pool_pages,
+             page_tokens, c.n_kv_heads * c.head_dim)
     if c.kv_dtype == "int8":
         def side():
             return {"int8": jnp.zeros(shape, dtype=jnp.int8),
@@ -123,7 +136,11 @@ def init_paged_cache(config, batch: int, max_seq: int | None = None,
     else:
         def side():
             return jnp.zeros(shape, dtype=jnp.dtype(c.dtype))
-    return {"k": side(), "v": side(), "page_table": table}
+    per_slot = {
+        name: jnp.zeros(lead + (batch,) + shape, dtype=jnp.dtype(dtype))
+        for name, (lead, shape, dtype)
+        in getattr(c, "slot_state", {}).items()}
+    return {"k": side(), "v": side(), "page_table": table, **per_slot}
 
 
 def is_paged(cache) -> bool:
@@ -165,6 +182,28 @@ def gather_layer(layer, table):
     return _gather(layer, table)
 
 
+#: The v5e compiler splits a gather whose slice is larger than 512 KiB
+#: into "mini-gathers" over halves of the operand's minor axis -- and
+#: materialises each half of the WHOLE pool to do it (two 3.1 GB copies
+#: a chunk at [3, 1057, 128, 3840] bf16, found by compiling the real
+#: shapes for the described chip: PERF.md, PR 33).
+_GATHER_SLICE_BYTES = 480 << 10
+
+
+def _gather_parts(arr) -> int:
+    """Into how many part-pages (a power of two, whole 16-row tiles) a
+    page of pool ``arr [L, P, pt, ...]`` is gathered so that one slice
+    stays under :data:`_GATHER_SLICE_BYTES`: 1 for every pool whose page
+    already is (1024-wide bf16 pages of 128 tokens are 256 KiB)."""
+    page_tokens = arr.shape[2]
+    page_bytes = arr.dtype.itemsize * math.prod(arr.shape[2:])
+    parts = 1
+    while page_bytes // parts > _GATHER_SLICE_BYTES \
+            and page_tokens % (32 * parts) == 0:
+        parts *= 2
+    return parts
+
+
 def gather_rows(side, table, index):
     """Layer ``index`` (may be traced) of a STACKED pool side
     ``[L, P, pt, ...]`` -> the logical rows ``[N, T, ...]`` of table
@@ -172,7 +211,17 @@ def gather_rows(side, table, index):
     per-layer slice of the pool materialises ahead of it (admission's
     in-scan read; the pool is closed over, never a scan input)."""
     def take(arr):
-        rows = arr[index, table]               # [N, pps, pt, ...]
+        parts = _gather_parts(arr)
+        if parts == 1:
+            rows = arr[index, table]           # [N, pps, pt, ...]
+        else:
+            # part-pages: the same bytes, each slice under the size
+            # past which the compiler splits the OPERAND
+            split = arr.reshape(arr.shape[0], -1, arr.shape[2] // parts,
+                                *arr.shape[3:])
+            rows = split[index, (table[..., None] * parts
+                                 + jnp.arange(parts)).reshape(
+                                     table.shape[0], -1)]
         return rows.reshape(table.shape[0], -1, *arr.shape[3:])
     if is_quantized(side):
         return {"int8": take(side["int8"]), "scale": take(side["scale"])}

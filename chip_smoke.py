@@ -367,7 +367,7 @@ def compare(name: str, kernel, reference, atol: float, rtol: float) -> str:
 
 
 def kernels(settings: dict, rehearse: bool, facts: dict) -> None:
-    """All seven kernel-table entries at the serving shapes, Mosaic
+    """The kernel-table entries at the serving shapes, Mosaic
     compiled (``interpret=False``; the rehearsal interprets), against
     the XLA reference each falls back to.  Queries are scaled up so the
     softmax is peaked: with diffuse random attention every output is
@@ -582,6 +582,48 @@ def kernels(settings: dict, rehearse: bool, facts: dict) -> None:
         close(f"topk[{jnp.dtype(dtype).name},k={k}]",
               lambda: topk(logits, k, interpret=interpret),
               lambda: jax.lax.top_k(logits, k), atol=0, rtol=0)
+
+    # 8-9. the gated delta rule (ops/pallas_gdn.py) at Olmo-Hybrid-7B's
+    # widths -- 30 heads of 96 / 192 -- against the jax.numpy form each
+    # falls back to: one 512-token admission chunk from a state that is
+    # not zero, and one decode step over the stored pool with rows that
+    # sit out (they keep their state bit for bit).  float32 both sides.
+    from aiko_services_tpu.ops.pallas_gdn import (
+        gated_delta_chunk_scan, gated_delta_decode_step, pack_state,
+        state_pack)
+    g_heads, g_dk, g_dv, g_chunk = (6, 24, 64, 128) if rehearse \
+        else (30, 96, 192, 512)
+    f32 = jnp.float32
+
+    def unit(rows):
+        return rows / jnp.linalg.norm(rows, axis=-1, keepdims=True)
+
+    def recurrence_inputs(tokens):
+        return (unit(normal((tokens, g_heads, g_dk), f32)) * g_dk ** -0.5,
+                unit(normal((tokens, g_heads, g_dk), f32)),
+                normal((tokens, g_heads, g_dv), f32),
+                -jnp.exp(normal((tokens, g_heads), f32, 2.0) - 3.0),
+                2.0 * jax.nn.sigmoid(normal((tokens, g_heads), f32, 2.0)))
+
+    scan_inputs = recurrence_inputs(g_chunk)
+    state = normal((g_heads, g_dk, g_dv), f32)
+    close("gated_delta_chunk_scan",
+          lambda: gated_delta_chunk_scan(*scan_inputs, state, kernel=True,
+                                         interpret=interpret),
+          lambda: jax.jit(gated_delta_chunk_scan)(*scan_inputs, state),
+          atol=1e-4, rtol=1e-4)
+    pack = state_pack(g_heads, g_dv)
+    pool = pack_state(normal((3, slots, g_heads, g_dk, g_dv), f32), pack)
+    step_inputs = recurrence_inputs(slots)
+    decoding = jnp.arange(slots) % 3 != 1
+    close("gated_delta_decode_step",
+          lambda: gated_delta_decode_step(
+              *step_inputs, pool, jnp.int32(1), decoding, pack=pack,
+              kernel=True, interpret=interpret),
+          lambda: jax.jit(lambda *inputs: gated_delta_decode_step(
+              *inputs, pool, jnp.int32(1), decoding, pack=pack))(
+                  *step_inputs),
+          atol=1e-5, rtol=1e-5)
 
 
 def _verify_reference(k_rows, v_rows, q, k_new, v_new, starts, positions):

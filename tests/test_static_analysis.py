@@ -38,6 +38,7 @@ DEFINITION_FIXTURES = {
     "bad_prefix_cache.json": "bad-parameter",
     "bad_llm_family.json": "bad-parameter",
     "bad_llm_decode_block.json": "bad-parameter",
+    "bad_llm_hybrid_family.json": "bad-parameter",
     "bad_data_plane.json": "bad-parameter",
     "bad_qos.json": "bad-parameter",
     "bad_qos_tenant.json": "bad-parameter",
