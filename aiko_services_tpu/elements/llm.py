@@ -44,6 +44,7 @@ from ..models.batching import ContinuousBatcher, Request
 from ..models.checkpoint import maybe_restore as _restore
 from ..models.families import family_spec_error
 from ..models.paged import is_paged
+from ..models.quant import is_quantized
 from ..models.tokenizer import ByteTokenizer, load_tokenizer
 from ..pipeline import PipelineElement, StreamEvent
 from ..services import Actor
@@ -253,7 +254,10 @@ class LLM(PipelineElement):
     of the state), its retired decode blocks observe
     ``llm_state_traffic_share``, and the recorder gets one
     ``build:llm_cache`` event whose info is the bytes of each cache
-    pool.
+    pool.  An int8 model of the Llama family also gets
+    ``build:llm_unembed``: the fused unembed's blocks at the decode
+    width and ``padded_weight_bytes``, what each of its calls copies
+    to pad the head (0 unless no block fits: ops/pallas_matmul.py).
 
     ASYNC by default: each frame parks and its request hops to the
     element's device WORKER THREAD, which owns the model and the shared
@@ -555,6 +559,18 @@ class LLM(PipelineElement):
             recorder.record("build", None, None, "llm_cache",
                             (time.perf_counter() - started) * 1000.0,
                             pools)
+            unembed = params.get("unembed")
+            if is_quantized(unembed) and unembed["int8"].ndim == 2:
+                # How the fused int8 unembed (ops/pallas_matmul.py)
+                # blocks this head at the decode width, and what a call
+                # copies to get there: 0 unless the kernel has to pad.
+                from ..ops.pallas_matmul import matmul_blocks
+                block_m, block_d, block_f, padded = matmul_blocks(
+                    self._batcher.max_slots, *unembed["int8"].shape)
+                recorder.record(
+                    "build", None, None, "llm_unembed", 0.0,
+                    {"block_m": block_m, "block_d": block_d,
+                     "block_f": block_f, "padded_weight_bytes": padded})
 
     def _stage_plan(self):
         """The MeshPlan of this element's placed stage (its definition

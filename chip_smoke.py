@@ -49,6 +49,7 @@ import contextlib
 import importlib.metadata
 import json
 import os
+import re
 import shutil
 import sys
 import tempfile
@@ -574,6 +575,23 @@ def kernels(settings: dict, rehearse: bool, facts: dict) -> None:
                   lambda x: (x @ leaf["int8"].astype(x.dtype))
                   * leaf["scale"].astype(x.dtype))(x),
               atol=1e-1, rtol=2e-2)
+    if not interpret:
+        # The head goes to the kernel as the array the tree holds: a
+        # pad of it inside a loop is a copy of the whole weight in
+        # every decode step (ISSUE 34), and only the compiled program
+        # shows whether one is left.
+        def two_steps(x, w, s):
+            return jax.lax.fori_loop(
+                0, 2, lambda _, x: x + int8_matmul(
+                    x, w, s, interpret=False)[:, :dim], x)
+        text = jax.jit(two_steps).lower(
+            normal((slots, dim)), leaf["int8"],
+            leaf["scale"]).compile().as_text()
+        pads = re.findall(r"= s8\[[\d,]*\](?:\{[^}]*\})? pad\(.*", text)
+        if pads:
+            raise RuntimeError(
+                f"int8_matmul pads its int8 weight: {pads[0][:160]}")
+        facts["int8_matmul[loop]"] = "no s8 pad"
 
     # 7. topk: EXACTLY lax.top_k, values and indices, on the f32 logits
     # sampling hands it and on raw bf16 logits.

@@ -47,3 +47,14 @@ def run_until(rt, predicate, timeout=5.0):
     predicate()'s final value."""
     rt.run(until=predicate, timeout=timeout)
     return predicate()
+
+
+def all_eqns(jaxpr):
+    """Every equation of a jaxpr, those of nested jaxprs (``pjit``,
+    ``scan``, ``while``, ``cond`` bodies) too."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                yield from all_eqns(inner)

@@ -23,8 +23,10 @@ from aiko_services_tpu.ops.pallas_decode import (
     _combine_self, _prep_query, _split_paged, flash_decode_append_paged,
     flash_decode_attention, flash_decode_attention_paged,
     flash_verify_append, paged_grid_steps, paged_pages_per_step)
-from aiko_services_tpu.ops.pallas_matmul import int8_matmul
+from aiko_services_tpu.ops.pallas_matmul import (
+    VMEM_BUDGET_BYTES, int8_matmul, matmul_blocks, tile_bytes)
 from aiko_services_tpu.ops.pallas_topk import topk as pallas_topk
+from conftest import all_eqns, run_until
 
 
 # -- paged flash-decode -----------------------------------------------------
@@ -660,6 +662,86 @@ def test_int8_matmul_matches_xla():
         np.asarray(reference, dtype=np.float32), atol=1e-1, rtol=2e-2)
 
 
+def _int8_case(m, d, f, seed=3):
+    """Exactly-representable inputs: the kernel must EQUAL the XLA
+    reference ``(x @ w.astype) * scale`` whatever its blocks."""
+    rng = np.random.default_rng(seed)
+    leaf = quantize_weight(
+        jnp.asarray(rng.integers(-7, 8, (d, f)), jnp.float32))
+    x = jnp.asarray(rng.integers(-3, 4, (m, d)), jnp.float32)
+    reference = (x @ leaf["int8"].astype(x.dtype)) \
+        * leaf["scale"].astype(x.dtype)
+    return x, leaf, reference
+
+
+def _pads_of_int8(jaxpr) -> list:
+    """Every ``pad`` equation over an int8 operand, nested jaxprs
+    included (the jitted call is a ``pjit`` equation)."""
+    return [eqn for eqn in all_eqns(jaxpr)
+            if eqn.primitive.name == "pad"
+            and eqn.invars[0].aval.dtype == jnp.int8]
+
+
+@pytest.mark.parametrize("m", [8, 32, 520])
+@pytest.mark.parametrize("d,f", [(256, 1152), (256, 640), (128, 2176)])
+def test_int8_matmul_reads_the_weight_where_it_lies(d, f, m):
+    """Widths of 128 x an odd number (92,544 = 128 x 723 in small): the
+    blocks follow the weight, so no ``pad`` of the int8 operand is
+    traced -- on the chip that pad was a copy of the whole head in
+    every decode step (ISSUE 34) -- and the answer is the reference's,
+    ragged last column block included."""
+    x, leaf, reference = _int8_case(m, d, f)
+    assert matmul_blocks(m, d, f, x.dtype)[3] == 0
+    jaxpr = jax.make_jaxpr(
+        lambda x, w, s: int8_matmul(x, w, s, interpret=True))(
+        x, leaf["int8"], leaf["scale"])
+    assert "pallas_call" in str(jaxpr) and not _pads_of_int8(jaxpr.jaxpr)
+    out = int8_matmul(x, leaf["int8"], leaf["scale"], interpret=True)
+    assert out.shape == (m, f)
+    assert np.array_equal(np.asarray(out), np.asarray(reference))
+
+
+@pytest.mark.parametrize("m", [8, 300])
+@pytest.mark.parametrize("d,f", [(96, 1000), (200, 384)])
+def test_int8_matmul_pads_what_no_block_fits(d, f, m):
+    """A width or a depth that is no multiple of 128 (tiny test
+    vocabularies, GPT-2's 50,257) keeps the pad path, says so in
+    ``padded_weight_bytes``, and still answers right."""
+    x, leaf, reference = _int8_case(m, d, f)
+    block_m, block_d, block_f, padded = matmul_blocks(
+        m, d, f, x.dtype, block_d=128)
+    assert padded >= d * f and padded % block_d == 0
+    jaxpr = jax.make_jaxpr(
+        lambda x, w, s: int8_matmul(x, w, s, block_d=128,
+                                    interpret=True))(
+        x, leaf["int8"], leaf["scale"])
+    assert _pads_of_int8(jaxpr.jaxpr)
+    out = int8_matmul(x, leaf["int8"], leaf["scale"], block_d=128,
+                      interpret=True)
+    assert np.array_equal(np.asarray(out), np.asarray(reference))
+
+
+@pytest.mark.parametrize("m", [16, 32, 512, 4096])
+@pytest.mark.parametrize("d,f", [(2048, 92544), (4096, 128256),
+                                 (2048, 128256), (4096, 32000)])
+def test_matmul_blocks_at_the_real_heads(d, f, m):
+    """Arithmetic only, no array: InternLM2-1.8B's, llama3-8b's,
+    llama3-1b's and Llama-2's heads are blocked as they lie (nothing
+    padded), by tiles the chip's VMEM holds, and the grid covers every
+    column and every row of the contraction."""
+    for dtype in (jnp.bfloat16, jnp.float32):
+        block_m, block_d, block_f, padded = matmul_blocks(m, d, f, dtype)
+        assert padded == 0
+        assert d % block_d == 0 and block_d % 128 == 0
+        assert block_f % 128 == 0 and -(-f // block_f) * block_f >= f
+        assert block_m % 8 == 0 and block_m <= max(m, 8)
+        assert tile_bytes(block_m, block_d, block_f,
+                          jnp.dtype(dtype).itemsize) <= VMEM_BUDGET_BYTES
+    # a block asked for by name is kept
+    assert matmul_blocks(m, d, f, jnp.bfloat16, block_f=128,
+                         block_d=512)[1:3] == (512, 128)
+
+
 def test_int8_matmul_serves_the_unembed():
     """decode_step logits with matmul_kernel='pallas' (the fused
     kernel on the quantized unembed, interpret mode here) match
@@ -680,6 +762,41 @@ def test_int8_matmul_serves_the_unembed():
                                atol=5e-2, rtol=5e-2)
     assert matmul_backend("off") == "reference"
     assert matmul_backend("pallas") == "pallas-int8"
+
+
+@pytest.mark.parametrize("vocab,padded", [(384, 0), (300, 64 * 384)])
+def test_llm_element_stamps_the_unembed_blocks(runtime, vocab, padded):
+    """A served int8 model says in the flight recorder how the fused
+    unembed blocks its head at the decode width and what a call copies
+    to get there (``build:llm_unembed``, beside ``build:llm_cache``):
+    0 for a head of whole 128-lane tiles, the padded weight for one
+    that is not."""
+    import queue
+
+    from aiko_services_tpu.pipeline import Pipeline
+
+    responses = queue.Queue()
+    pipeline = Pipeline({
+        "version": 0, "name": "unembed_stamp", "runtime": "jax",
+        "parameters": {}, "graph": ["(llm)"],
+        "elements": [{
+            "name": "llm", "input": [{"name": "text"}],
+            "output": [{"name": "text"}],
+            "parameters": {"model": "tiny", "quantize": "int8",
+                           "vocab_size": vocab, "max_seq": 64,
+                           "max_new_tokens": 2, "max_slots": 4},
+            "deploy": {"local": {
+                "module": "aiko_services_tpu.elements.llm",
+                "class_name": "LLM"}}}]}, runtime=runtime)
+    stream = pipeline.create_stream_local("1", queue_response=responses)
+    pipeline.create_frame_local(stream, {"text": "hi"})
+    assert run_until(runtime, lambda: responses.qsize() >= 1,
+                     timeout=180.0)
+    stamps = [event[6] for event in pipeline.recorder.snapshot()
+              if event[1] == "build" and event[4] == "llm_unembed"]
+    pipeline.stop()
+    assert stamps == [{"block_m": 8, "block_d": 64, "block_f": 384,
+                       "padded_weight_bytes": padded}]
 
 
 # -- on-TPU top-k -----------------------------------------------------------

@@ -26,6 +26,7 @@ from aiko_services_tpu.models.paged import (PageAllocator, gather_slot,
                                             pages_per_slot)
 from aiko_services_tpu.models.tokenizer import ByteTokenizer
 from aiko_services_tpu.pipeline.overlap import TransferLedger
+from conftest import all_eqns
 
 
 @pytest.fixture(scope="module")
@@ -568,14 +569,9 @@ def test_paged_admission_writes_only_its_pages(tiny_f32, starts):
 
 def _scans(jaxpr, length):
     """Every ``scan`` of ``length`` steps in a jaxpr, nested ones too."""
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "scan" \
-                and eqn.params["length"] == length:
-            yield eqn
-        for value in eqn.params.values():
-            inner = getattr(value, "jaxpr", value)
-            if hasattr(inner, "eqns"):
-                yield from _scans(inner, length)
+    return [eqn for eqn in all_eqns(jaxpr)
+            if eqn.primitive.name == "scan"
+            and eqn.params["length"] == length]
 
 
 @pytest.mark.parametrize("program", ["prefill_into_slot",
