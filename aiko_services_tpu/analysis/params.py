@@ -345,13 +345,22 @@ ELEMENT_PARAMETERS: dict[tuple[str, str], dict[str, ParamSpec]] = {
         "family": ParamSpec(
             "model family the element builds from ``widths`` "
             "(models/families.py); absent = the ``model`` presets",
-            choices=("llama", "deepseek_v3", "olmo_hybrid")),
+            choices=("llama", "deepseek_v3", "olmo_hybrid", "sdar_moe")),
         "widths": ParamSpec(
             "published config.json keys of the family (a key it lacks "
             "is refused; one left out keeps the family's default; "
             "numbers, but for olmo_hybrid's list layer_types and "
             "boolean linear_allow_neg_eigval)",
             kind="json"),
+        "block_length": ParamSpec(
+            "positions a block of the sdar_moe family's generation by "
+            "diffusion (divides kv_page_tokens and "
+            "decode_block_tokens: models/families.py)",
+            number=True, minimum=1),
+        "denoising_steps": ParamSpec(
+            "denoising passes a block of the sdar_moe family "
+            "(1..block_length)",
+            number=True, minimum=1),
         "sample_top_k": ParamSpec(
             "restrict sampled rows to the k highest logits via the "
             "ops top-k interface (0 = full-vocab categorical; the "

@@ -39,10 +39,10 @@ import time
 
 import jax
 
-from ..models import deepseek, llama, olmo_hybrid
+from ..models import deepseek, llama, olmo_hybrid, sdar
 from ..models.batching import ContinuousBatcher, Request
 from ..models.checkpoint import maybe_restore as _restore
-from ..models.families import family_spec_error
+from ..models.families import FAMILY_PARAMETERS, family_spec_error
 from ..models.paged import is_paged
 from ..models.quant import is_quantized
 from ..models.tokenizer import ByteTokenizer, load_tokenizer
@@ -227,7 +227,8 @@ class LLM(PipelineElement):
 
     MODEL FAMILIES: ``model`` picks a preset of the Llama family
     (``tiny`` | ``tiny-moe`` | ``llama3-1b`` | ``llama3-8b``);
-    ``family`` (``llama`` | ``deepseek_v3`` | ``olmo_hybrid``) +
+    ``family`` (``llama`` | ``deepseek_v3`` | ``olmo_hybrid`` |
+    ``sdar_moe``) +
     ``widths`` (the family's published ``config.json`` keys -> numbers;
     ``olmo_hybrid`` also takes the list ``layer_types`` and the boolean
     ``linear_allow_neg_eigval``: models/families.py) build any config
@@ -254,7 +255,20 @@ class LLM(PipelineElement):
     of the state), its retired decode blocks observe
     ``llm_state_traffic_share``, and the recorder gets one
     ``build:llm_cache`` event whose info is the bytes of each cache
-    pool.  An int8 model of the Llama family also gets
+    pool.  ``sdar_moe`` (models/sdar.py) generates by DIFFUSION OVER
+    BLOCKS over drop-less softmax-routed experts: ``block_length``
+    (default 4) positions a block under a block-causal mask,
+    ``denoising_steps`` (1..``block_length``, default
+    ``block_length``) denoising passes a block, each deciding the most confident masked positions, then a
+    pass that commits the block's K/V to the pages and emits it -- so a
+    pass of the device loop yields several tokens a row or none, and a
+    request's first token arrives when its first block commits.  It
+    serves by the device loop alone (``decode_block_tokens`` a multiple
+    of ``block_length``, which must divide ``kv_page_tokens``) and
+    refuses the same parameters by name; its retired blocks observe
+    ``llm_diffusion_tokens_per_row_pass``,
+    ``llm_diffusion_commit_pass_share`` and the two ``llm_moe_*``
+    histograms.  An int8 model of the Llama family also gets
     ``build:llm_unembed``: the fused unembed's blocks at the decode
     width and ``padded_weight_bytes``, what each of its calls copies
     to pad the head (0 unless no block fits: ops/pallas_matmul.py).
@@ -327,7 +341,7 @@ class LLM(PipelineElement):
     # the per-token tick in silence (families.family_spec_error).
     _MODEL_PARAMS = ("checkpoint", "tokenizer", "vocab_size", "max_seq",
                      "seed", "attention", "model", "family", "widths",
-                     "quantize",
+                     "quantize", "block_length", "denoising_steps",
                      "decode_block", "inflight", "max_slots",
                      "decode_block_tokens", "speculative", "spec_tokens",
                      "spec_window", "kv_page_tokens", "kv_pages",
@@ -488,6 +502,10 @@ class LLM(PipelineElement):
             olmo_hybrid, olmo_hybrid.OlmoHybridConfig, "kernels",
             {"auto": "auto", "paged-kernel": "on", "reference": "off"},
             "its state pool"),
+        "sdar_moe": (
+            sdar, sdar.SdarConfig, "kernels",
+            {"auto": "auto", "paged-kernel": "on", "reference": "off"},
+            "its experts"),
     }
 
     def _ensure_one_chip_family(self, settings: dict, family: str):
@@ -496,8 +514,10 @@ class LLM(PipelineElement):
         page pool, routed experts; models/deepseek.py) or
         ``olmo_hybrid`` (gated delta-rule layers with a per-slot
         float32 state beside full-attention layers over K/V pages;
-        models/olmo_hybrid.py).  What the family cannot serve was
-        refused above, by the parameter's name."""
+        models/olmo_hybrid.py) or ``sdar_moe`` (generation by diffusion
+        over blocks, softmax-routed experts; models/sdar.py, with its
+        own ``block_length`` and ``denoising_steps``).  What the family
+        cannot serve was refused above, by the parameter's name."""
         module, config_class, kernel_field, kernel_values, unsharded = \
             self._ONE_CHIP_FAMILIES[family]
         plan = self._stage_plan()
@@ -517,6 +537,9 @@ class LLM(PipelineElement):
                   kernel_field: kernel_values[decode_kernel]}
         if settings.get("vocab_size") is not None:
             fields["vocab_size"] = int(settings["vocab_size"])
+        for name, field in FAMILY_PARAMETERS.get(family, {}).items():
+            if settings.get(name) is not None:
+                fields[field] = int(settings[name])
         config = config_class.from_widths(
             settings.get("widths") or {}, **fields)
         params = _restore(
@@ -897,6 +920,16 @@ class LLM(PipelineElement):
                     telemetry.registry.observe(
                         "llm_moe_load_imbalance",
                         observed["moe_load_imbalance"])
+                if "diffusion_tokens_per_row_pass" in observed:
+                    # Tokens emitted a live row and pass, and the share
+                    # of row-passes that only stored a block's K/V
+                    # (models/sdar.py:loop_stats).
+                    telemetry.registry.observe(
+                        "llm_diffusion_tokens_per_row_pass",
+                        observed["diffusion_tokens_per_row_pass"])
+                    telemetry.registry.observe(
+                        "llm_diffusion_commit_pass_share",
+                        observed["diffusion_commit_pass_share"])
                 if "state_traffic_share" in observed:
                     # Of the cache bytes a retired block's steps moved,
                     # the share that was recurrent state

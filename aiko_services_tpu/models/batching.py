@@ -65,7 +65,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import deepseek, llama, olmo_hybrid
+from . import deepseek, llama, olmo_hybrid, sdar
 from .paged import PageAllocator, init_paged_cache, pages_per_slot
 from .quant import draft_params
 from ..utils.misc import next_power_of_two
@@ -213,11 +213,23 @@ def model_family(config):
     which its ``loop_stats`` turns into what the LLM element observes;
     ``paged_decode_pages`` says how many pages a grid step its paged
     decode kernel takes where that kernel serves the cache (the batcher
-    then counts how much of each block's grid streams a live page)."""
+    then counts how much of each block's grid streams a live page).
+    ``BLOCK_DIFFUSION`` (models/sdar.py) says that generation is by
+    diffusion over blocks of ``config.block_length``: admission
+    prefills a prompt's whole blocks (``admitted_length``) and yields
+    NO token; a joiner enters the device loop with a block
+    (``joiner_carry``, ``carry_width`` columns of the chained carry the
+    Llama family calls ``history``); a pass of the loop emits several
+    tokens a row or none, so the ring needs room for a block a row, the
+    pages are assured a block past the stored positions, a first token
+    arrives when the first block commits, and ``steps`` counts passes
+    (the family's ``loop_stats`` counts the rows in them)."""
     if isinstance(config, deepseek.DeepseekConfig):
         return deepseek
     if isinstance(config, olmo_hybrid.OlmoHybridConfig):
         return olmo_hybrid
+    if isinstance(config, sdar.SdarConfig):
+        return sdar
     return llama
 
 
@@ -256,7 +268,7 @@ class _LoopBlock:
 class ContinuousBatcher:
     def __init__(self, params,
                  config: llama.LlamaConfig | deepseek.DeepseekConfig
-                 | olmo_hybrid.OlmoHybridConfig,
+                 | olmo_hybrid.OlmoHybridConfig | sdar.SdarConfig,
                  max_slots: int = 8, max_seq: int | None = None,
                  prefill_chunk: int = 512, rng_seed: int = 0,
                  inflight: int = 2,
@@ -353,6 +365,28 @@ class ContinuousBatcher:
         # Paged KV cache (models/paged.py): fixed-size pages + per-slot
         # page table; 0 keeps the monolithic [slots, max_seq] cache.
         self.kv_page_tokens = max(0, int(kv_page_tokens))
+        # Generation by diffusion over blocks (``model_family``): the
+        # block length, 0 for a family that emits a token a row a step.
+        self._block = config.block_length \
+            if getattr(family, "BLOCK_DIFFUSION", False) else 0
+        if self._block:
+            if not self.device_loop \
+                    or self.decode_block_tokens % self._block:
+                raise ValueError(
+                    f"decode_block_tokens={self.decode_block_tokens}: "
+                    f"generation by diffusion over blocks rides the "
+                    f"device loop; a multiple of block_length "
+                    f"({self._block})")
+            if self.kv_page_tokens % self._block \
+                    or self.prefill_chunk % self._block:
+                raise ValueError(
+                    f"block_length={self._block}: must divide "
+                    f"kv_page_tokens ({self.kv_page_tokens}) and "
+                    f"prefill_chunk ({self.prefill_chunk}), so that a "
+                    f"block lies inside one page and one chunk")
+        # The longest a sequence grows: the slot's last block is the
+        # trash block where K/V is written a block at a time.
+        self._seq_limit = self.max_seq - self._block
         # Shared-prefix page cache (ISSUE 18): requests whose prompts
         # share leading pages map ONE physical copy, refcounted, and
         # skip prefill over the shared span.  Rides the page table, so
@@ -436,7 +470,7 @@ class ContinuousBatcher:
         # the page table, and a conservative length upper bound for
         # page allocation while blocks are in flight.
         self._pending_first: dict[int, Request] = {}     # by slot
-        self._firsts = jnp.zeros(max_slots, dtype=jnp.int32)
+        self._firsts = self._no_firsts()
         self._slot_index = [jnp.int32(slot) for slot in range(max_slots)]
         self._page_rows = None if self._pages is None else np.zeros(
             (max_slots, self._pages.pps), dtype=np.int32)
@@ -486,8 +520,16 @@ class ContinuousBatcher:
 
     # -- admission ---------------------------------------------------------
 
+    def _no_firsts(self):
+        """The first-token vector before any admission: noughts, or,
+        where admission yields no token, an id no stop token has (the
+        fold compares it with the slot's stop tokens, whose pad is
+        -1)."""
+        return jnp.full(self.max_slots, -2 if self._block else 0,
+                        dtype=jnp.int32)
+
     def submit(self, request: Request):
-        if len(request.prompt_tokens) >= self.max_seq:
+        if len(request.prompt_tokens) >= self._seq_limit:
             request.prompt_tokens = \
                 request.prompt_tokens[-(self.max_seq // 2):]
         # An empty prompt still needs one position of context to sample
@@ -583,6 +625,10 @@ class ContinuousBatcher:
             if request is None:     # cancelled/evicted while waiting
                 continue
             start, chunk_tokens = self._admission_chunk(request)
+            if not chunk_tokens:
+                # (a prompt shorter than a block: nothing to prefill)
+                self._admission_advance(slot, request, start, 0, None)
+                continue
             if not self._ensure_pages(slot, start + self.prefill_chunk):
                 self._prefilling.append(slot)   # pool pressure: wait
                 continue
@@ -667,7 +713,17 @@ class ContinuousBatcher:
         if not getattr(self._family, "ADMISSION_CARRIES_STATE", False):
             start = min(start, self.max_seq - self.prefill_chunk)
         return start, request.prompt_tokens[
-            start:start + self.prefill_chunk]
+            start:min(start + self.prefill_chunk,
+                      self._admission_length(request))]
+
+    def _admission_length(self, request: Request) -> int:
+        """How much of the prompt admission writes: all of it, or its
+        whole blocks where generation is by diffusion over blocks (the
+        tokens left over open the first generated block)."""
+        if self._block:
+            return self._family.admitted_length(
+                self.config, len(request.prompt_tokens))
+        return len(request.prompt_tokens)
 
     def _admission_advance(self, slot: int, request: Request,
                            start: int, chunk_len: int, logits):
@@ -688,8 +744,17 @@ class ContinuousBatcher:
             self._pages.register_prefix(slot, prompt,
                                         request.prefill_pos,
                                         self.kv_page_tokens)
-        if request.prefill_pos < len(prompt):
+        if request.prefill_pos < self._admission_length(request):
             self._prefilling.append(slot)       # more chunks to go
+            return
+        if self._block:
+            # Admission yields no token: the slot joins the next block
+            # dispatch with its first block (``_fold_rows``), and its
+            # first token arrives when that block commits.
+            self.lengths[slot] = request.prefill_pos
+            self._lengths_upper[slot] = len(prompt)
+            self.decoding[slot] = True
+            self._pending_first[slot] = request
             return
         # (a family that computes the sampled position alone hands
         # back one position)
@@ -909,8 +974,7 @@ class ContinuousBatcher:
         and post-recover; every later block chains device-side)."""
         self._launches += 6             # one a statement below
         self._key, loop_key = jax.random.split(self._key)
-        history_width = self.spec_window \
-            if self.speculative == "ngram" else 1
+        history_width = self._carry_width() or 1
         return {
             "tokens": jnp.asarray(self.current),
             "lengths": jnp.asarray(self.lengths),
@@ -921,6 +985,15 @@ class ContinuousBatcher:
             "key": loop_key,
         }
 
+    def _carry_width(self) -> int:
+        """Columns a joiner brings into the chained ``history`` carry:
+        its n-gram history tail under ``speculative: ngram``, its first
+        block and phase where generation is by diffusion over blocks
+        (the family's ``carry_width``), else none."""
+        if self._block:
+            return self._family.carry_width(self.config)
+        return self.spec_window if self.speculative == "ngram" else 0
+
     def _fold_rows(self, firsts_meta: list) -> np.ndarray:
         """The fold-in's host side, packed for one upload: a row a
         slot (the ``_FOLD_*`` columns) saying which slots were freed,
@@ -929,8 +1002,11 @@ class ContinuousBatcher:
         cache boundary; the stop-token part of that verdict is the
         device's), every slot's temperature (as its bits) and stop
         tokens, and under ``speculative: ngram`` each joiner's history
-        tail.  Clears the freed set."""
-        window = self.spec_window if self.speculative == "ngram" else 0
+        tail -- or, where generation is by diffusion over blocks, the
+        block it enters with (the family's ``joiner_carry``): it joins
+        at the positions admission stored, with its whole budget, no
+        token having been emitted for it.  Clears the freed set."""
+        window = self._carry_width()
         eos_end = _FOLD_EOS + self._eos_width
         packed = np.zeros((self.max_slots, eos_end + window),
                           dtype=np.int32)
@@ -942,6 +1018,13 @@ class ContinuousBatcher:
             plen = len(request.prompt_tokens)
             left = request.max_new_tokens - request.generated
             packed[slot, _FOLD_JOIN] = 1
+            if self._block:
+                packed[slot, _FOLD_PLEN] = request.prefill_pos
+                packed[slot, _FOLD_BUDGET] = max(left, 1)
+                packed[slot, _FOLD_MAY_DECODE] = 1
+                packed[slot, eos_end:] = self._family.joiner_carry(
+                    self.config, request.prompt_tokens)
+                continue
             packed[slot, _FOLD_PLEN] = plen
             packed[slot, _FOLD_BUDGET] = left - 1
             packed[slot, _FOLD_MAY_DECODE] = \
@@ -969,8 +1052,10 @@ class ContinuousBatcher:
         page-pool pressure wants the in-flight blocks retired before
         an eviction can free room."""
         ring = self.decode_block_tokens
-        spec_extra = self.spec_tokens + 1 \
-            if self.speculative != "off" else 1
+        # (a block's commit stores a whole block, and the first one's
+        # part of it is prompt that the ring does not count)
+        spec_extra = 2 * self._block if self._block \
+            else self.spec_tokens + 1 if self.speculative != "off" else 1
         live = [i for i in range(self.max_slots) if self.decoding[i]]
         joining = sorted(self._pending_first)
         if not live and not joining:
@@ -1033,7 +1118,7 @@ class ContinuousBatcher:
                 "accepted": accepted, "drafted": drafted, "steps": steps}
         if stats:
             tree["stats"] = stats[0]    # the family's block statistics
-        if firsts_meta:
+        if firsts_meta and not self._block:
             tree["firsts"] = self._firsts
         _prefetch(tree)                 # overlap newer blocks
         self._loop_chain = {"tokens": tokens_next,
@@ -1078,7 +1163,7 @@ class ContinuousBatcher:
         self.blocks_retired += 1
         self.accepted_tokens += int(np.asarray(fetched["accepted"]).sum())
         self.draft_tokens += int(np.asarray(fetched["drafted"]).sum())
-        if blk.firsts_meta:
+        if "firsts" in fetched:
             first_tokens = np.asarray(fetched["firsts"])
             for slot, request in blk.firsts_meta:
                 if self.slots[slot] is request and not request.done:
@@ -1208,7 +1293,7 @@ class ContinuousBatcher:
         self.pending = revived + self.pending
         self._prefilling.clear()
         self._pending_first.clear()
-        self._firsts = jnp.zeros(self.max_slots, dtype=jnp.int32)
+        self._firsts = self._no_firsts()
         self._loop_inflight.clear()
         self._loop_chain = None
         self._force_inactive.clear()
@@ -1258,7 +1343,7 @@ class ContinuousBatcher:
         finished = bool(request.committed) and (
             request.committed[-1] in request.eos_tokens
             or request.generated >= request.max_new_tokens
-            or len(request.prompt_tokens) >= self.max_seq)
+            or len(request.prompt_tokens) >= self._seq_limit)
         if finished:
             request.done = True
             if request in self.pending:
@@ -1357,7 +1442,7 @@ class ContinuousBatcher:
             - request.rebased
         finished = (token in request.eos_tokens
                     or request.generated >= request.max_new_tokens
-                    or total_len >= self.max_seq)
+                    or total_len >= self._seq_limit)
         if request.emit is not None:
             request.emit(request.request_id, token, finished)
         if finished:

@@ -62,6 +62,7 @@ from ..ops.tiles import on_tpu
 from .families import FAMILY_WIDTHS, config_fields
 from .llama import _finish, greedy_sample, select_tokens, \
     temperature_sample                                      # noqa: F401
+from .moe import routed_experts
 from .paged import (gather_latent_pages, is_paged, latent_pages,
                     paged_extent, pool_page_tokens, scatter_latent_pages,
                     scatter_latent_rows)
@@ -355,71 +356,24 @@ def route(c: DeepseekConfig, h, w_router, router_bias):
 
 def routed_ffn(c: DeepseekConfig, h, layer, valid=None, stack=None):
     """The routed experts' part of a sparse layer over ``h [N, D]``:
-    every token-expert pair computed, none dropped.  Pairs are sorted
-    by expert and multiplied by grouped matmuls over the experts'
-    stacked weights (``ragged_dot``: rows of expert ``e`` meet only
-    ``W[e]``; an expert without rows costs nothing).  ``valid [N]``
-    marks the rows that count (a decode step's live rows): the others
-    sort past the last group, touch no expert and give nought.
-
-    ``stack = (experts, index)`` hands over EVERY sparse layer's
-    experts ``[Ls, E, ...]`` and this layer's (traced) index instead of
-    ``layer["experts"]``: the grouped matmul then runs over all ``Ls *
-    E`` groups with only this layer's non-empty, and no layer's 1.1 GB
-    of experts is sliced out of the stack in front of the kernel (XLA
-    fuses a slice into an einsum, not into a custom call: the device
-    loop copied all three matrices, every layer and step).
+    this family's :func:`route`, then every token-expert pair computed,
+    none dropped (``moe.routed_experts``: the sort, the grouped matmuls
+    over the experts' stacked weights, the unsort and the gate-weighted
+    sum, shared with models/sdar.py).  ``valid [N]`` marks the rows
+    that count (a decode step's live rows): the others touch no expert
+    and give nought.  ``stack = (experts, index)`` hands over EVERY
+    sparse layer's experts ``[Ls, E, ...]`` and this layer's (traced)
+    index instead of ``layer["experts"]``, so that no layer's 1.1 GB
+    of experts is sliced out of the stack in front of the kernel.
 
     Returns (out ``[N, D]``, chosen ``[N, k]``, rows per expert ``[E]``).
     """
-    n, k, e = h.shape[0], c.n_experts_per_token, c.n_experts
     chosen, gates = route(c, h, layer["w_router"], layer["router_bias"])
-    expert_of = chosen.reshape(-1)                           # [N*k]
-    if valid is not None:
-        expert_of = jnp.where(jnp.repeat(valid, k), expert_of, e)
-    megablox = c.grouped_matmul == "megablox" \
-        or (c.grouped_matmul == "auto" and on_tpu())
-    if megablox and (n * k) % _GROUPED_ROWS:
-        # the kernel tiles the rows: pad with rows of no expert
-        expert_of = jnp.pad(expert_of, (0, -(n * k) % _GROUPED_ROWS),
-                            constant_values=e)
-    order = jnp.argsort(expert_of, stable=True)
-    rows = h[jnp.minimum(order // k, n - 1)]                 # [N*k', D]
-    sizes = jnp.bincount(expert_of, length=e + 1)[:e].astype(jnp.int32)
-    if stack is None:
-        experts, groups = layer["experts"], sizes
-    else:
-        stacked, index = stack
-        experts = jax.tree_util.tree_map(
-            lambda leaf: leaf.reshape(-1, *leaf.shape[2:]), stacked)
-        groups = jax.lax.dynamic_update_slice(
-            jnp.zeros((experts["w_up"].shape[0],), jnp.int32), sizes,
-            (index * e,))
-        # rows of this layer's experts start at the stack's row 0: the
-        # groups before them are empty
-    matmul = _megablox if megablox else jax.lax.ragged_dot
-    hidden = jax.nn.silu(matmul(rows, experts["w_gate"], groups)) \
-        * matmul(rows, experts["w_up"], groups)
-    out = matmul(hidden, experts["w_down"], groups)
-    # back to token order, each pair weighted by its gate (float32);
-    # rows past the last group hold whatever the kernel left there
-    out = out[jnp.argsort(order)[:n * k]].reshape(n, k, -1) \
-        .astype(jnp.float32)
-    if valid is not None:
-        out = jnp.where(valid[:, None, None], out, 0.0)
-    return (out * gates[..., None]).sum(1).astype(h.dtype), chosen, sizes
-
-
-_GROUPED_ROWS = 128     # megablox's row tile
-
-
-def _megablox(rows, weights, groups):
-    """``ragged_dot`` by the megablox kernel, one tile a whole expert
-    matrix wide (the tiling that won the sweep on the v5e: PERF.md)."""
-    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
-    return gmm(rows, weights, groups, preferred_element_type=rows.dtype,
-               tiling=(_GROUPED_ROWS,) + weights.shape[1:],
-               interpret=not on_tpu())
+    experts, index = (layer["experts"], None) if stack is None else stack
+    out, sizes = routed_experts(h, chosen, gates, experts,
+                                grouped_matmul=c.grouped_matmul,
+                                valid=valid, index=index)
+    return out, chosen, sizes
 
 
 def _sparse_ffn(c: DeepseekConfig, h, layer, valid=None, stack=None):

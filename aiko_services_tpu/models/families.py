@@ -2,8 +2,8 @@
 ``config.json`` keys (``widths``) each takes and what each refuses.
 Imports nothing of jax, so the create-time parameter check
 (``analysis/params.py``) and the runtime (``elements/llm.py``,
-``models/llama.py``, ``models/deepseek.py``, ``models/olmo_hybrid.py``)
-read ONE table.
+``models/llama.py``, ``models/deepseek.py``, ``models/olmo_hybrid.py``,
+``models/sdar.py``) read ONE table.
 
 An LLM element names a family and hands it widths::
 
@@ -13,16 +13,19 @@ An LLM element names a family and hands it widths::
                    "max_seq": 8192, ...}
 
 A key the family lacks is an error; a key left out keeps the family's
-default (``LlamaConfig`` / ``DeepseekConfig`` / ``OlmoHybridConfig``).
+default (``LlamaConfig`` / ``DeepseekConfig`` / ``OlmoHybridConfig`` /
+``SdarConfig``).
 Widths are numbers, but for the keys of ``LIST_WIDTHS`` (a list of
 names from the given set: the ``olmo_hybrid`` family's per-layer
-``layer_types``) and ``BOOL_WIDTHS`` (a boolean).
+``layer_types``) and ``BOOL_WIDTHS`` (a boolean).  A family may also
+take parameters of its own beside its widths (``FAMILY_PARAMETERS``:
+the ``sdar_moe`` family's ``block_length`` and ``denoising_steps``).
 """
 
 from __future__ import annotations
 
-__all__ = ["FAMILY_WIDTHS", "FAMILY_REFUSES", "config_fields",
-           "family_spec_error"]
+__all__ = ["FAMILY_WIDTHS", "FAMILY_REFUSES", "FAMILY_PARAMETERS",
+           "config_fields", "family_spec_error"]
 
 #: family -> {published config.json key: config dataclass field}
 FAMILY_WIDTHS: dict[str, dict[str, str]] = {
@@ -63,6 +66,23 @@ FAMILY_WIDTHS: dict[str, dict[str, str]] = {
         "linear_conv_kernel_dim": "linear_conv_kernel",
         "linear_allow_neg_eigval": "linear_allow_neg_eigval",
         "rms_norm_eps": "norm_eps"},
+    "sdar_moe": {
+        "vocab_size": "vocab_size", "hidden_size": "dim",
+        "num_hidden_layers": "n_layers",
+        "num_attention_heads": "n_heads",
+        "num_key_value_heads": "n_kv_heads", "head_dim": "head_dim",
+        "moe_intermediate_size": "moe_hidden_dim",
+        "num_experts": "n_experts",
+        "num_experts_per_tok": "n_experts_per_token",
+        "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps"},
+}
+
+#: family -> {element parameter: config dataclass field} for what a
+#: family takes beside its widths (whole numbers; with another family
+#: the parameter is refused)
+FAMILY_PARAMETERS: dict[str, dict[str, str]] = {
+    "sdar_moe": {"block_length": "block_length",
+                 "denoising_steps": "denoising_steps"},
 }
 
 #: width key -> the names its list may hold (every other width is a
@@ -97,6 +117,18 @@ FAMILY_REFUSES: dict[str, dict[str, str]] = {
         "spec_window": "speculation is not served",
         "prefix_cache": "a shared prefix is a snapshot of the recurrent "
                         "state, not pages; none is kept",
+        "model": "a family is built from widths, not from a preset",
+    },
+    "sdar_moe": {
+        "quantize": "the family serves bfloat16 weights and bfloat16 "
+                    "K/V pages (no int8)",
+        "speculative": "several tokens a pass are decided by diffusion "
+                       "over a block; there is no draft beside it",
+        "spec_tokens": "speculation is not served",
+        "spec_window": "speculation is not served",
+        "prefix_cache": "a shared prefix would have to end at a block "
+                        "boundary, and re-written shared pages are not "
+                        "bit-equal under grouped expert matmuls",
         "model": "a family is built from widths, not from a preset",
     },
 }
@@ -191,4 +223,55 @@ def family_spec_error(parameters: dict) -> str | None:
         if name in parameters and not _is_default(name, parameters[name]):
             return f"{name}={parameters[name]!r}: not with family " \
                    f"{family} ({why})"
+    for other, names in FAMILY_PARAMETERS.items():
+        for name in names:
+            if name in parameters and other != family:
+                return f"{name}={parameters[name]!r}: not with family " \
+                       f"{family} (a parameter of the {other} family)"
+    if family == "sdar_moe":
+        return _block_diffusion_error(parameters)
+    return None
+
+
+def _whole(parameters: dict, name: str, default: int):
+    """A whole-number parameter, its default where left out, None where
+    it is no whole number."""
+    value = parameters.get(name, default)
+    if isinstance(value, bool):
+        return None
+    try:
+        number = float(value)
+    except (TypeError, ValueError):
+        return None
+    return int(number) if number == int(number) else None
+
+
+def _block_diffusion_error(parameters: dict) -> str | None:
+    """What is wrong with the ``sdar_moe`` family's own parameters and
+    the sizes they must divide, or None: ``block_length`` >= 1 dividing
+    the page (``kv_page_tokens``, which must be set: K/V is committed
+    into pages), the admission chunk (512) and the emitted-token ring
+    (``decode_block_tokens``, which must be set: generation is by the
+    device loop), ``denoising_steps`` in 1..``block_length``."""
+    block = _whole(parameters, "block_length", 4)
+    if block is None or block < 1:
+        return f"block_length={parameters.get('block_length')!r}: a " \
+               f"whole number >= 1"
+    steps = _whole(parameters, "denoising_steps", block)
+    if steps is None or not 1 <= steps <= block:
+        return f"denoising_steps={parameters.get('denoising_steps')!r}: " \
+               f"a whole number in 1..block_length ({block})"
+    page = _whole(parameters, "kv_page_tokens", 0)
+    if not page:
+        return "kv_page_tokens=0: the sdar_moe family commits K/V a " \
+               "block at a time into pages only; set kv_page_tokens > 0"
+    if page % block or 512 % block:
+        return f"block_length={block}: must divide the page " \
+               f"(kv_page_tokens={page}) and the admission chunk (512)"
+    ring = _whole(parameters, "decode_block_tokens", 0)
+    if not ring or ring % block:
+        return f"decode_block_tokens=" \
+               f"{parameters.get('decode_block_tokens', 0)!r}: the " \
+               f"sdar_moe family generates by the device loop alone; a " \
+               f"multiple of block_length ({block})"
     return None

@@ -45,7 +45,7 @@ _STAT_LANES = 128      # softmax stats replicated across the lane dim
 def _flash_kernel(offset_ref, q_ref, k_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr, *,
                   block_q, block_k, causal, kv_len, rows_per_head,
-                  scale):
+                  scale, frontier):
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
     qi = pl.program_id(1)
@@ -63,8 +63,10 @@ def _flash_kernel(offset_ref, q_ref, k_ref, v_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # Causal: skip KV blocks strictly above this Q block's last row.
-    live = (k_start <= q_start + block_q - 1) if causal else True
+    # Causal: skip KV blocks strictly above this Q block's last row
+    # (``frontier(p)``: the last key position a query at ``p`` sees).
+    live = (k_start <= frontier(q_start + block_q - 1)) if causal \
+        else True
     # Interior blocks need NO masking: every key position is both
     # in-range and at-or-before every query position.  The mask path
     # (2 iotas + compares + 2 wheres on [bq, bk] f32) costs about as
@@ -74,7 +76,7 @@ def _flash_kernel(offset_ref, q_ref, k_ref, v_ref, o_ref,
     in_range = k_start + block_k <= kv_len
     interior = jnp.logical_and(
         in_range,
-        (k_start + block_k - 1 <= q_start) if causal else True)
+        (k_start + block_k - 1 <= frontier(q_start)) if causal else True)
 
     def _online_update(s, p_mask=None):
         m_prev = m_scr[:, :1]                           # [bq, 1]
@@ -124,7 +126,7 @@ def _flash_kernel(offset_ref, q_ref, k_ref, v_ref, o_ref,
             jnp.int32, (block_q, block_k), 1)
         mask = k_pos < kv_len
         if causal:
-            mask = jnp.logical_and(mask, k_pos <= q_pos)
+            mask = jnp.logical_and(mask, k_pos <= frontier(q_pos))
         _online_update(jnp.where(mask, s, _NEG_INF), p_mask=mask)
 
     @pl.when(ki == nk - 1)
@@ -133,13 +135,32 @@ def _flash_kernel(offset_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
+def _frontier(block_length: int):
+    """``position -> the last key position a query there sees``: itself
+    (causal), or under a BLOCK-causal mask of ``block_length`` the last
+    position of its own block -- a query sees whole earlier blocks and
+    its own block in both directions.  At 1 the expression is the
+    causal one to the letter (the callers that hand no block length
+    keep their programs)."""
+    if block_length == 1:
+        return lambda position: position
+    return lambda position: \
+        (position // block_length + 1) * block_length - 1
+
+
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "block_q", "block_k", "interpret", "pack_heads"))
+    "causal", "block_q", "block_k", "interpret", "pack_heads",
+    "block_length"))
 def flash_attention(q, k, v, q_offset=0, *, causal: bool = True,
                     block_q: int = 512, block_k: int = 2048,
                     interpret: bool | None = None,
-                    pack_heads: bool = False):
+                    pack_heads: bool = False, block_length: int = 1):
     """Causal flash attention.
+
+    ``block_length`` > 1 makes the mask BLOCK-causal at absolute
+    positions: the frontier of a query at ``p`` is ``k < (p //
+    block_length + 1) * block_length`` (generation by diffusion over
+    blocks, models/sdar.py); 1 is plain causality.
 
     q: [B, S, H, d]; k/v: [B, T, Hkv, d] with H % Hkv == 0 (GQA: each
     query head attends its group's KV head via the grouped grid rows,
@@ -238,10 +259,11 @@ def flash_attention(q, k, v, q_offset=0, *, causal: bool = True,
         scale = None
 
     grid = (grid_rows, rows_pad // block_q, t_pad // block_k)
+    frontier = _frontier(int(block_length))
     kernel = functools.partial(
         _flash_kernel, block_q=block_q, block_k=block_k,
         causal=causal, kv_len=t, rows_per_head=rows_per_head,
-        scale=scale)
+        scale=scale, frontier=frontier)
 
     def kv_block(bh, qi, ki, offset):
         # Clamp dead KV blocks (fully above the causal frontier) to the
@@ -251,7 +273,8 @@ def flash_attention(q, k, v, q_offset=0, *, causal: bool = True,
         # extent every layer.
         if not causal:
             return (bh, ki, 0)
-        q_last = offset[0] + (qi * block_q) % rows_per_head + block_q - 1
+        q_last = frontier(offset[0] + (qi * block_q) % rows_per_head
+                          + block_q - 1)
         return (bh, jnp.minimum(ki, q_last // block_k), 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(

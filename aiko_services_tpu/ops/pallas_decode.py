@@ -978,14 +978,16 @@ def flash_decode_append_paged(q, k_view, v_view, layer, k_new, v_new,
 
 
 def _combine_chunk(acc, m, l, q, k_new, v_new, positions, scale,
-                   kv: int, d: int):
+                   kv: int, d: int, block_mask: bool = False):
     """Merge the verify chunk's own keys/values (the causal self part)
     with the kernel's cache-part stats -- the S-query generalization of
     :func:`_combine_self`.  acc [B, S*H, C], m/l [B, S*H]; q [B,S,H,hd]
     rope'd unscaled queries; k_new/v_new [B,S,K,hd]; positions [B,S]
     trash-clamped absolute positions (causality among chunk keys is
     ``key_pos <= query_pos``, exactly the dense concat path's mask).
-    Returns [B, S, H, hd] f32."""
+    ``block_mask``: the chunk is ONE block of a block-causal mask, all
+    of it visible to each of its queries, in both directions
+    (models/sdar.py).  Returns [B, S, H, hd] f32."""
     b, s, h, _ = q.shape
     blocks = jnp.arange(h) // (h // kv)
     onehot = _group_onehot(h, kv, jnp.float32)               # [H, K]
@@ -994,8 +996,11 @@ def _combine_chunk(acc, m, l, q, k_new, v_new, positions, scale,
     q32 = q.astype(jnp.float32)
     chunk_logits = jnp.einsum("bshd,bthd->bsht", q32,
                               k_new_h) * scale               # [B,S,H,S]
-    causal = positions[:, None, None, :] <= \
-        positions[:, :, None, None]                          # [B,S,1,S]
+    if block_mask:
+        causal = jnp.ones((b, s, 1, s), dtype=bool)
+    else:
+        causal = positions[:, None, None, :] <= \
+            positions[:, :, None, None]                      # [B,S,1,S]
     chunk_logits = jnp.where(causal, chunk_logits, _NEG_INF)
     m_k = m.reshape(b, s, h)
     l_k = l.reshape(b, s, h)
@@ -1015,7 +1020,8 @@ def _combine_chunk(acc, m, l, q, k_new, v_new, positions, scale,
 def flash_verify_append(q, k_view, v_view, layer, k_new, v_new, starts,
                         positions, *, page_table=None,
                         block_t: int = 2048,
-                        interpret: bool | None = None):
+                        interpret: bool | None = None,
+                        block_mask: bool = False):
     """Batched chunk-verify attention on the split-K kernels (ISSUE 11):
     the speculative multi-token target step's concat-attention with the
     cache read ONCE for all S draft positions -- not once per drafted
@@ -1029,7 +1035,9 @@ def flash_verify_append(q, k_view, v_view, layer, k_new, v_new, starts,
     every H rows).  The chunk's own k/v are the self part, combined
     outside with causal masking by the trash-clamped ``positions`` --
     the exact semantics of the dense concat path in
-    ``models/llama.py:_chunk_verify``.
+    ``models/llama.py:_chunk_verify``.  ``block_mask``: the chunk is one
+    block of a block-causal mask instead (:func:`_combine_chunk`); the
+    cache part is the same.
 
     q: [B, S, H, hd] rope'd queries; k_view/v_view: stacked cache views
     (:func:`_split_stacked`) or paged pool views (:func:`_split_paged`,
@@ -1056,5 +1064,5 @@ def flash_verify_append(q, k_view, v_view, layer, k_new, v_new, starts,
             starts, block_t=block_t, interpret=interpret,
             qrow_period=h)
     out = _combine_chunk(acc, m, l, q, k_new, v_new, positions, scale,
-                         kv, d)
+                         kv, d, block_mask=block_mask)
     return out.astype(q.dtype)

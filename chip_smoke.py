@@ -424,6 +424,20 @@ def kernels(settings: dict, rehearse: bool, facts: dict) -> None:
                                       interpret=interpret),
               lambda: jax.jit(attention_prefill)(q, k, v, positions))
 
+    # 1b. the same kernel with a BLOCK-causal frontier (generation by
+    # diffusion over blocks of four, models/sdar.py): a query sees its
+    # whole block; the chunk starts mid-way through a kernel block.
+    block = 4
+    q = normal((1, chunk, heads, hd), scale=4.0)
+    k, v = normal((1, extent, kv, hd)), normal((1, extent, kv, hd))
+    offset = (extent - chunk) // 2 // block * block
+    frontier = ((offset + jnp.arange(chunk)) // block + 1)[None, :] \
+        * block - 1
+    close("flash_attention[block=4]",
+          lambda: flash_attention(q, k, v, q_offset=offset,
+                                  interpret=interpret, block_length=block),
+          lambda: jax.jit(attention_prefill)(q, k, v, frontier))
+
     # 2-4. split-K decode: flat, stacked and paged, bf16 and int8.
     lengths = jnp.asarray(
         [(extent - 1) * (i + 1) // slots for i in range(slots)],
@@ -504,6 +518,22 @@ def kernels(settings: dict, rehearse: bool, facts: dict) -> None:
                   vv, starts, positions, page_table=table,
                   interpret=interpret),
               verify_reference)
+
+    # 5a. the verify chunk as ONE block of a block-causal mask (a pass
+    # of models/sdar.py): block-aligned rows, the block's own part
+    # visible to all of its queries.
+    aligned = starts // block * block
+    within = jnp.minimum(aligned[:, None] + jnp.arange(block)[None, :],
+                         extent - 1)
+    pools = [paged(flatten(cache_k)), paged(flatten(cache_v))]
+    close("flash_verify_append[paged,block=4]",
+          lambda: flash_verify_append(
+              vq[:, :block], *map(_split_paged, pools), jnp.int32(layer),
+              vk[:, :block], vv[:, :block], aligned, within,
+              page_table=table, interpret=interpret, block_mask=True),
+          lambda: jax.jit(_verify_reference, static_argnums=7)(
+              cache_k[layer], cache_v[layer], vq[:, :block],
+              vk[:, :block], vv[:, :block], aligned, within, True))
 
     # 5b. the paged form across the shapes its pages-a-group follows
     # (ops/pallas_decode.py:paged_pages_per_step): page sizes 8..256 at
@@ -644,18 +674,22 @@ def kernels(settings: dict, rehearse: bool, facts: dict) -> None:
           atol=1e-5, rtol=1e-5)
 
 
-def _verify_reference(k_rows, v_rows, q, k_new, v_new, starts, positions):
+def _verify_reference(k_rows, v_rows, q, k_new, v_new, starts, positions,
+                      block_mask=False):
     """The dense concat-attention ``llama._chunk_verify`` falls back
-    to (tests/test_kernel_plane.py::_verify_reference, verbatim)."""
+    to (tests/test_kernel_plane.py::_verify_reference, verbatim);
+    ``block_mask``: the chunk's keys all take its first position, so
+    every query of the chunk sees all of it (``sdar._pass_impl``)."""
     import jax.numpy as jnp
     from aiko_services_tpu.ops.layers import attention_prefill
     b, t = k_rows.shape[:2]
     s = q.shape[1]
     k_all = jnp.concatenate([k_rows, k_new], axis=1)
     v_all = jnp.concatenate([v_rows, v_new], axis=1)
+    own = jnp.broadcast_to(positions[:, :1], (b, s)) if block_mask \
+        else positions
     kv_positions = jnp.concatenate(
-        [jnp.broadcast_to(jnp.arange(t)[None, :], (b, t)), positions],
-        axis=1)
+        [jnp.broadcast_to(jnp.arange(t)[None, :], (b, t)), own], axis=1)
     valid = jnp.concatenate(
         [jnp.arange(t)[None, :] < starts[:, None],
          jnp.ones((b, s), dtype=bool)], axis=1)

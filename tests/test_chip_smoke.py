@@ -50,6 +50,9 @@ def test_smoke_rehearsal_passes_at_tiny():
     # Off the chip every ``auto`` probe resolves the reference path.
     assert "decode_backend=reference" in serve
     assert "matmul_backend=reference" in serve
+    # The block-causal forms of the two attention kernels (ISSUE 36).
+    assert "flash_attention[block=4]=" in phases[1]
+    assert "flash_verify_append[paged,block=4]=" in phases[1]
 
 
 _IMPORT_PROBE = """

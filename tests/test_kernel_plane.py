@@ -552,6 +552,68 @@ def test_chunk_verify_kernel_matches_dense():
                                atol=1e-5, rtol=1e-5)
 
 
+def test_chunk_verify_block_mask_matches_dense():
+    """``block_mask``: the chunk is ONE block of a block-causal mask --
+    its own keys visible to each of its queries in both directions
+    (a pass of models/sdar.py) -- over a paged pool, at block-aligned
+    rows; without it the chunk's part stays causal, bit for bit."""
+    from aiko_services_tpu.ops.pallas_decode import _combine_chunk
+    key = jax.random.PRNGKey(16)
+    L, B, K, G, hd, S = 2, 3, 2, 2, 16, 4
+    C, H = K * hd, K * G
+    P, pt, pps = 13, 32, 4
+    starts = jnp.asarray([0, 36, pps * pt - S], dtype=jnp.int32)
+    positions = starts[:, None] + jnp.arange(S)[None, :]
+    q = jax.random.normal(jax.random.fold_in(key, 1), (B, S, H, hd))
+    k_new = jax.random.normal(jax.random.fold_in(key, 2), (B, S, K, hd))
+    v_new = jax.random.normal(jax.random.fold_in(key, 3), (B, S, K, hd))
+    pool_k = jax.random.normal(jax.random.fold_in(key, 4), (L, P, pt, C))
+    pool_v = jax.random.normal(jax.random.fold_in(key, 5), (L, P, pt, C))
+    table = jnp.asarray([[1, 2, 3, 0], [4, 5, 6, 7], [8, 9, 10, 11]],
+                        dtype=jnp.int32)
+    layer = 1
+
+    def rows(pool):
+        return pool[layer][table].reshape(B, pps * pt, K, hd)
+    out = flash_verify_append(q, (pool_k, None), (pool_v, None),
+                              jnp.int32(layer), k_new, v_new, starts,
+                              positions, page_table=table, interpret=True,
+                              block_mask=True)
+    # dense: the block's keys all stand at the block's first position
+    t = pps * pt
+    own = jnp.broadcast_to(starts[:, None], (B, S))
+    reference = attention_prefill(
+        q, jnp.concatenate([rows(pool_k), k_new], axis=1),
+        jnp.concatenate([rows(pool_v), v_new], axis=1), positions,
+        kv_length_mask=jnp.concatenate(
+            [jnp.arange(t)[None, :] < starts[:, None],
+             jnp.ones((B, S), dtype=bool)], axis=1),
+        kv_positions=jnp.concatenate(
+            [jnp.broadcast_to(jnp.arange(t)[None, :], (B, t)), own],
+            axis=1))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(reference),
+                               atol=1e-5, rtol=1e-5)
+    causal = flash_verify_append(q, (pool_k, None), (pool_v, None),
+                                 jnp.int32(layer), k_new, v_new, starts,
+                                 positions, page_table=table,
+                                 interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(causal), np.asarray(_verify_reference(
+            rows(pool_k), rows(pool_v), q, k_new, v_new, starts,
+            positions)), atol=1e-5, rtol=1e-5)
+    assert float(jnp.abs(out - causal).max()) > 1e-2
+    # every existing caller's program: the default is the causal chunk
+    stats = (jnp.zeros((B, S * H, C)), jnp.full((B, S * H), -1e30),
+             jnp.zeros((B, S * H)))
+
+    def program(**mask):
+        return str(jax.make_jaxpr(lambda *inputs: _combine_chunk(
+            *inputs, positions, hd ** -0.5, K, hd, **mask))(
+                *stats, q, k_new, v_new))
+    assert program() == program(block_mask=False)
+    assert program() != program(block_mask=True)
+
+
 def test_chunk_verify_wired_into_speculative_loop():
     """_chunk_verify with use_flash routes through the kernel and
     produces the same logits and cache as the dense concat path."""

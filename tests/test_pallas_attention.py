@@ -113,3 +113,53 @@ def test_flash_pack_heads_falls_back_when_unpaired():
     v = jax.random.normal(jax.random.fold_in(key, 2), (1, 32, 3, 16))
     out = flash_attention(q, k, v, block_q=8, block_k=8, pack_heads=True)
     np.testing.assert_allclose(out, _dense(q, k, v), atol=1e-5)
+
+
+def _block_dense(q, k, v, q_offset, block):
+    """Block-causal attention in jax.numpy: a query at ``p`` sees keys
+    up to the end of its own block of ``block``."""
+    b, s = q.shape[:2]
+    positions = q_offset + jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
+    return attention_prefill(q, k, v, (positions // block + 1) * block - 1)
+
+
+@pytest.mark.parametrize("offset,block_q,block_k", [
+    (24, 16, 16),       # the chunk starts mid-way through a kernel block
+    (0, 16, 32), (20, 8, 16)])
+def test_flash_block_causal_matches_dense(offset, block_q, block_k):
+    """``block_length`` 4 (generation by diffusion over blocks): the
+    frontier of a query is the end of its own block, at absolute
+    positions, GQA and a ragged key extent included."""
+    key = jax.random.PRNGKey(7)
+    q = jax.random.normal(key, (1, 32, 4, 16))
+    k = jax.random.normal(jax.random.fold_in(key, 1), (1, 60, 2, 16))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (1, 60, 2, 16))
+    out = flash_attention(q, k, v, q_offset=offset, block_q=block_q,
+                          block_k=block_k, block_length=4)
+    np.testing.assert_allclose(
+        out, _block_dense(q, repeat_kv(k, 2), repeat_kv(v, 2), offset, 4),
+        atol=1e-5)
+    # and it is not the causal answer
+    causal = flash_attention(q, k, v, q_offset=offset, block_q=block_q,
+                             block_k=block_k)
+    assert float(jnp.abs(out - causal).max()) > 1e-2
+
+
+def test_flash_block_length_one_is_the_causal_program():
+    """A caller that hands no block length gets the program it always
+    got: at 1 the frontier is the position itself, to the letter."""
+    key = jax.random.PRNGKey(8)
+    q = jax.random.normal(key, (1, 32, 4, 16))
+    k = jax.random.normal(jax.random.fold_in(key, 1), (1, 56, 2, 16))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (1, 56, 2, 16))
+
+    def program(**block):
+        return str(jax.make_jaxpr(lambda q, k, v: flash_attention(
+            q, k, v, q_offset=24, block_q=16, block_k=16, **block))(
+                q, k, v))
+    assert program() == program(block_length=1)
+    assert program() != program(block_length=4)
+    assert np.array_equal(
+        flash_attention(q, k, v, q_offset=24, block_q=16, block_k=16),
+        flash_attention(q, k, v, q_offset=24, block_q=16, block_k=16,
+                        block_length=1))
