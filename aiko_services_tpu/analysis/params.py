@@ -337,8 +337,9 @@ ELEMENT_PARAMETERS: dict[tuple[str, str], dict[str, ParamSpec]] = {
         # -- kernel plane (ISSUE 11) ----------------------------------
         "decode_kernel": ParamSpec(
             "decode-attention backend in the ops capability-probe "
-            "vocabulary (ops.decode_backend); auto follows the cache "
-            "structure and extent threshold",
+            "vocabulary (ops.decode_backend); auto follows the cache's "
+            "layout on the chip: a paged cache takes the paged kernel "
+            "at any extent, a dense one flash from the extent threshold",
             choices=("auto", "paged-kernel", "dense-flash",
                      "reference")),
         # -- model family (ISSUE 29) ----------------------------------

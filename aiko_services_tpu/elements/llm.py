@@ -43,7 +43,7 @@ from ..models import deepseek, llama, olmo_hybrid, sdar
 from ..models.batching import ContinuousBatcher, Request
 from ..models.checkpoint import maybe_restore as _restore
 from ..models.families import FAMILY_PARAMETERS, family_spec_error
-from ..models.paged import is_paged
+from ..models.paged import is_paged, pool_page_tokens
 from ..models.quant import is_quantized
 from ..models.tokenizer import ByteTokenizer, load_tokenizer
 from ..pipeline import PipelineElement, StreamEvent
@@ -272,6 +272,11 @@ class LLM(PipelineElement):
     ``build:llm_unembed``: the fused unembed's blocks at the decode
     width and ``padded_weight_bytes``, what each of its calls copies
     to pad the head (0 unless no block fits: ops/pallas_matmul.py).
+    Every model of the Llama family gets ``build:llm_decode_backend``:
+    the decode attention the probe resolved for its cache
+    (``backend``: ops.DECODE_BACKENDS), the cache's ``extent``, its
+    ``page_tokens`` and the paged kernel's ``pages_per_step`` (None
+    where the cache is dense or another backend decodes).
 
     ASYNC by default: each frame parks and its request hops to the
     element's device WORKER THREAD, which owns the model and the shared
@@ -418,8 +423,10 @@ class LLM(PipelineElement):
         # ops capability-probe vocabulary (ops.decode_backend):
         # paged-kernel / dense-flash force the Pallas kernel plane
         # (which one actually engages follows the cache's structure),
-        # reference forces the dense einsum path, auto defers to the
-        # extent threshold.  Domain-validated at create time
+        # reference forces the dense einsum path, auto follows the
+        # cache's layout on the chip (a paged cache takes the paged
+        # kernel at any extent; a dense one takes flash from the extent
+        # threshold up).  Domain-validated at create time
         # (analysis/params.py ELEMENT_PARAMETERS).
         decode_kernel = str(settings.get("decode_kernel",
                                          "auto")).strip().lower()
@@ -582,6 +589,19 @@ class LLM(PipelineElement):
             recorder.record("build", None, None, "llm_cache",
                             (time.perf_counter() - started) * 1000.0,
                             pools)
+            if isinstance(config, llama.LlamaConfig):
+                # Which decode attention the probe chose for this
+                # cache (ops.decode_backend follows its layout), and
+                # what it saw: None where a field does not apply.
+                served, cache = self._batcher.config, self._batcher.cache
+                recorder.record(
+                    "build", None, None, "llm_decode_backend", 0.0,
+                    {"backend": llama.resolve_decode_backend(served, cache),
+                     "extent": llama.cache_extent(cache),
+                     "page_tokens": pool_page_tokens(cache)
+                     if is_paged(cache) else None,
+                     "pages_per_step": llama.paged_decode_pages(
+                         served, cache)})
             unembed = params.get("unembed")
             if is_quantized(unembed) and unembed["int8"].ndim == 2:
                 # How the fused int8 unembed (ops/pallas_matmul.py)

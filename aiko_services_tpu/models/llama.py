@@ -57,14 +57,20 @@ class LlamaConfig:
     # Decode attention implementation: "dense" (ops/layers.py
     # attention_decode_append), "flash" (the split-K Pallas kernel,
     # ops/pallas_decode.py -- streams the cache once, softmax stats in
-    # VMEM, int8 cache dequantized in-kernel), or "auto" (flash ON THE
-    # TPU BACKEND once the cache extent reaches
-    # ``flash_decode_threshold``, dense everywhere else -- resolved at
-    # trace time, the cache length is static under jit).  Measured on
-    # v5e with the flat cache: flash wins from 1k up (0.88 vs 0.86 HBM
-    # util at 1k; 0.84 vs ~0.45 at 8k, where dense's [B, H, T] HBM
-    # intermediates outweigh the cache); sub-1k shapes keep dense
-    # (single fused dispatch).
+    # VMEM, int8 cache dequantized in-kernel), or "auto" (ON THE TPU
+    # BACKEND: a PAGED cache takes the paged kernel whatever its
+    # extent; a flat / stacked cache takes flash once its extent
+    # reaches ``flash_decode_threshold``; dense everywhere else --
+    # resolved at trace time, the cache's layout and length are static
+    # under jit: ops.decode_backend).  ``flash_decode_threshold`` is
+    # the FLAT cache's: measured on v5e with the flat cache, flash wins
+    # from 1k up (0.88 vs 0.86 HBM util at 1k; 0.84 vs ~0.45 at 8k,
+    # where dense's [B, H, T] HBM intermediates outweigh the cache);
+    # sub-1k flat shapes keep dense (single fused dispatch, the row's
+    # extent read in place).  A paged cache has no in-place dense
+    # path -- inside the layer scan the gather takes a layer's whole K
+    # and V pool out of the stack every step (PERF.md section 6, PR
+    # 37) -- so the threshold does not apply to it.
     # NOTE: pallas_call has no GSPMD
     # partitioning rules, so under a tp-sharded cache keep "dense" (or
     # shard_map the layer); single-chip and dp-sharded serving

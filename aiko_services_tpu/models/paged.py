@@ -49,15 +49,15 @@ decode-side gather materializes the logical view, so the
 REFERENCE paged decode streams the cache roughly twice per step on TPU
 -- the price of paging without a paged-attention kernel.  ISSUE 11
 removed that price on the kernel plane: when the decode backend
-resolves to ``paged-kernel`` (ops.decode_backend -- 'auto' past the
-flash threshold, or an explicit flash/``decode_kernel`` request),
+resolves to ``paged-kernel`` (ops.decode_backend -- 'auto' on the
+chip at any extent, or an explicit flash/``decode_kernel`` request),
 decode and chunk-verify walk the page table IN-KERNEL
 (ops/pallas_decode.py:flash_decode_attention_paged): each row copies
 its own live pages out of the pool, their physical indices read from
 the scalar-prefetched table, so the logical row view never
 materializes, the cache streams once, and a page a slot could hold
 but does not is never touched.  The gather path remains the reference
-(and the sub-threshold / distributed fallback); the memory win (pool
+(and the off-chip / distributed fallback); the memory win (pool
 sized to the *live* token count) and recompile-free admission hold on
 both.
 """

@@ -40,20 +40,36 @@ def decode_backend(requested: str = "auto", *, paged: bool = False,
     (pallas_call has no GSPMD partitioning rules -- the caller decides
     whether an explicit 'flash' request on a sharded cache is an
     error); under ``auto`` the kernels engage on the TPU backend
-    (:func:`on_tpu`) once ``extent`` reaches ``threshold`` and the
-    structure fits (dense: block-alignable extent; paged:
-    sublane-aligned ``page_tokens``).
+    (:func:`on_tpu`) alone, and the cache's LAYOUT decides what
+    ``extent`` means to them:
+
+    - a DENSE (flat / stacked) cache has an extent the reference path
+      reads in place, so the kernel engages once ``extent`` reaches
+      ``threshold`` (measured on that layout: one fused dispatch wins
+      under 1k) and is block-alignable;
+    - a PAGED cache has no such path: the reference gathers a layer's
+      pages to a ``[B, T, ...]`` view, and inside a layer scan XLA
+      takes the layer's WHOLE K and V pool out of the stack to do it
+      (1.1 ms of a 4.5 ms step at 16 slots x 512 on v5e, PR 37), so
+      with sublane-aligned pages (``page_tokens % 8 == 0``) it
+      resolves ``paged-kernel`` at ANY extent -- the kernel copies a
+      row's live pages only and ``threshold`` is not consulted.  (Int8
+      pools with pages under 128 tokens copy a lane-padded scale pool a
+      step on that path, ``pallas_decode._split_paged``; nothing gates
+      on it.)
     """
     if requested in ("dense", "reference") or distributed:
         return "reference"
     if requested == "flash":
         return "paged-kernel" if paged else "dense-flash"
-    if (extent or 0) < threshold or not on_tpu():
+    if not on_tpu():
         return "reference"
     if paged:
         return "paged-kernel" \
             if page_tokens and page_tokens % 8 == 0 else "reference"
-    return "dense-flash" if (extent or 0) % 128 == 0 else "reference"
+    extent = extent or 0
+    return "dense-flash" \
+        if extent >= threshold and extent % 128 == 0 else "reference"
 
 
 def matmul_backend(requested: str = "auto") -> str:

@@ -190,10 +190,11 @@ def attention_decode_append(q: jax.Array, k_cache: jax.Array,
     tail carrying ~97% of the attention mass shrinks the output to
     the spike's few percent (tests/test_flash_decode.py::
     test_dense_int8_diffuse_tail_error_mode quantifies it).  Diffuse
-    long-context attention is exactly the int8-KV regime, so for
-    T >= LlamaConfig.flash_decode_threshold the decode path defaults
-    to the split-K Pallas kernel (ops/pallas_decode.py,
-    decode_attention="auto"), which dequantizes IN KERNEL -- no
+    long-context attention is exactly the int8-KV regime, so on the
+    chip the decode path defaults to a split-K Pallas kernel
+    (ops/pallas_decode.py, decode_attention="auto": a flat cache of
+    T >= LlamaConfig.flash_decode_threshold, a paged cache of any
+    extent), which dequantizes IN KERNEL -- no
     query or weight quantization at all -- and this dense int8 path
     remains only an explicit short-context opt-in.  k_new/
     v_new: [B, 1, K, hd]; lengths: [B] valid cache positions (NOT

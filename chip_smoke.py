@@ -11,8 +11,9 @@ hidden 8192, vocabulary 128,256; weights random from a seed):
    on`` and ``transfer_guard: disallow``: image -> micro-batched resize
    -> fused (donating) resize chain -> Detector -> DetectionCaption ->
    LLM in the serving configuration (device loop, paged KV, int8
-   weights, flash prefill, ``max_seq`` at the flash-decode threshold so
-   the ``auto`` probes resolve the Pallas kernels).  A ``GatewayClient``
+   weights, flash prefill; on one chip the ``auto`` probes resolve the
+   Pallas kernels: a paged cache takes the paged kernel at any
+   extent).  A ``GatewayClient``
    in this process streams two waves of 8 frames at 640x640 over
    WebSocket ``/v1/stream``.  Passes only if every frame comes back ok,
    in order, with non-empty text; nothing implicit crossed to the host;

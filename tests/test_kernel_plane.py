@@ -423,12 +423,164 @@ def test_decode_loop_paged_kernel_token_identical():
     assert np.array_equal(streams["kernel"][0], streams["reference"][0])
 
 
+# -- a paged cache under the flash threshold (ISSUE 37) --------------------
+
+def _random_pools(cache, key):
+    """The cache with both pools drawn from ``key``: any length is then
+    a history, without a prefill."""
+    out = dict(cache)
+    for index, side in enumerate(("k", "v")):
+        out[side] = jax.random.normal(
+            jax.random.fold_in(key, index), cache[side].shape,
+            dtype=jnp.float32).astype(cache[side].dtype)
+    return out
+
+
+def test_paged_kernel_part_full_batch_matches_gather():
+    """``camera-paced``'s geometry in small: 16 slots of 4 pages, 3
+    rows live with ONE part-full page each, the other 13 at length 0
+    with no page at all (their table rows hold the trash page).  The
+    paged kernel -- four pages a group, so a live row is one group and
+    a dead row a grid step that copies nothing -- gives the logits of
+    the reference gather path in every row, and both leave the same
+    pools behind: the token's K/V written into the live page."""
+    rows, pps, page_tokens = 16, 4, 8
+    base = dataclasses.replace(
+        llama.LlamaConfig.tiny(vocab_size=64, max_seq=pps * page_tokens),
+        dtype="float32")
+    params = llama.init_params(jax.random.PRNGKey(0), base)
+    live = {2: (5, 3), 7: (9, 5), 13: (1, 7)}   # row: (page, length)
+    table = np.zeros((rows, pps), dtype=np.int32)
+    lengths = np.zeros(rows, dtype=np.int32)
+    for row, (page, length) in live.items():
+        table[row, 0], lengths[row] = page, length
+    tokens = jnp.arange(3, 3 + rows, dtype=jnp.int32)
+    assert paged_grid_steps(lengths, page_tokens, pps, pps) == (3, 16)
+    results = {}
+    for name, attention in (("kernel", "flash"), ("reference", "dense")):
+        config = dataclasses.replace(base, decode_attention=attention)
+        cache = _random_pools(
+            init_paged_cache(config, rows, config.max_seq, page_tokens),
+            jax.random.PRNGKey(7))
+        cache["page_table"] = jnp.asarray(table)
+        assert paged_pages_per_step(_split_paged(cache["k"]), pps) == pps
+        before = np.asarray(cache["k"])      # (the same on both sides)
+        logits, cache = llama.decode_step(params, config, tokens, cache,
+                                          jnp.asarray(lengths))
+        results[name] = (np.asarray(logits), np.asarray(cache["k"]),
+                         np.asarray(cache["v"]))
+    for kernel, reference in zip(results["kernel"],
+                                 results["reference"]):
+        np.testing.assert_allclose(kernel, reference, atol=2e-5,
+                                   rtol=2e-5)
+    after = results["kernel"][1]
+    for row, (page, length) in live.items():
+        # (the written page: one new position, the history untouched)
+        assert np.array_equal(after[:, page, :length],
+                              before[:, page, :length])
+        assert not np.array_equal(after[:, page, length],
+                                  before[:, page, length])
+        assert np.array_equal(after[:, page, length + 1:],
+                              before[:, page, length + 1:])
+
+
+def _served_blocks(config, use_flash_expected):
+    """Two blocks of the device loop on a paged cache of four slots,
+    with row 1 retiring and row 2 joining between them, as the batcher
+    folds them in: (emitted, counts) of each block."""
+    from aiko_services_tpu.models.llama import _resolve_decode_flash
+    params = llama.init_params(jax.random.PRNGKey(0), config)
+    cache = _random_pools(_fully_mapped_paged_cache(config, 4, 16),
+                          jax.random.PRNGKey(11))
+    assert _resolve_decode_flash(config, cache) is use_flash_expected
+    tokens = jnp.asarray([7, 11, 0, 0], dtype=jnp.int32)
+    lengths = jnp.asarray([9, 21, 0, 0], dtype=jnp.int32)
+    active = jnp.asarray([True, True, False, False])
+    budget = jnp.asarray([12, 12, 0, 0], dtype=jnp.int32)
+    key = jax.random.PRNGKey(5)
+    blocks = []
+    for block in range(2):
+        (emitted, counts, tokens, lengths, active, budget, _, key, _, _,
+         _, cache) = llama.decode_loop(
+            params, config, tokens, cache, lengths, active, budget,
+            jnp.zeros(4, dtype=jnp.float32),
+            jnp.full((4, 1), -1, dtype=jnp.int32),
+            jnp.full((4, 1), -1, dtype=jnp.int32), key, ring=4)
+        blocks.append((np.asarray(emitted), np.asarray(counts)))
+        if block == 0:
+            # (row 1 retires, row 2 joins with a 13-token history)
+            tokens = tokens.at[2].set(5)
+            lengths = lengths.at[2].set(13)
+            budget = budget.at[2].set(12)
+            active = jnp.asarray([True, False, True, False])
+    return blocks
+
+
+def test_decode_loop_paged_under_threshold_takes_the_kernel(monkeypatch):
+    """A paged cache whose extent (64) is under
+    ``flash_decode_threshold`` (1024) decodes through the paged kernel
+    under ``auto`` on the chip (ISSUE 37: ``ops.on_tpu`` answered "yes"
+    here, the kernel interpreted), token for token what the reference
+    path emits over two blocks with a row joining and a row retiring
+    between them."""
+    from aiko_services_tpu import ops
+    base = dataclasses.replace(
+        llama.LlamaConfig.tiny(vocab_size=64, max_seq=64),
+        dtype="float32")
+    assert base.decode_attention == "auto"
+    assert base.max_seq < base.flash_decode_threshold
+    reference = _served_blocks(
+        dataclasses.replace(base, decode_attention="dense"), False)
+    assert _served_blocks(base, False)[0][1].tolist() == [4, 4, 0, 0]
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)   # as on the chip
+    kernel = _served_blocks(base, True)
+    for (emitted, counts), (ref_emitted, ref_counts) in zip(kernel,
+                                                             reference):
+        assert np.array_equal(counts, ref_counts)
+        assert np.array_equal(emitted, ref_emitted)
+    assert kernel[1][1].tolist() == [4, 0, 4, 0]
+
+
+def test_resolve_paged_cell_cache_answers_kernel(monkeypatch):
+    """``camera-paced``'s cache -- ``init_paged_cache(..., max_seq=512,
+    page_tokens=128)`` at InternLM2's 8 KV heads of 128 in bfloat16,
+    16 slots -- resolves ``paged-kernel`` on the chip and 4 pages a
+    grid step (one group a row), so the batcher counts
+    ``llm_decode_live_grid_share`` there; off the chip, distributed or
+    with ``decode_attention: dense`` it stays ``reference`` / None."""
+    from aiko_services_tpu import ops
+    config = llama.LlamaConfig(vocab_size=64, dim=2048, n_layers=1,
+                               n_heads=16, n_kv_heads=8, hidden_dim=64,
+                               max_seq=512)
+    cache = init_paged_cache(config, 16, 512, page_tokens=128)
+    assert cache["k"].shape == (1, 65, 128, 1024)
+    assert llama.resolve_decode_backend(config, cache) == "reference"
+    assert llama.paged_decode_pages(config, cache) is None
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)   # as on the chip
+    assert llama.resolve_decode_backend(config, cache) == "paged-kernel"
+    assert llama.paged_decode_pages(config, cache) == 4
+    dense = dataclasses.replace(config, decode_attention="dense")
+    assert llama.resolve_decode_backend(dense, cache) == "reference"
+    assert llama.paged_decode_pages(dense, cache) is None
+    flat = llama.init_cache(config, 16, 512)
+    assert llama.resolve_decode_backend(config, flat) == "reference"
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:2]), ("tp",))
+    placed = jax.device_put(cache, jax.tree_util.tree_map(
+        lambda spec: jax.sharding.NamedSharding(mesh, spec),
+        llama.cache_specs(config, paged=True)))
+    assert llama.resolve_decode_backend(config, placed) == "reference"
+    assert llama.paged_decode_pages(config, placed) is None
+
+
 def test_decode_backend_capability_probe(monkeypatch):
     """The probe replaces the old raise: paged + explicit flash is the
-    paged kernel; auto follows platform/extent/threshold/structure;
-    distributed and dense force the reference path.  Off the chip every
-    ``auto`` probe resolves ``reference`` (ISSUE 21) -- only a kernel
-    asked for by name runs there."""
+    paged kernel; auto follows platform and the cache's LAYOUT -- a
+    paged cache takes the paged kernel at any extent (ISSUE 37: its
+    dense path copies a layer's whole pools a step), a dense one takes
+    flash from the threshold up -- and distributed and dense force the
+    reference path.  Off the chip every ``auto`` probe resolves
+    ``reference`` (ISSUE 21) -- only a kernel asked for by name runs
+    there."""
     from aiko_services_tpu import ops
     assert decode_backend("flash", paged=True,
                           page_tokens=64) == "paged-kernel"
@@ -437,14 +589,33 @@ def test_decode_backend_capability_probe(monkeypatch):
                           threshold=1024, page_tokens=64) == "reference"
     assert decode_backend("auto", extent=2048,
                           threshold=1024) == "reference"
+    assert decode_backend("auto", paged=True, extent=256,
+                          threshold=1024, page_tokens=64) == "reference"
     assert matmul_backend("auto") == "reference"
     monkeypatch.setattr(ops, "on_tpu", lambda: True)   # as on the chip
     assert matmul_backend("auto") == "pallas-int8"
     assert decode_backend("auto", paged=True, extent=2048,
                           threshold=1024,
                           page_tokens=64) == "paged-kernel"
+    # (the threshold is the dense cache's: a paged cache under it ...)
     assert decode_backend("auto", paged=True, extent=256,
+                          threshold=1024,
+                          page_tokens=64) == "paged-kernel"
+    assert decode_backend("auto", paged=True, extent=512,
+                          threshold=1024,
+                          page_tokens=128) == "paged-kernel"
+    # (... and what did NOT change)
+    assert decode_backend("auto", extent=256,
+                          threshold=1024) == "reference"
+    assert decode_backend("auto", paged=True, extent=256,
+                          threshold=1024, distributed=True,
+                          page_tokens=64) == "reference"
+    assert decode_backend("auto", paged=True, extent=256,
+                          threshold=1024, page_tokens=6) == "reference"
+    assert decode_backend("dense", paged=True, extent=256,
                           threshold=1024, page_tokens=64) == "reference"
+    assert decode_backend("reference", paged=True, extent=256,
+                          page_tokens=64) == "reference"
     assert decode_backend("auto", paged=True, extent=2048,
                           threshold=1024, page_tokens=6) == "reference"
     assert decode_backend("flash") == "dense-flash"
@@ -826,27 +997,24 @@ def test_int8_matmul_serves_the_unembed():
     assert matmul_backend("pallas") == "pallas-int8"
 
 
-@pytest.mark.parametrize("vocab,padded", [(384, 0), (300, 64 * 384)])
-def test_llm_element_stamps_the_unembed_blocks(runtime, vocab, padded):
-    """A served int8 model says in the flight recorder how the fused
-    unembed blocks its head at the decode width and what a call copies
-    to get there (``build:llm_unembed``, beside ``build:llm_cache``):
-    0 for a head of whole 128-lane tiles, the padded weight for one
-    that is not."""
+def _build_stamps(runtime, event: str, **parameters) -> list:
+    """Serve one request through an LLM element of ``model: tiny`` with
+    ``parameters`` and return the info of every ``build:<event>`` the
+    flight recorder holds afterwards."""
     import queue
 
     from aiko_services_tpu.pipeline import Pipeline
 
     responses = queue.Queue()
     pipeline = Pipeline({
-        "version": 0, "name": "unembed_stamp", "runtime": "jax",
+        "version": 0, "name": f"{event}_stamp", "runtime": "jax",
         "parameters": {}, "graph": ["(llm)"],
         "elements": [{
             "name": "llm", "input": [{"name": "text"}],
             "output": [{"name": "text"}],
-            "parameters": {"model": "tiny", "quantize": "int8",
-                           "vocab_size": vocab, "max_seq": 64,
-                           "max_new_tokens": 2, "max_slots": 4},
+            "parameters": {"model": "tiny", "max_seq": 64,
+                           "max_new_tokens": 2, "max_slots": 4,
+                           **parameters},
             "deploy": {"local": {
                 "module": "aiko_services_tpu.elements.llm",
                 "class_name": "LLM"}}}]}, runtime=runtime)
@@ -854,11 +1022,48 @@ def test_llm_element_stamps_the_unembed_blocks(runtime, vocab, padded):
     pipeline.create_frame_local(stream, {"text": "hi"})
     assert run_until(runtime, lambda: responses.qsize() >= 1,
                      timeout=180.0)
-    stamps = [event[6] for event in pipeline.recorder.snapshot()
-              if event[1] == "build" and event[4] == "llm_unembed"]
+    stamps = [entry[6] for entry in pipeline.recorder.snapshot()
+              if entry[1] == "build" and entry[4] == event]
     pipeline.stop()
-    assert stamps == [{"block_m": 8, "block_d": 64, "block_f": 384,
-                       "padded_weight_bytes": padded}]
+    return stamps
+
+
+@pytest.mark.parametrize("vocab,padded", [(384, 0), (300, 64 * 384)])
+def test_llm_element_stamps_the_unembed_blocks(runtime, vocab, padded):
+    """A served int8 model says in the flight recorder how the fused
+    unembed blocks its head at the decode width and what a call copies
+    to get there (``build:llm_unembed``, beside ``build:llm_cache``):
+    0 for a head of whole 128-lane tiles, the padded weight for one
+    that is not."""
+    assert _build_stamps(runtime, "llm_unembed", quantize="int8",
+                         vocab_size=vocab) \
+        == [{"block_m": 8, "block_d": 64, "block_f": 384,
+             "padded_weight_bytes": padded}]
+
+
+@pytest.mark.parametrize("page_tokens,on_chip,stamp", [
+    (0, False, {"backend": "reference", "extent": 64,
+                "page_tokens": None, "pages_per_step": None}),
+    (16, False, {"backend": "reference", "extent": 64,
+                 "page_tokens": 16, "pages_per_step": None}),
+    (16, True, {"backend": "paged-kernel", "extent": 64,
+                "page_tokens": 16, "pages_per_step": 4}),
+])
+def test_llm_element_stamps_the_decode_backend(runtime, monkeypatch,
+                                               page_tokens, on_chip,
+                                               stamp):
+    """A served Llama-family model says once a build which decode
+    attention the probe resolved for its cache and what the probe saw
+    (``build:llm_decode_backend``, beside ``build:llm_unembed``): a
+    paged cache of extent 64, far under the flash threshold, is the
+    paged kernel's on the chip (``ops.on_tpu`` answered "yes" here:
+    the request is then served through the interpreted kernel)."""
+    from aiko_services_tpu import ops
+
+    if on_chip:
+        monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    assert _build_stamps(runtime, "llm_decode_backend",
+                         kv_page_tokens=page_tokens) == [stamp]
 
 
 # -- on-TPU top-k -----------------------------------------------------------
