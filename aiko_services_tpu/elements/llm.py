@@ -579,6 +579,9 @@ class LLM(PipelineElement):
             fault_probe=self._fault_probe,
             trace=None if self._recorder() is None else self._trace_tick,
             cache_put=cache_put)
+        built = time.perf_counter()
+        # Every admission width is built here, before any request.
+        self._batcher.warm_admission()
         recorder = self._recorder()
         if recorder is not None:
             # What the cache holds, pool by pool, in bytes.
@@ -587,8 +590,11 @@ class LLM(PipelineElement):
                 for path, leaf in jax.tree_util.tree_leaves_with_path(
                     self._batcher.cache)}
             recorder.record("build", None, None, "llm_cache",
-                            (time.perf_counter() - started) * 1000.0,
-                            pools)
+                            (built - started) * 1000.0, pools)
+            recorder.record(
+                "build", None, None, "llm_admission_widths",
+                (time.perf_counter() - built) * 1000.0,
+                {"widths": list(self._batcher.admission_widths)})
             if isinstance(config, llama.LlamaConfig):
                 # Which decode attention the probe chose for this
                 # cache (ops.decode_backend follows its layout), and
@@ -914,7 +920,12 @@ class LLM(PipelineElement):
         telemetry = getattr(self.pipeline, "telemetry", None)
         stats = batcher.take_request_stats()
         block_stats = batcher.take_block_stats()
+        fills = batcher.take_admission_fills()
         if telemetry is not None:
+            for fill in fills:
+                # An admission chunk's real tokens over its program's
+                # rows (models/batching.py: ``admission_widths``).
+                telemetry.registry.observe("llm_admission_fill", fill)
             for entry in stats:
                 # Tenant/class labels (ISSUE 19): the per-tenant SLO
                 # view needs decode latency split the same way the

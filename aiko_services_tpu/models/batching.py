@@ -79,6 +79,10 @@ __all__ = ["Request", "ContinuousBatcher", "MicroBatcher",
 # power-of-two compile shapes, each a fresh jit of the full model).
 _ADMISSION_BURST_MAX = 8
 
+# The narrowest admission program over a dense cache, in tokens (a
+# paged cache's is its page).
+_DENSE_ADMISSION_FLOOR = 128
+
 # ``speculative: auto`` enables draft speculation only when the startup
 # micro-probe measures at least this tokens/s ratio over plain decode.
 SPEC_AUTO_MIN_RATIO = 1.2
@@ -207,7 +211,10 @@ def model_family(config):
     fit the slot -- the overlap would be applied twice, so the family
     takes a chunk that spills -- and the recorder's ``prefill`` info
     counts the chunks that were handed a state: ``state_carried``),
-    leave out
+    ``ADMISSION_NARROW_CHUNKS`` (models/llama.py: its admission takes a
+    chunk of any whole number of pages, so a chunk is padded only to
+    the least of ``admission_widths`` that holds its tokens, not to
+    ``prefill_chunk``), leave out
     ``prefill_into_slots`` (admission stays one slot a program) and
     return block statistics after ``decode_loop``'s twelve results,
     which its ``loop_stats`` turns into what the LLM element observes;
@@ -259,7 +266,8 @@ class _LoopBlock:
     def __init__(self, tree, snapshot, firsts_meta, grid=None, seq=0):
         self.tree = tree
         self.seq = seq                # blocks_dispatched at its launch
-        self.snapshot = snapshot      # [(slot, request)] in the block
+        # [(slot, request)] in the block, in admission order
+        self.snapshot = snapshot
         self.firsts_meta = firsts_meta  # [(slot, request)] admissions
         # (live, all) grid steps of the paged decode kernel at the
         # block's first step, or None where it does not serve decode
@@ -415,6 +423,20 @@ class ContinuousBatcher:
                 prefix_min_tokens=self.prefix_min_tokens)
         else:
             self.cache = family.init_cache(config, max_slots, self.max_seq)
+        # The rows an admission program may have, narrowest first: the
+        # page (a dense cache: 128 tokens) times a power of two, capped
+        # at ``prefill_chunk`` -- or ``prefill_chunk`` alone where the
+        # family takes no narrower chunk (``model_family``).  One
+        # compiled shape of each admission program a width.
+        self.admission_widths = (self.prefill_chunk,)
+        if getattr(family, "ADMISSION_NARROW_CHUNKS", False):
+            width = min(self.kv_page_tokens or _DENSE_ADMISSION_FLOOR,
+                        self.prefill_chunk)
+            widths = []
+            while width < self.prefill_chunk:
+                widths.append(width)
+                width *= 2
+            self.admission_widths = (*widths, self.prefill_chunk)
         # Multichip serving: ``cache_put`` places the initial KV cache
         # onto the serving mesh (e.g. ``lambda c: plan.put(c,
         # llama.cache_specs(config))`` for TP-sharded kv heads) --
@@ -459,8 +481,9 @@ class ContinuousBatcher:
         # the device trace gives the run), ``info`` its ``seq`` (the
         # program's own count of launches; ``blocks_dispatched`` for a
         # block), ``entered`` (the call's own ``perf_counter`` stamp)
-        # and ``call_ms`` (its length), with ``tokens`` of a chunk and
-        # ``steps_max`` of a block (``_launch``); the
+        # and ``call_ms`` (its length), with ``tokens`` and ``width``
+        # (the program's rows) of a chunk and ``steps_max`` of a block
+        # (``_launch``); the
         # ``retire_wait`` of a block carries its ``seq`` and the
         # ``steps`` it ran.  The device runs one stream in launch
         # order, so the i-th run of a program in a trace is one launch
@@ -525,6 +548,8 @@ class ContinuousBatcher:
         # the same way.
         self._request_stats: list[dict] = []
         self._block_stats: list[dict] = []
+        # each admission chunk's real tokens over its program's rows, %
+        self._admission_fills: list[float] = []
         # ``speculative: auto``: measure, then commit to draft or off.
         if self.speculative == "auto":
             self.spec_probe_ratio = self._spec_probe()
@@ -645,18 +670,21 @@ class ContinuousBatcher:
                 # (a prompt shorter than a block: nothing to prefill)
                 self._admission_advance(slot, request, start, 0, None)
                 continue
-            if not self._ensure_pages(slot, start + self.prefill_chunk):
+            width = self._admission_width(len(chunk_tokens))
+            if not self._ensure_pages(slot, start + width):
                 self._prefilling.append(slot)   # pool pressure: wait
                 continue
             self._sync_page_table()
-            padded = np.zeros((1, self.prefill_chunk), dtype=np.int32)
+            padded = np.zeros((1, width), dtype=np.int32)
             padded[0, :len(chunk_tokens)] = chunk_tokens
             logits, self.cache = self._launch(
                 "prefill_into_slot", self._family.prefill_into_slot,
                 self.params, self.config, jnp.asarray(padded),
                 self.cache, jnp.int32(slot), jnp.int32(start),
                 *self._admission_last(len(chunk_tokens)),
-                tokens=len(chunk_tokens))
+                tokens=len(chunk_tokens), width=width)
+            self._admission_fills.append(
+                100.0 * len(chunk_tokens) / width)
             self._admission_advance(slot, request, start,
                                     len(chunk_tokens), logits)
             chunks += 1
@@ -667,7 +695,10 @@ class ContinuousBatcher:
         """One chunk for EVERY admitting slot in a single batched
         dispatch.  N is padded up to a power-of-two compile bucket by
         duplicating the first row (idempotent: same slot, same start,
-        same tokens -- see llama.prefill_into_slots)."""
+        same tokens -- see llama.prefill_into_slots).  The burst runs
+        at its widest row's width; a row's pages cover its own width,
+        and its pad rows past them write the trash page (a dense
+        cache: the slot's own positions past its prompt)."""
         admitting = []
         for _ in range(len(self._prefilling)):
             if not self._prefilling:
@@ -675,8 +706,9 @@ class ContinuousBatcher:
             slot = self._prefilling.pop(0)
             if self.slots[slot] is None:    # cancelled/evicted
                 continue
-            start, _ = self._admission_chunk(self.slots[slot])
-            if not self._ensure_pages(slot, start + self.prefill_chunk):
+            start, chunk_tokens = self._admission_chunk(self.slots[slot])
+            if not self._ensure_pages(
+                    slot, start + self._admission_width(len(chunk_tokens))):
                 self._prefilling.append(slot)   # pool pressure: wait
                 continue
             admitting.append(slot)
@@ -692,26 +724,27 @@ class ContinuousBatcher:
         self._sync_page_table()
         n = len(admitting)
         rows = pad_to_bucket(admitting)
-        bucket = len(rows)
-        tokens = np.zeros((bucket, self.prefill_chunk), dtype=np.int32)
-        slot_rows = np.zeros(bucket, dtype=np.int32)
-        starts = np.zeros(bucket, dtype=np.int32)
-        metas = []
-        for i, slot in enumerate(rows):
-            request = self.slots[slot]
-            start, chunk_tokens = self._admission_chunk(request)
+        chunks = [(slot, self.slots[slot],
+                   *self._admission_chunk(self.slots[slot]))
+                  for slot in rows]
+        width = self._admission_width(
+            max(len(chunk_tokens) for *_, chunk_tokens in chunks))
+        tokens = np.zeros((len(rows), width), dtype=np.int32)
+        for i, (*_, chunk_tokens) in enumerate(chunks):
             tokens[i, :len(chunk_tokens)] = chunk_tokens
-            slot_rows[i] = slot
-            starts[i] = start
-            metas.append((slot, request, start, len(chunk_tokens)))
         logits, self.cache = self._launch(
             "prefill_into_slots", self._family.prefill_into_slots,
             self.params, self.config, jnp.asarray(tokens), self.cache,
-            jnp.asarray(slot_rows), jnp.asarray(starts),
-            tokens=sum(meta[3] for meta in metas[:n]))
-        for i, (slot, request, start, chunk_len) in enumerate(metas[:n]):
-            self._admission_advance(slot, request, start, chunk_len,
-                                    logits[i:i + 1])
+            jnp.asarray(np.asarray(rows, dtype=np.int32)),
+            jnp.asarray(np.asarray([chunk[2] for chunk in chunks],
+                                   dtype=np.int32)),
+            tokens=sum(len(chunk[3]) for chunk in chunks[:n]),
+            width=width)
+        for i, (slot, request, start, chunk_tokens) in enumerate(chunks[:n]):
+            self._admission_fills.append(
+                100.0 * len(chunk_tokens) / width)
+            self._admission_advance(slot, request, start,
+                                    len(chunk_tokens), logits[i:i + 1])
         return n
 
     def _admission_chunk(self, request: Request):
@@ -721,20 +754,49 @@ class ContinuousBatcher:
         and corrupt earlier positions); a clamped start re-writes the
         overlap with byte-identical KV (same tokens, same positions), so
         correctness is unaffected and only the final chunk pays.  The
-        chunk is always PADDED to prefill_chunk by the caller: one
-        compiled shape per admission; pad positions hold garbage KV, but
-        decode writes each position before the length mask ever admits
-        it, and the causal prefill mask never looks past the query
-        position.  A family with a recurrent state takes the chunk
-        where it starts (``ADMISSION_CARRIES_STATE``): the overlap of
-        a moved chunk would enter the state twice, so its admission
-        copes with a chunk that spills past the slot instead."""
+        caller PADS the chunk to the least of ``admission_widths`` that
+        holds it (``_admission_width``: one compiled shape per width,
+        and ``start + width <= max_seq`` whatever the width); pad
+        positions hold garbage KV, but decode writes each position
+        before the length mask ever admits it, and the causal prefill
+        mask never looks past the query position.  A family with a
+        recurrent state takes the chunk where it starts
+        (``ADMISSION_CARRIES_STATE``): the overlap of a moved chunk
+        would enter the state twice, so its admission copes with a
+        chunk that spills past the slot instead."""
         start = request.prefill_pos
         if not getattr(self._family, "ADMISSION_CARRIES_STATE", False):
             start = min(start, self.max_seq - self.prefill_chunk)
         return start, request.prompt_tokens[
             start:min(start + self.prefill_chunk,
                       self._admission_length(request))]
+
+    def _admission_width(self, tokens: int) -> int:
+        """Rows of the admission program for a chunk of ``tokens`` real
+        tokens: the least of ``admission_widths`` that holds them."""
+        return next(width for width in self.admission_widths
+                    if width >= tokens)
+
+    def warm_admission(self) -> None:
+        """Build the admission program of every width in
+        ``admission_widths`` before any request, as serving calls it
+        (and the slice of the last position's logits a first token is
+        sampled from): a width that traffic first needs mid-serving
+        would be compiled inside a request's latency.  Each width runs
+        once on pad tokens at slot 0, position 0 -- a paged slot with
+        no pages writes the trash page, a dense row positions written
+        again before any length admits them.  Nothing to build for a family
+        that takes ``prefill_chunk``-wide chunks alone: its first
+        chunk builds its one width."""
+        if len(self.admission_widths) == 1 \
+                or any(request is not None for request in self.slots):
+            return
+        for width in self.admission_widths:
+            logits, self.cache = self._family.prefill_into_slot(
+                self.params, self.config,
+                jnp.asarray(np.zeros((1, width), dtype=np.int32)),
+                self.cache, jnp.int32(0), jnp.int32(0))
+            self._sample(logits[:, width - 1, :], 0.0)
 
     def _admission_length(self, request: Request) -> int:
         """How much of the prompt admission writes: all of it, or its
@@ -877,7 +939,7 @@ class ContinuousBatcher:
         self._mark = now
 
     def _launch(self, program: str, function, *args, seq=None,
-                tokens=None, steps_max=None, **kwargs):
+                tokens=None, width=None, steps_max=None, **kwargs):
         """``function(*args, **kwargs)``, the launch of the device
         program ``program``.  With a ``trace`` tap it is handed on as
         it returns, numbered (``seq`` where the caller numbers it, the
@@ -896,6 +958,8 @@ class ContinuousBatcher:
         info = {"seq": seq, "entered": entered, "call_ms": call_ms}
         if tokens is not None:
             info["tokens"] = tokens
+        if width is not None:
+            info["width"] = width
         if steps_max is not None:
             info["steps_max"] = steps_max
         self.trace(program, None, info)
@@ -1186,9 +1250,12 @@ class ContinuousBatcher:
         for slot in snapshot:
             self._lengths_upper[slot] = min(
                 int(self._lengths_upper[slot]) + ring, self.max_seq)
+        # Rows in admission order, which the retire emits in (below).
+        rows = sorted(((i, self.slots[i]) for i in snapshot),
+                      key=lambda row: -1 if row[1] is None
+                      else row[1].admit_seq)
         self._loop_inflight.append(_LoopBlock(
-            tree, [(i, self.slots[i]) for i in snapshot], firsts_meta,
-            grid, self.blocks_dispatched))
+            tree, rows, firsts_meta, grid, self.blocks_dispatched))
         self.blocks_dispatched += 1
         return True
 
@@ -1213,16 +1280,21 @@ class ContinuousBatcher:
         self.blocks_retired += 1
         self.accepted_tokens += int(np.asarray(fetched["accepted"]).sum())
         self.draft_tokens += int(np.asarray(fetched["drafted"]).sum())
-        if "firsts" in fetched:
+        firsts = dict(blk.firsts_meta) if "firsts" in fetched else {}
+        if firsts:
             first_tokens = np.asarray(fetched["firsts"])
-            for slot, request in blk.firsts_meta:
-                if self.slots[slot] is request and not request.done:
-                    token = int(first_tokens[slot])
-                    self.current[slot] = token
-                    self._emit(request, token)
+        # Request by request in ADMISSION order, not slot order: of two
+        # requests that finish in one block, the one admitted first
+        # completes first -- a stream's frames are answered in the
+        # order they came, and the gateway drops an answer that arrives
+        # after a later frame's as a failover duplicate.
         for slot, request in blk.snapshot:
             if request is None or self.slots[slot] is not request:
                 continue
+            if firsts.get(slot) is request and not request.done:
+                token = int(first_tokens[slot])
+                self.current[slot] = token
+                self._emit(request, token)
             for index in range(int(counts[slot])):
                 if self.slots[slot] is not request or request.done:
                     break
@@ -1474,6 +1546,12 @@ class ContinuousBatcher:
         that counts nothing in its loop)."""
         stats, self._block_stats = self._block_stats, []
         return stats
+
+    def take_admission_fills(self) -> list[float]:
+        """Drain each admission chunk's real tokens over the rows of the
+        program that wrote it, in % (one a chunk, in launch order)."""
+        fills, self._admission_fills = self._admission_fills, []
+        return fills
 
     def _emit(self, request: Request, token: int):
         request.generated += 1

@@ -822,6 +822,14 @@ def prefill_into_slots(params: dict, config: LlamaConfig,
         slots, starts)
 
 
+# The batcher may hand admission a chunk only as wide as its real
+# tokens need: ``_admit_chunks`` takes any whole number of pages (a
+# dense cache any length), flash attention narrows its blocks to the
+# chunk, and pad rows only write positions past the length mask.  (An
+# MoE layer's capacity buffer follows the program's rows.)
+ADMISSION_NARROW_CHUNKS = True
+
+
 
 def _cache_distributed(cache) -> bool:
     """True when the cache payload lives sharded across more than one

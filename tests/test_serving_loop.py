@@ -159,7 +159,7 @@ FOLD_SLOTS = 8
 
 def _fold_batcher(params, config, emitted, phases=None, **kw):
     """Eight slots, chunks of 32 over pages of 16: a short prompt's
-    admission takes both pages its whole generation needs, so no fold
+    admission takes the page its first block needs, so no first fold
     of these tests finds the page table changed."""
     def emit(request_id, token, finished):
         emitted.setdefault(request_id, []).append(token)
@@ -352,6 +352,42 @@ def test_blocks_run_ahead_only_while_requests_wait(tiny):
     assert max(depth for waiting, depth in depths if waiting) == 3
     assert {depth for waiting, depth in depths if not waiting} == {1}
     assert any(not waiting for waiting, _ in depths)
+
+
+def test_requests_finishing_in_one_block_complete_in_admission_order(tiny):
+    """A later request in a LOWER slot that finishes in the same block
+    as an earlier one completes after it: a stream's frames are
+    answered in the order they came (the gateway drops an answer that
+    arrives after a later frame's as a failover duplicate).  ``x``
+    frees slot 0 after one block, ``b`` takes it and finishes, on its
+    first block, in the block that ``a`` (slot 1) finishes in."""
+    config, params = tiny
+    tok = ByteTokenizer()
+    order, retired = [], []
+
+    def emit(request_id, token, finished):
+        if finished:
+            order.append((request_id, len(retired)))
+
+    def tap(name, ms, info=None):
+        if name == "retire_wait":
+            retired.append(info["seq"])
+
+    batcher = ContinuousBatcher(params, config, max_slots=2, max_seq=64,
+                                prefill_chunk=16, decode_block_tokens=8,
+                                inflight=1, trace=tap)
+    requests = {name: Request(request_id=name, max_new_tokens=budget,
+                              prompt_tokens=tok.encode(f"{name} prompt"),
+                              emit=emit)
+                for name, budget in (("x", 2), ("a", 16), ("b", 8))}
+    batcher.submit(requests["x"])
+    batcher.submit(requests["a"])
+    batcher.step()
+    assert [request_id for request_id, _ in order] == ["x"]
+    batcher.submit(requests["b"])
+    assert batcher.run_until_drained(max_steps=100) < 100
+    assert [requests[name].slot for name in "xab"] == [0, 1, 0]
+    assert order == [("x", 1), ("a", 2), ("b", 2)]
 
 
 def test_device_page_table_follows_the_allocator(tiny_f32):
