@@ -346,12 +346,14 @@ ELEMENT_PARAMETERS: dict[tuple[str, str], dict[str, ParamSpec]] = {
         "family": ParamSpec(
             "model family the element builds from ``widths`` "
             "(models/families.py); absent = the ``model`` presets",
-            choices=("llama", "deepseek_v3", "olmo_hybrid", "sdar_moe")),
+            choices=("llama", "deepseek_v3", "olmo_hybrid", "sdar_moe",
+                     "cohere2_moe")),
         "widths": ParamSpec(
             "published config.json keys of the family (a key it lacks "
             "is refused; one left out keeps the family's default; "
-            "numbers, but for olmo_hybrid's list layer_types and "
-            "boolean linear_allow_neg_eigval)",
+            "numbers, but for the list layer_types of olmo_hybrid and "
+            "cohere2_moe and olmo_hybrid's boolean "
+            "linear_allow_neg_eigval)",
             kind="json"),
         "block_length": ParamSpec(
             "positions a block of the sdar_moe family's generation by "
@@ -361,6 +363,11 @@ ELEMENT_PARAMETERS: dict[tuple[str, str], dict[str, ParamSpec]] = {
         "denoising_steps": ParamSpec(
             "denoising passes a block of the sdar_moe family "
             "(1..block_length)",
+            number=True, minimum=1),
+        "expert_shards": ParamSpec(
+            "chips a layer's routed experts of the cohere2_moe family "
+            "are divided over (the router's outputs are num_experts "
+            "times this; this chip holds the first share)",
             number=True, minimum=1),
         "sample_top_k": ParamSpec(
             "restrict sampled rows to the k highest logits via the "

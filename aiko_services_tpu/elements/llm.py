@@ -39,7 +39,7 @@ import time
 
 import jax
 
-from ..models import deepseek, llama, olmo_hybrid, sdar
+from ..models import command_a, deepseek, llama, olmo_hybrid, sdar
 from ..models.batching import ContinuousBatcher, Request
 from ..models.checkpoint import maybe_restore as _restore
 from ..models.families import FAMILY_PARAMETERS, family_spec_error
@@ -228,9 +228,10 @@ class LLM(PipelineElement):
     MODEL FAMILIES: ``model`` picks a preset of the Llama family
     (``tiny`` | ``tiny-moe`` | ``llama3-1b`` | ``llama3-8b``);
     ``family`` (``llama`` | ``deepseek_v3`` | ``olmo_hybrid`` |
-    ``sdar_moe``) +
+    ``sdar_moe`` | ``cohere2_moe``) +
     ``widths`` (the family's published ``config.json`` keys -> numbers;
-    ``olmo_hybrid`` also takes the list ``layer_types`` and the boolean
+    ``olmo_hybrid`` and ``cohere2_moe`` also take the list
+    ``layer_types``, ``olmo_hybrid`` the boolean
     ``linear_allow_neg_eigval``: models/families.py) build any config
     of a family -- a key the family lacks is refused, one left out
     keeps its default.  ``deepseek_v3``
@@ -268,7 +269,20 @@ class LLM(PipelineElement):
     refuses the same parameters by name; its retired blocks observe
     ``llm_diffusion_tokens_per_row_pass``,
     ``llm_diffusion_commit_pass_share`` and the two ``llm_moe_*``
-    histograms.  An int8 model of the Llama family also gets
+    histograms.  ``cohere2_moe`` (models/command_a.py) is sliding-window
+    layers with rotary positions and global layers with none, in the
+    published ``layer_types`` pattern, each a parallel block of
+    attention and sigmoid-routed experts beside averaged shared ones;
+    ``expert_shards`` says over how many chips the router's experts
+    are divided (this one holds the first share).  Its window layers keep their
+    pages in pools of their own and give back every page wholly behind
+    the window; it refuses the same parameters by name, its retired
+    decode blocks observe ``llm_window_pages_held`` (the fullest live
+    row's window pages) and the two ``llm_moe_*`` histograms over the
+    experts it holds, the recorder's ``llm_tick:demux`` info
+    gets ``window_pages_released`` and its ``build:llm_cache`` info
+    ``window_pages_per_slot`` beside each pool's bytes.  An int8 model
+    of the Llama family also gets
     ``build:llm_unembed``: the fused unembed's blocks at the decode
     width and ``padded_weight_bytes``, what each of its calls copies
     to pad the head (0 unless no block fits: ops/pallas_matmul.py).
@@ -347,6 +361,7 @@ class LLM(PipelineElement):
     _MODEL_PARAMS = ("checkpoint", "tokenizer", "vocab_size", "max_seq",
                      "seed", "attention", "model", "family", "widths",
                      "quantize", "block_length", "denoising_steps",
+                     "expert_shards",
                      "decode_block", "inflight", "max_slots",
                      "decode_block_tokens", "speculative", "spec_tokens",
                      "spec_window", "kv_page_tokens", "kv_pages",
@@ -513,6 +528,10 @@ class LLM(PipelineElement):
             sdar, sdar.SdarConfig, "kernels",
             {"auto": "auto", "paged-kernel": "on", "reference": "off"},
             "its experts"),
+        "cohere2_moe": (
+            command_a, command_a.CommandAConfig, "kernels",
+            {"auto": "auto", "paged-kernel": "on", "reference": "off"},
+            "its experts or its window pools"),
     }
 
     def _ensure_one_chip_family(self, settings: dict, family: str):
@@ -523,8 +542,11 @@ class LLM(PipelineElement):
         float32 state beside full-attention layers over K/V pages;
         models/olmo_hybrid.py) or ``sdar_moe`` (generation by diffusion
         over blocks, softmax-routed experts; models/sdar.py, with its
-        own ``block_length`` and ``denoising_steps``).  What the family
-        cannot serve was refused above, by the parameter's name."""
+        own ``block_length`` and ``denoising_steps``) or ``cohere2_moe``
+        (window and global layers, a chip's share of the experts;
+        models/command_a.py, with its own ``expert_shards``).  What the
+        family cannot serve was refused above, by the parameter's
+        name."""
         module, config_class, kernel_field, kernel_values, unsharded = \
             self._ONE_CHIP_FAMILIES[family]
         plan = self._stage_plan()
@@ -589,6 +611,9 @@ class LLM(PipelineElement):
                 jax.tree_util.keystr(path): int(leaf.nbytes)
                 for path, leaf in jax.tree_util.tree_leaves_with_path(
                     self._batcher.cache)}
+            if self._batcher.window_pages_per_slot:
+                pools["window_pages_per_slot"] = \
+                    self._batcher.window_pages_per_slot
             recorder.record("build", None, None, "llm_cache",
                             (built - started) * 1000.0, pools)
             recorder.record(
@@ -949,8 +974,9 @@ class LLM(PipelineElement):
                                                **labels)
             for observed in block_stats:
                 if "moe_experts_touched" in observed:
-                    # What the latent family counted in a retired
-                    # block (models/deepseek.py:loop_stats).
+                    # What a routed family counted in a retired block
+                    # (models/deepseek.py, sdar.py, command_a.py:
+                    # loop_stats; of the held experts alone in the last).
                     telemetry.registry.observe(
                         "llm_moe_experts_touched",
                         observed["moe_experts_touched"])
@@ -974,6 +1000,11 @@ class LLM(PipelineElement):
                     telemetry.registry.observe(
                         "llm_state_traffic_share",
                         observed["state_traffic_share"])
+                if "window_pages_held" in observed:
+                    # The fullest live row's pages in a window layer.
+                    telemetry.registry.observe(
+                        "llm_window_pages_held",
+                        observed["window_pages_held"])
                 if "paged_grid_steps" in observed:
                     # How much of the paged decode kernel's grid had a
                     # live page to stream at the block's first step.

@@ -65,7 +65,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import deepseek, llama, olmo_hybrid, sdar
+from . import command_a, deepseek, llama, olmo_hybrid, sdar
 from .paged import PageAllocator, init_paged_cache, pages_per_slot
 from .quant import draft_params
 from ..utils.misc import next_power_of_two
@@ -230,9 +230,16 @@ def model_family(config):
     tokens a row or none, so the ring needs room for a block a row, the
     pages are assured a block past the stored positions, a first token
     arrives when the first block commits, and ``steps`` counts passes
-    (the family's ``loop_stats`` counts the rows in them)."""
+    (the family's ``loop_stats`` counts the rows in them).  A config
+    with ``n_window_layers`` (models/command_a.py) gets window pages
+    beside its pages (``paged.PageAllocator``): the batcher tells the
+    allocator, before each program, the lowest position a program not
+    yet retired may read, and the pages wholly behind the window of
+    that position go back."""
     if isinstance(config, deepseek.DeepseekConfig):
         return deepseek
+    if isinstance(config, command_a.CommandAConfig):
+        return command_a
     if isinstance(config, olmo_hybrid.OlmoHybridConfig):
         return olmo_hybrid
     if isinstance(config, sdar.SdarConfig):
@@ -277,7 +284,8 @@ class _LoopBlock:
 class ContinuousBatcher:
     def __init__(self, params,
                  config: llama.LlamaConfig | deepseek.DeepseekConfig
-                 | olmo_hybrid.OlmoHybridConfig | sdar.SdarConfig,
+                 | olmo_hybrid.OlmoHybridConfig | sdar.SdarConfig
+                 | command_a.CommandAConfig,
                  max_slots: int = 8, max_seq: int | None = None,
                  prefill_chunk: int = 512, rng_seed: int = 0,
                  inflight: int = 2,
@@ -407,6 +415,13 @@ class ContinuousBatcher:
                 "prefix_cache: on shares KV at page granularity: set "
                 "kv_page_tokens > 0")
         self._pages: PageAllocator | None = None
+        # Sliding-window layers (``model_family``): their window in
+        # tokens, the most pages a slot's window layers hold -- the
+        # window and an admission chunk, straddling a page -- and the
+        # physical pages of their pools, that many a slot.
+        self._window = getattr(config, "window", 0) \
+            if getattr(config, "n_window_layers", 0) else 0
+        self.window_pages_per_slot = self._window_pages = 0
         if self.kv_page_tokens:
             pps = pages_per_slot(self.max_seq, self.kv_page_tokens)
             if self.prefill_chunk % self.kv_page_tokens:
@@ -414,13 +429,21 @@ class ContinuousBatcher:
                     f"kv_page_tokens={self.kv_page_tokens} must divide "
                     f"prefill_chunk ({self.prefill_chunk}) so admission "
                     f"chunks stay page-aligned")
+            if self._window:
+                self.window_pages_per_slot = min(pps, -(
+                    -(self._window + self.prefill_chunk)
+                    // self.kv_page_tokens) + 1)
+                self._window_pages = \
+                    max_slots * self.window_pages_per_slot + 1
             self.cache = init_paged_cache(
                 config, max_slots, self.max_seq, self.kv_page_tokens,
-                kv_pages)
+                kv_pages, self._window_pages or None)
             pool = family.cache_array(self.cache).shape[1]
             self._pages = PageAllocator(
                 pool, pps, max_slots, prefix_cache=self.prefix_cache,
-                prefix_min_tokens=self.prefix_min_tokens)
+                prefix_min_tokens=self.prefix_min_tokens,
+                window_pages=self._window_pages)
+            self._window_released = 0   # at the last retire
         else:
             self.cache = family.init_cache(config, max_slots, self.max_seq)
         # The rows an admission program may have, narrowest first: the
@@ -513,6 +536,8 @@ class ContinuousBatcher:
         self._slot_index = [jnp.int32(slot) for slot in range(max_slots)]
         self._page_rows = None if self._pages is None else np.zeros(
             (max_slots, self._pages.pps), dtype=np.int32)
+        self._window_rows = None if not self._window_pages \
+            else np.zeros_like(self._page_rows)
         # Device calls (programs and explicit uploads) made by the
         # fold-in and the page-table sync, counted where they are made.
         self._launches = 0
@@ -671,7 +696,7 @@ class ContinuousBatcher:
                 self._admission_advance(slot, request, start, 0, None)
                 continue
             width = self._admission_width(len(chunk_tokens))
-            if not self._ensure_pages(slot, start + width):
+            if not self._ensure_pages(slot, start + width, start):
                 self._prefilling.append(slot)   # pool pressure: wait
                 continue
             self._sync_page_table()
@@ -708,7 +733,8 @@ class ContinuousBatcher:
                 continue
             start, chunk_tokens = self._admission_chunk(self.slots[slot])
             if not self._ensure_pages(
-                    slot, start + self._admission_width(len(chunk_tokens))):
+                    slot, start + self._admission_width(len(chunk_tokens)),
+                    start):
                 self._prefilling.append(slot)   # pool pressure: wait
                 continue
             admitting.append(slot)
@@ -969,7 +995,8 @@ class ContinuousBatcher:
         if self._pages is not None:
             for slot in decoding:
                 if not self._ensure_pages(slot,
-                                          int(self.lengths[slot]) + 2):
+                                          int(self.lengths[slot]) + 2,
+                                          int(self.lengths[slot])):
                     # Unreachable while the pool holds one full slot
                     # (pps + 1, enforced at init): preempt the slot
                     # itself rather than let its write land on the
@@ -1183,7 +1210,10 @@ class ContinuousBatcher:
             if self.slots[slot] is None:
                 continue                # evicted by an earlier ensure
             upto = int(self._lengths_upper[slot]) + ring + spec_extra
-            if not self._ensure_pages(slot, upto):
+            # (the oldest block not yet retired reads from the length
+            # the host last fetched)
+            if not self._ensure_pages(slot, upto,
+                                      int(self.lengths[slot])):
                 return False            # retire in-flight blocks first
         # An ensure above may have PREEMPTED a just-admitted slot for
         # its pages (the youngest occupant is usually a joining one):
@@ -1316,6 +1346,19 @@ class ContinuousBatcher:
                         "paged_grid_steps_live": live_steps,
                         "paged_grid_steps": grid_steps,
                         "paged_pages_per_step": self._paged_pages}
+        if self._window:
+            # The fullest live row's window pages, and the window pages
+            # given back since the last retire (host-side: no fetch).
+            held = [self._pages.window_holds(slot)
+                    for slot, request in blk.snapshot
+                    if request is not None and self.slots[slot] is request]
+            released = self._pages.window_released
+            observed = {**(observed or {}),
+                        "window_pages_released":
+                            released - self._window_released}
+            if held:
+                observed["window_pages_held"] = max(held)
+            self._window_released = released
         if observed:
             self._block_stats.append(observed)
         if self.trace is not None:
@@ -1323,18 +1366,25 @@ class ContinuousBatcher:
 
     # -- paged-cache bookkeeping -------------------------------------------
 
-    def _ensure_pages(self, slot: int, upto_tokens: int) -> bool:
+    def _ensure_pages(self, slot: int, upto_tokens: int,
+                      reads_from: int = 0) -> bool:
         """Cover the slot's logical positions [0, upto_tokens) with
-        physical pages.  Under pool pressure: with blocks in flight the
-        caller must retire them first (their writes still route through
-        the already-dispatched table), otherwise the YOUNGEST other
-        occupant is preempted -- its generation resumes later from its
-        committed tokens, exactly like :meth:`recover`."""
+        physical pages -- a window layer's from the window of
+        ``reads_from`` on, the lowest position a query of the program
+        about to be launched or of one not yet retired may have (its
+        pages wholly behind that window go back).  Under pool pressure:
+        with blocks in flight the caller must retire them first (their
+        writes still route through the already-dispatched table),
+        otherwise the YOUNGEST other occupant is preempted -- its
+        generation resumes later from its committed tokens, exactly
+        like :meth:`recover`."""
         if self._pages is None:
             return True
         pages = self._pages.pages_for(
             min(int(upto_tokens), self.max_seq), self.kv_page_tokens)
-        if self._pages.ensure(slot, pages):
+        first = max(0, int(reads_from) + 1 - self._window) \
+            // self.kv_page_tokens if self._window else 0
+        if self._pages.ensure(slot, pages, first):
             return True
         if self._loop_inflight:
             return False
@@ -1345,7 +1395,7 @@ class ContinuousBatcher:
             if not victims:
                 return False
             self._evict_slot(max(victims)[1])
-            if self._pages.ensure(slot, pages):
+            if self._pages.ensure(slot, pages, first):
                 return True
 
     def _sync_page_table(self) -> None:
@@ -1356,18 +1406,23 @@ class ContinuousBatcher:
         it replaces -- across the serving mesh where ``cache_put`` put
         that one there, uncommitted otherwise -- so the programs that
         take the cache see the arguments they were built for."""
-        if self._pages is None or not self._pages.dirty:
+        if self._pages is None:
             return
-        for slot, row in self._pages.dirty.items():
-            self._page_rows[slot] = row
-        self._pages.dirty.clear()
-        table = self.cache["page_table"]
-        # (a copy of the mirror: the device may read the host's buffer
-        # after the call returns)
-        self.cache["page_table"] = jax.device_put(
-            self._page_rows.copy(),
-            table.sharding if table.committed else None)
-        self._launches += 1
+        for key, dirty, mirror in (
+                ("page_table", self._pages.dirty, self._page_rows),
+                ("window_table", self._pages.window_dirty,
+                 self._window_rows)):
+            if not dirty:
+                continue
+            for slot, row in dirty.items():
+                mirror[slot] = row
+            dirty.clear()
+            table = self.cache[key]
+            # (a copy of the mirror: the device may read the host's
+            # buffer after the call returns)
+            self.cache[key] = jax.device_put(
+                mirror.copy(), table.sharding if table.committed else None)
+            self._launches += 1
 
     def _evict_slot(self, slot: int) -> None:
         """Preempt one slot for its pages: rebase the request onto its
@@ -1427,9 +1482,12 @@ class ContinuousBatcher:
         if self._pages is not None:
             self._pages.reset()
             self._page_rows[:] = 0
+            if self._window_rows is not None:
+                self._window_rows[:] = 0
             self.cache = init_paged_cache(
                 self.config, self.max_slots, self.max_seq,
-                self.kv_page_tokens, self._pages.total)
+                self.kv_page_tokens, self._pages.total,
+                self._window_pages or None)
         else:
             self.cache = self._family.init_cache(
                 self.config, self.max_slots, self.max_seq)

@@ -3,7 +3,7 @@
 Imports nothing of jax, so the create-time parameter check
 (``analysis/params.py``) and the runtime (``elements/llm.py``,
 ``models/llama.py``, ``models/deepseek.py``, ``models/olmo_hybrid.py``,
-``models/sdar.py``) read ONE table.
+``models/sdar.py``, ``models/command_a.py``) read ONE table.
 
 An LLM element names a family and hands it widths::
 
@@ -14,12 +14,15 @@ An LLM element names a family and hands it widths::
 
 A key the family lacks is an error; a key left out keeps the family's
 default (``LlamaConfig`` / ``DeepseekConfig`` / ``OlmoHybridConfig`` /
-``SdarConfig``).
+``SdarConfig`` / ``CommandAConfig``).
 Widths are numbers, but for the keys of ``LIST_WIDTHS`` (a list of
-names from the given set: the ``olmo_hybrid`` family's per-layer
-``layer_types``) and ``BOOL_WIDTHS`` (a boolean).  A family may also
-take parameters of its own beside its widths (``FAMILY_PARAMETERS``:
-the ``sdar_moe`` family's ``block_length`` and ``denoising_steps``).
+names from the family's set: the ``olmo_hybrid`` and ``cohere2_moe``
+families' per-layer ``layer_types``) and ``BOOL_WIDTHS`` (a boolean).
+A family may also take parameters of its own beside its widths
+(``FAMILY_PARAMETERS``: the ``sdar_moe`` family's ``block_length`` and
+``denoising_steps``; the ``cohere2_moe`` family's ``expert_shards``,
+the chips a layer's experts are divided over, of which this one holds
+the first share).
 """
 
 from __future__ import annotations
@@ -75,6 +78,18 @@ FAMILY_WIDTHS: dict[str, dict[str, str]] = {
         "num_experts": "n_experts",
         "num_experts_per_tok": "n_experts_per_token",
         "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps"},
+    "cohere2_moe": {
+        "vocab_size": "vocab_size", "hidden_size": "dim",
+        "num_hidden_layers": "n_layers",
+        "num_attention_heads": "n_heads",
+        "num_key_value_heads": "n_kv_heads", "head_dim": "head_dim",
+        "intermediate_size": "moe_hidden_dim",
+        "num_experts": "n_experts",
+        "num_experts_per_tok": "n_experts_per_token",
+        "num_shared_experts": "n_shared_experts",
+        "sliding_window": "window", "layer_types": "layer_types",
+        "rope_theta": "rope_theta", "layer_norm_eps": "norm_eps",
+        "logit_scale": "logit_scale"},
 }
 
 #: family -> {element parameter: config dataclass field} for what a
@@ -83,12 +98,15 @@ FAMILY_WIDTHS: dict[str, dict[str, str]] = {
 FAMILY_PARAMETERS: dict[str, dict[str, str]] = {
     "sdar_moe": {"block_length": "block_length",
                  "denoising_steps": "denoising_steps"},
+    "cohere2_moe": {"expert_shards": "expert_shards"},
 }
 
-#: width key -> the names its list may hold (every other width is a
-#: number or, for ``BOOL_WIDTHS``, a boolean)
-LIST_WIDTHS: dict[str, tuple] = {
-    "layer_types": ("linear_attention", "full_attention")}
+#: family -> {width key: the names its list may hold} (every other
+#: width is a number or, for ``BOOL_WIDTHS``, a boolean)
+LIST_WIDTHS: dict[str, dict[str, tuple]] = {
+    "olmo_hybrid": {"layer_types": ("linear_attention", "full_attention")},
+    "cohere2_moe": {"layer_types": ("sliding_attention",
+                                    "full_attention")}}
 BOOL_WIDTHS = ("linear_allow_neg_eigval",)
 
 #: family -> {parameter: why it is refused} for every parameter whose
@@ -131,9 +149,23 @@ FAMILY_REFUSES: dict[str, dict[str, str]] = {
                         "bit-equal under grouped expert matmuls",
         "model": "a family is built from widths, not from a preset",
     },
+    "cohere2_moe": {
+        "quantize": "the family serves bfloat16 weights and bfloat16 "
+                    "K/V pages (no int8)",
+        "speculative": "a draft's rejected tokens would have to give "
+                       "back window pages already released; none is "
+                       "kept",
+        "spec_tokens": "speculation is not served",
+        "spec_window": "speculation is not served",
+        "prefix_cache": "window layers hold no prefix once their pages "
+                        "are released, and re-written shared pages are "
+                        "not bit-equal under grouped expert matmuls",
+        "model": "a family is built from widths, not from a preset",
+    },
 }
 
-_FLOAT_FIELDS = ("rope_theta", "norm_eps", "routed_scaling_factor")
+_FLOAT_FIELDS = ("rope_theta", "norm_eps", "routed_scaling_factor",
+                 "logit_scale")
 
 
 def config_fields(family: str, widths: dict) -> dict:
@@ -146,22 +178,22 @@ def config_fields(family: str, widths: dict) -> dict:
         raise ValueError(
             f"widths: the {family} family has no {unknown} "
             f"(has: {sorted(known)})")
-    return {known[key]: _field_value(key, known[key], value)
+    return {known[key]: _field_value(family, key, known[key], value)
             for key, value in widths.items()}
 
 
-def _field_value(key: str, field: str, value):
-    if key in LIST_WIDTHS:
+def _field_value(family: str, key: str, field: str, value):
+    if key in LIST_WIDTHS.get(family, {}):
         return tuple(str(item) for item in value)
     if key in BOOL_WIDTHS:
         return bool(value)
     return float(value) if field in _FLOAT_FIELDS else int(value)
 
 
-def _width_error(key: str, value) -> str | None:
+def _width_error(family: str, key: str, value) -> str | None:
     """What is wrong with one width's value, or None."""
-    if key in LIST_WIDTHS:
-        names = LIST_WIDTHS[key]
+    if key in LIST_WIDTHS.get(family, {}):
+        names = LIST_WIDTHS[family][key]
         if not isinstance(value, (list, tuple)) or not value \
                 or any(item not in names for item in value):
             return f"widths: {key} must be a list of {'|'.join(names)}"
@@ -216,7 +248,7 @@ def family_spec_error(parameters: dict) -> str | None:
             return f"widths: the {family} family has no {unknown} " \
                    f"(has: {sorted(known)})"
         for key in sorted(widths):
-            problem = _width_error(key, widths[key])
+            problem = _width_error(family, key, widths[key])
             if problem is not None:
                 return problem
     for name, why in FAMILY_REFUSES[family].items():

@@ -1,9 +1,11 @@
 """The routed experts' grouped matmuls, shared by the families that
 route (``models/deepseek.py``: sigmoid scores, selection bias, scaled
-gates; ``models/sdar.py``: softmax scores, renormalised gates).  A
-family's own router says which experts each token goes to and with
-what gates; the sort, the grouped matmuls, the unsort and the
-gate-weighted sum are :func:`routed_experts`, one code for both."""
+gates; ``models/sdar.py``: softmax scores, renormalised gates;
+``models/command_a.py``: sigmoid scores, renormalised gates, a chip's
+share of the experts).  A family's own router says which experts each
+token goes to and with what gates; the sort, the grouped matmuls, the
+unsort and the gate-weighted sum are :func:`routed_experts`, one code
+for all three."""
 
 from __future__ import annotations
 
@@ -15,19 +17,33 @@ from ..ops.tiles import on_tpu
 __all__ = ["routed_experts"]
 
 _GROUPED_ROWS = 128     # megablox's row tile
+_TILE_BYTES = 6 << 20   # the largest expert-matrix tile megablox takes
+
+
+def _tiling(weights) -> tuple:
+    """megablox's tiling: one tile a whole expert matrix wide (the
+    tiling that won the sweep on the v5e: PERF.md) where the matrix is
+    at most ``_TILE_BYTES``, else its larger side halved until it is
+    (a 4096 x 4096 expert's 32 MiB tile, double-buffered, is more fast
+    memory than a kernel may take)."""
+    depth, width = weights.shape[1:]
+    while depth * width * weights.dtype.itemsize > _TILE_BYTES:
+        if depth >= width:
+            depth //= 2
+        else:
+            width //= 2
+    return _GROUPED_ROWS, depth, width
 
 
 def _megablox(rows, weights, groups):
-    """``ragged_dot`` by the megablox kernel, one tile a whole expert
-    matrix wide (the tiling that won the sweep on the v5e: PERF.md)."""
+    """``ragged_dot`` by the megablox kernel (:func:`_tiling`)."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
     return gmm(rows, weights, groups, preferred_element_type=rows.dtype,
-               tiling=(_GROUPED_ROWS,) + weights.shape[1:],
-               interpret=not on_tpu())
+               tiling=_tiling(weights), interpret=not on_tpu())
 
 
 def routed_experts(h, chosen, gates, experts, *, grouped_matmul: str,
-                   valid=None, index=None):
+                   valid=None, index=None, held=None):
     """The routed experts' part of a sparse layer over ``h [N, D]``,
     given the router's verdict (``chosen [N, k]`` expert ids, ``gates
     [N, k]`` float32): every token-expert pair computed, none dropped.
@@ -47,11 +63,27 @@ def routed_experts(h, chosen, gates, experts, *, grouped_matmul: str,
     fuses a slice into an einsum, not into a custom call: the device
     loop copied all three matrices, every layer and step).
 
-    Returns (out ``[N, D]``, rows per expert ``[E]``)."""
+    ``held = (first, count)`` (static) is a chip's SHARE of the experts:
+    ``chosen`` names experts of the whole layer, ``experts`` holds the
+    ``count`` of them from ``first`` on, and a pair whose expert is not
+    held sorts past the last group as an invalid row does and gives
+    nought.  Gates are as the router gave them (normalised over all it
+    chose), so the shares of a layer sum to the whole layer's routed
+    part.
+
+    Returns (out ``[N, D]``, rows per expert ``[E]``, of the held
+    experts where ``held``)."""
     n, k = chosen.shape
     e = experts["w_up"].shape[0 if index is None else 1]
     expert_of = chosen.reshape(-1)                           # [N*k]
-    if valid is not None:
+    keep = None
+    if held is not None:
+        expert_of = expert_of - held[0]
+        keep = (expert_of >= 0) & (expert_of < held[1])
+        if valid is not None:
+            keep = keep & jnp.repeat(valid, k)
+        expert_of = jnp.where(keep, expert_of, e)
+    elif valid is not None:
         expert_of = jnp.where(jnp.repeat(valid, k), expert_of, e)
     megablox = grouped_matmul == "megablox" \
         or (grouped_matmul == "auto" and on_tpu())
@@ -79,6 +111,8 @@ def routed_experts(h, chosen, gates, experts, *, grouped_matmul: str,
     # rows past the last group hold whatever the kernel left there
     out = out[jnp.argsort(order)[:n * k]].reshape(n, k, -1) \
         .astype(jnp.float32)
-    if valid is not None:
+    if keep is not None:
+        out = jnp.where(keep.reshape(n, k, 1), out, 0.0)
+    elif valid is not None:
         out = jnp.where(valid[:, None, None], out, 0.0)
     return (out * gates[..., None]).sum(1).astype(h.dtype), sizes

@@ -17,7 +17,14 @@ KV in fixed-size **pages** instead:
   instead (``slot_state``: the recurrent state and the convolution's
   tail, ``[L_lin, slots, ...]``, addressed by the slot and by no page
   table) rides the same cache dict -- one ``init_paged_cache``, one
-  allocator, one ``recover()``;
+  allocator, one ``recover()``; a config whose layers of one kind
+  attend a sliding WINDOW (``n_window_layers`` of ``window`` tokens:
+  models/command_a.py) gets a second pair of pools for them,
+  ``window_k`` / ``window_v`` ``[L_win, P_win, page_tokens, K*hd]``,
+  behind a second table, ``window_table``, from which the allocator
+  takes back every page that lies wholly behind the window (the pages
+  the window layers hold stay bounded whatever the context), while the
+  other layers keep every page;
 - a device **page table** ``[B, pages_per_slot] int32`` mapping each
   slot's logical pages to physical pages.  Entry 0 is the reserved
   TRASH page: unallocated logical pages point at it, and inactive
@@ -88,7 +95,8 @@ def pages_per_slot(max_seq: int, page_tokens: int) -> int:
 
 def init_paged_cache(config, batch: int, max_seq: int | None = None,
                      page_tokens: int = 64,
-                     total_pages: int | None = None) -> dict:
+                     total_pages: int | None = None,
+                     window_pages: int | None = None) -> dict:
     """Paged serving cache: ``{"k": pool, "v": pool, "page_table"}``,
     or, for a config with a ``latent_width`` (latent attention: one
     payload a token, no heads, no k/v pair), ``{"latent": pool,
@@ -108,7 +116,10 @@ def init_paged_cache(config, batch: int, max_seq: int | None = None,
     page 0 (default: full provisioning, ``batch * pages_per_slot + 1``
     -- memory parity with the dense cache; size it down to serve more
     slots than worst-case memory allows, with the ContinuousBatcher
-    preempting under pool pressure)."""
+    preempting under pool pressure).  A config with
+    ``n_window_layers`` also gets ``window_k`` / ``window_v`` pools that
+    deep of ``window_pages`` physical pages (default: full
+    provisioning, as ``total_pages``) and their own ``window_table``."""
     c = config
     t = max_seq or c.max_seq
     pps = pages_per_slot(t, page_tokens)
@@ -140,6 +151,14 @@ def init_paged_cache(config, batch: int, max_seq: int | None = None,
         name: jnp.zeros(lead + (batch,) + shape, dtype=jnp.dtype(dtype))
         for name, (lead, shape, dtype)
         in getattr(c, "slot_state", {}).items()}
+    windowed = getattr(c, "n_window_layers", 0)
+    if windowed:
+        window_shape = (windowed, batch * pps + 1 if window_pages is None
+                        else int(window_pages)) + shape[2:]
+        per_slot.update(
+            window_k=jnp.zeros(window_shape, dtype=jnp.dtype(c.dtype)),
+            window_v=jnp.zeros(window_shape, dtype=jnp.dtype(c.dtype)),
+            window_table=jnp.zeros((batch, pps), dtype=jnp.int32))
     return {"k": side(), "v": side(), "page_table": table, **per_slot}
 
 
@@ -353,11 +372,22 @@ class PageAllocator:
     there and prefills it, leaving the donor untouched.  The index
     itself holds a reference, so warm pages survive their slot and
     serve the next request; under pool pressure :meth:`ensure` reclaims
-    index-only (refcount-1) entries leaf-first."""
+    index-only (refcount-1) entries leaf-first.
+
+    Window pages (``window_pages`` > 0: the physical pages of a cache's
+    ``window_k`` / ``window_v`` pools, ``init_paged_cache``): a second
+    free list and table row a slot, ``window_dirty`` beside ``dirty``.
+    :meth:`ensure` with ``first`` covers logical pages [first, pages)
+    there and gives back every page of the slot before ``first`` -- the
+    caller names as ``first`` the page of the lowest position a program
+    not yet retired may still read (``ContinuousBatcher._ensure_pages``),
+    so a page goes back only once no program in flight can read it, and
+    a page another slot takes is written by a program launched after
+    every one that read it (the device runs them in launch order)."""
 
     def __init__(self, total_pages: int, pages_per_slot: int,
                  max_slots: int, prefix_cache: bool = False,
-                 prefix_min_tokens: int = 64):
+                 prefix_min_tokens: int = 64, window_pages: int = 0):
         self.total = int(total_pages)
         self.pps = int(pages_per_slot)
         self.max_slots = int(max_slots)
@@ -367,6 +397,12 @@ class PageAllocator:
         self._slots: dict[int, dict[int, int]] = {}
         # slot -> host table row pending upload (numpy-friendly lists).
         self.dirty: dict[int, list[int]] = {}
+        # -- window pages ------------------------------------------------
+        self.window_total = int(window_pages)
+        self._window_free = list(range(self.window_total - 1, 0, -1))
+        self._window_slots: dict[int, dict[int, int]] = {}
+        self.window_dirty: dict[int, list[int]] = {}
+        self.window_released = 0       # pages given back behind a window
         # -- prefix cache ------------------------------------------------
         self.prefix_cache = bool(prefix_cache)
         self.prefix_min_tokens = int(prefix_min_tokens)
@@ -394,6 +430,10 @@ class PageAllocator:
     def holds(self, slot: int) -> int:
         return len(self._slots.get(slot, ()))
 
+    def window_holds(self, slot: int) -> int:
+        """Window pages the slot holds."""
+        return len(self._window_slots.get(slot, ()))
+
     def missing(self, slot: int, pages: int) -> int:
         """How many NEW pages covering logical pages [0, pages) would
         need allocating for ``slot``."""
@@ -401,10 +441,12 @@ class PageAllocator:
         return sum(1 for logical in range(min(pages, self.pps))
                    if logical not in owned)
 
-    def ensure(self, slot: int, pages: int) -> bool:
+    def ensure(self, slot: int, pages: int, first: int = 0) -> bool:
         """Allocate (atomically) whatever logical pages [0, pages) the
-        slot is missing.  False (and no change) when the free list
-        cannot cover them -- after reclaiming unreferenced prefix-index
+        slot is missing -- and, with window pages, whatever window pages
+        [first, pages) it is missing, once those before ``first`` are
+        given back.  False (and no change) when a free list cannot
+        cover them -- after reclaiming unreferenced prefix-index
         entries leaf-first when the cache is on."""
         pages = min(int(pages), self.pps)
         owned = self._slots.setdefault(slot, {})
@@ -414,6 +456,24 @@ class PageAllocator:
             self._reclaim(len(wanted) - len(self._free))
         if len(wanted) > len(self._free):
             return False
+        if self.window_total:
+            window = self._window_slots.setdefault(slot, {})
+            behind = [logical for logical in window if logical < first]
+            wanted_window = [logical for logical in range(first, pages)
+                             if logical not in window]
+            if len(wanted_window) > len(self._window_free) + len(behind):
+                return False
+            if behind or wanted_window:
+                row = self.window_dirty.setdefault(
+                    slot, self._row(slot, window))
+                for logical in behind:
+                    self._window_free.append(window.pop(logical))
+                    row[logical] = 0
+                self.window_released += len(behind)
+                for logical in wanted_window:
+                    phys = self._window_free.pop()
+                    window[logical] = phys
+                    row[logical] = phys
         if wanted:
             row = self.dirty.setdefault(slot, self._row(slot))
             for logical in wanted:
@@ -441,6 +501,10 @@ class PageAllocator:
                 self._refs[phys] = refs
         self._free.extend(sorted(freed, reverse=True))
         self.dirty[slot] = [0] * self.pps
+        window = self._window_slots.pop(slot, {})
+        if window:
+            self._window_free.extend(window.values())
+            self.window_dirty[slot] = [0] * self.pps
         return len(owned)
 
     def reset(self) -> None:
@@ -451,6 +515,9 @@ class PageAllocator:
         self._free = list(range(self.total - 1, 0, -1))
         self._slots.clear()
         self.dirty.clear()
+        self._window_free = list(range(self.window_total - 1, 0, -1))
+        self._window_slots.clear()
+        self.window_dirty.clear()
         self._refs.clear()
         self._prefix.clear()
         self._key_of.clear()
@@ -593,9 +660,10 @@ class PageAllocator:
         live.update(self._key_of)
         return self.total - 1 - len(self._free) - len(live)
 
-    def _row(self, slot: int) -> list[int]:
+    def _row(self, slot: int, owned: dict | None = None) -> list[int]:
         row = [0] * self.pps
-        for logical, phys in self._slots.get(slot, {}).items():
+        owned = self._slots.get(slot, {}) if owned is None else owned
+        for logical, phys in owned.items():
             row[logical] = phys
         return row
 

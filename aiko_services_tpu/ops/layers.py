@@ -94,7 +94,8 @@ def _split_kv(layer):
 def attention_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
                       q_positions: jax.Array,
                       kv_length_mask: jax.Array | None = None,
-                      kv_positions: jax.Array | None = None) -> jax.Array:
+                      kv_positions: jax.Array | None = None,
+                      window: int = 0) -> jax.Array:
     """Causal attention for a prompt chunk.
 
     q: [B, S, H, hd]; k/v: [B, T, K, hd] where K divides H -- grouped
@@ -108,7 +109,9 @@ def attention_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
     ``arange(T)`` (keys ARE the cache row); the speculative verify
     step passes an explicit vector because its key axis concatenates
     the cache row with the draft chunk's per-row offset positions
-    (models/llama.py decode_loop).  float32 softmax.
+    (models/llama.py decode_loop).  ``window`` > 0 bands the causal
+    mask: a query at ``p`` sees keys at ``p - window < j <= p`` (a
+    sliding-window layer).  float32 softmax.
 
     k/v may be int8-quantized cache layers (``{"int8", "scale"}``,
     models/quant.py:quantize_kv): key scales multiply the score logits,
@@ -135,6 +138,9 @@ def attention_prefill(q: jax.Array, k: jax.Array, v: jax.Array,
         key_pos = kv_positions[:, None, None, None, :]      # [B,1,1,1,T]
     causal = key_pos <= \
         q_positions[:, None, None, :, None]        # [B,1,1,S,T]
+    if window:
+        causal = jnp.logical_and(
+            causal, key_pos > q_positions[:, None, None, :, None] - window)
     if kv_length_mask is not None:
         causal = jnp.logical_and(
             causal, kv_length_mask[:, None, None, None, :])

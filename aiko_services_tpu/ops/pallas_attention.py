@@ -45,7 +45,7 @@ _STAT_LANES = 128      # softmax stats replicated across the lane dim
 def _flash_kernel(offset_ref, q_ref, k_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr, *,
                   block_q, block_k, causal, kv_len, rows_per_head,
-                  scale, frontier):
+                  scale, frontier, window=0):
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
     qi = pl.program_id(1)
@@ -67,6 +67,9 @@ def _flash_kernel(offset_ref, q_ref, k_ref, v_ref, o_ref,
     # (``frontier(p)``: the last key position a query at ``p`` sees).
     live = (k_start <= frontier(q_start + block_q - 1)) if causal \
         else True
+    if window:
+        # ... and KV blocks wholly before the first query's band
+        live = jnp.logical_and(live, k_start + block_k > q_start - window + 1)
     # Interior blocks need NO masking: every key position is both
     # in-range and at-or-before every query position.  The mask path
     # (2 iotas + compares + 2 wheres on [bq, bk] f32) costs about as
@@ -77,6 +80,10 @@ def _flash_kernel(offset_ref, q_ref, k_ref, v_ref, o_ref,
     interior = jnp.logical_and(
         in_range,
         (k_start + block_k - 1 <= frontier(q_start)) if causal else True)
+    if window:
+        # (every key inside the last query's band too)
+        interior = jnp.logical_and(
+            interior, k_start >= q_start + block_q - window)
 
     def _online_update(s, p_mask=None):
         m_prev = m_scr[:, :1]                           # [bq, 1]
@@ -127,6 +134,8 @@ def _flash_kernel(offset_ref, q_ref, k_ref, v_ref, o_ref,
         mask = k_pos < kv_len
         if causal:
             mask = jnp.logical_and(mask, k_pos <= frontier(q_pos))
+        if window:
+            mask = jnp.logical_and(mask, k_pos > q_pos - window)
         _online_update(jnp.where(mask, s, _NEG_INF), p_mask=mask)
 
     @pl.when(ki == nk - 1)
@@ -150,17 +159,22 @@ def _frontier(block_length: int):
 
 @functools.partial(jax.jit, static_argnames=(
     "causal", "block_q", "block_k", "interpret", "pack_heads",
-    "block_length"))
+    "block_length", "window"))
 def flash_attention(q, k, v, q_offset=0, *, causal: bool = True,
                     block_q: int = 512, block_k: int = 2048,
                     interpret: bool | None = None,
-                    pack_heads: bool = False, block_length: int = 1):
+                    pack_heads: bool = False, block_length: int = 1,
+                    window: int = 0):
     """Causal flash attention.
 
     ``block_length`` > 1 makes the mask BLOCK-causal at absolute
     positions: the frontier of a query at ``p`` is ``k < (p //
     block_length + 1) * block_length`` (generation by diffusion over
-    blocks, models/sdar.py); 1 is plain causality.
+    blocks, models/sdar.py); 1 is plain causality.  ``window`` > 0
+    (causal only) bands it: a query at ``p`` sees ``p - window < k <=
+    p`` (a sliding-window layer, models/command_a.py), and KV blocks
+    wholly before a query block's band are neither fetched nor
+    computed.
 
     q: [B, S, H, d]; k/v: [B, T, Hkv, d] with H % Hkv == 0 (GQA: each
     query head attends its group's KV head via the grouped grid rows,
@@ -196,6 +210,8 @@ def flash_attention(q, k, v, q_offset=0, *, causal: bool = True,
     differently.
     """
     interpret = interpret_off_chip(interpret)
+    if window and (not causal or block_length != 1):
+        raise ValueError("flash_attention: a window bands a causal mask")
     b, s, h, d = q.shape
     t, h_kv = k.shape[1], k.shape[2]
     groups = h // h_kv
@@ -263,7 +279,8 @@ def flash_attention(q, k, v, q_offset=0, *, causal: bool = True,
     kernel = functools.partial(
         _flash_kernel, block_q=block_q, block_k=block_k,
         causal=causal, kv_len=t, rows_per_head=rows_per_head,
-        scale=scale, frontier=frontier)
+        scale=scale, frontier=frontier,
+        **({"window": int(window)} if window else {}))
 
     def kv_block(bh, qi, ki, offset):
         # Clamp dead KV blocks (fully above the causal frontier) to the
@@ -273,8 +290,13 @@ def flash_attention(q, k, v, q_offset=0, *, causal: bool = True,
         # extent every layer.
         if not causal:
             return (bh, ki, 0)
-        q_last = frontier(offset[0] + (qi * block_q) % rows_per_head
-                          + block_q - 1)
+        q_first = offset[0] + (qi * block_q) % rows_per_head
+        q_last = frontier(q_first + block_q - 1)
+        if window:
+            # ... and dead blocks wholly before the band to the first
+            # live one
+            ki = jnp.maximum(ki, jnp.maximum(q_first - window + 1, 0)
+                             // block_k)
         return (bh, jnp.minimum(ki, q_last // block_k), 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(

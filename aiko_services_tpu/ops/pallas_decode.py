@@ -548,7 +548,7 @@ def paged_grid_steps(lengths, page_tokens: int, pps: int,
 
 def _paged_kernel(layer_ref, lengths_ref, table_ref, q_ref, *refs,
                   page_tokens, pages, pps, n_heads, n_kv, groups,
-                  compute_dtype, quantized, period):
+                  compute_dtype, quantized, period, window=0):
     """One ROW of the paged form (grid ``(B,)``, walked in order).  The
     pools stay in HBM (``ANY`` space); the row loops over its LIVE page
     groups only -- ``pages`` consecutive logical pages a group -- and
@@ -580,6 +580,13 @@ def _paged_kernel(layer_ref, lengths_ref, table_ref, q_ref, *refs,
     length = row_length(b)
     n_groups = pl.cdiv(length, page_tokens * pages)
 
+    def row_start(row):
+        # (the first position a row reads: 0 without a window)
+        return jnp.maximum(row_length(row) + 1 - window, 0)
+
+    def first_group(row):
+        return row_start(row) // (page_tokens * pages) if window else 0
+
     def copies(row, logical, slot, index):
         physical = table_ref[row * pps + logical]
         return [pltpu.make_async_copy(
@@ -590,8 +597,12 @@ def _paged_kernel(layer_ref, lengths_ref, table_ref, q_ref, *refs,
         limit = row_length(row)
         for index in range(pages):
             logical = group * pages + index
+            wanted = logical * page_tokens < limit
+            if window:
+                wanted = jnp.logical_and(
+                    wanted, (logical + 1) * page_tokens > row_start(row))
 
-            @pl.when(logical * page_tokens < limit)
+            @pl.when(wanted)
             def _start(index=index, logical=logical):
                 for copy in copies(row, logical, slot, index):
                     copy.start()
@@ -608,7 +619,7 @@ def _paged_kernel(layer_ref, lengths_ref, table_ref, q_ref, *refs,
     @pl.when(jnp.logical_and(n_groups > 0, state[1] == 0))
     def _prime():
         # (no earlier row had a page: nobody prefetched this group)
-        start_group(b, 0, state[0])
+        start_group(b, first_group(b), state[0])
         state[1] = 1
 
     shared = dict(n_heads=n_heads, n_kv=n_kv, groups=groups,
@@ -633,13 +644,17 @@ def _paged_kernel(layer_ref, lengths_ref, table_ref, q_ref, *refs,
 
             @pl.when(following < rows)
             def _():
-                start_group(following, 0, other)
+                start_group(following, first_group(following), other)
 
+        lo = row_start(b) if window else 0
         for index in range(pages):
             logical = group * pages + index
             t_start = logical * page_tokens
+            live = t_start < length
+            if window:
+                live = jnp.logical_and(live, t_start + page_tokens > lo)
 
-            @pl.when(t_start < length)
+            @pl.when(live)
             def _live(index=index, logical=logical, t_start=t_start):
                 for copy in copies(b, logical, slot, index):
                     copy.wait()
@@ -655,6 +670,8 @@ def _paged_kernel(layer_ref, lengths_ref, table_ref, q_ref, *refs,
                     return _scores_block(q_ref[0], page(0), page(2),
                                          **shared)
                 interior = t_start + page_tokens <= length
+                if window:
+                    interior = jnp.logical_and(interior, t_start >= lo)
 
                 @pl.when(interior)
                 def _whole_page():
@@ -663,8 +680,11 @@ def _paged_kernel(layer_ref, lengths_ref, table_ref, q_ref, *refs,
 
                 @pl.when(jnp.logical_not(interior))
                 def _last_page():
-                    mask = t_start + jax.lax.broadcasted_iota(
-                        jnp.int32, (n_heads, page_tokens), 1) < length
+                    position = t_start + jax.lax.broadcasted_iota(
+                        jnp.int32, (n_heads, page_tokens), 1)
+                    mask = position < length
+                    if window:
+                        mask = jnp.logical_and(mask, position >= lo)
                     _online_update(m_scr, l_scr, acc_scr,
                                    jnp.where(mask, scores(), _NEG_INF),
                                    page(1), page(3), p_mask=mask,
@@ -672,19 +692,20 @@ def _paged_kernel(layer_ref, lengths_ref, table_ref, q_ref, *refs,
         state[0] = other
         return carry
 
-    jax.lax.fori_loop(0, n_groups, fold_group, 0)
+    jax.lax.fori_loop(first_group(b), n_groups, fold_group, 0)
     o_ref[0] = acc_scr[...]
     m_ref[0] = m_scr[...]
     l_ref[0] = l_scr[...]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "qrow_period",
-                                             "pages_per_step"))
+                                             "pages_per_step", "window"))
 def flash_decode_attention_paged(q_pad, k_pool, v_pool, k_scale_t,
                                  v_scale_t, layer, page_table, lengths,
                                  *, interpret: bool | None = None,
                                  qrow_period: int | None = None,
-                                 pages_per_step: int | None = None):
+                                 pages_per_step: int | None = None,
+                                 window: int = 0):
     """:func:`flash_decode_attention` over ONE layer of a PAGED cache
     pool, the page table walked IN-KERNEL (ISSUE 11 tentpole).
 
@@ -710,8 +731,11 @@ def flash_decode_attention_paged(q_pad, k_pool, v_pool, k_scale_t,
     paid.  ``pages_per_step`` follows the
     pools' shapes (:func:`paged_pages_per_step`; the argument is for
     the tests and the probe): whatever it is, pages meet the float32
-    statistics one by one, in order.  Returns the same partial
-    (acc, m, l) stats as the flat kernel.
+    statistics one by one, in order.  ``window`` > 0 (static) reads
+    each row's last ``window - 1`` positions alone (a sliding-window
+    layer's decode: :func:`_paged_kernel`); the pages before them need
+    no entry in the table.  Returns the same partial (acc, m, l) stats
+    as the flat kernel.
     """
     quantized = k_scale_t is not None
     b, h, c = q_pad.shape
@@ -744,7 +768,7 @@ def flash_decode_attention_paged(q_pad, k_pool, v_pool, k_scale_t,
         n_heads=h_pad, n_kv=n_kv,
         groups=max((qrow_period or h) // n_kv, 1),
         compute_dtype=q_pad.dtype, quantized=quantized,
-        period=qrow_period)
+        period=qrow_period, **({"window": int(window)} if window else {}))
 
     # Scale pools ride as [L, P, K, pt] so the kernel's [K, Tb] block
     # matches the flat kernel's layout exactly.
@@ -948,7 +972,8 @@ def flash_decode_append_stacked(q, k_view, v_view, layer, k_new, v_new,
 
 def flash_decode_append_paged(q, k_view, v_view, layer, k_new, v_new,
                               page_table, lengths, *,
-                              interpret: bool | None = None):
+                              interpret: bool | None = None,
+                              window: int = 0):
     """Paged twin of :func:`flash_decode_append_stacked`: the cache
     stays its PHYSICAL page pools ([L, P, pt, C] payload views +
     [L, P, K, pt] scales from :func:`_split_paged`, scan-invariant) and
@@ -957,8 +982,9 @@ def flash_decode_append_paged(q, k_view, v_view, layer, k_new, v_new,
     stacked-cache invariant differs here: the POOL extent never has to
     divide a block size (pages ARE the blocks), but the table must
     cover the logical extent the lengths claim -- the allocator's
-    ``ensure`` contract.  q/k_new/v_new/lengths as in
-    flash_decode_append."""
+    ``ensure`` contract -- from the window's first page on, with a
+    ``window`` (:func:`flash_decode_attention_paged`).  q/k_new/v_new/
+    lengths as in flash_decode_append."""
     b, _, h, d = q.shape
     k_payload, k_scale_t = k_view
     v_payload, v_scale_t = v_view
@@ -971,7 +997,8 @@ def flash_decode_append_paged(q, k_view, v_view, layer, k_new, v_new,
     q_pad, blocks, onehot, scale = _prep_query(q_flat, h, kv, d)
     acc, m, l = flash_decode_attention_paged(
         q_pad, k_payload, v_payload, k_scale_t, v_scale_t, layer,
-        page_table, lengths, interpret=interpret)
+        page_table, lengths, interpret=interpret,
+        **({"window": int(window)} if window else {}))
     out = _combine_self(acc, m, l, q_flat, k_new, v_new, blocks,
                         onehot, scale, kv, d)
     return out.reshape(q.shape).astype(q.dtype)
