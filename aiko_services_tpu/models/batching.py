@@ -214,7 +214,14 @@ def model_family(config):
     ``ADMISSION_NARROW_CHUNKS`` (models/llama.py: its admission takes a
     chunk of any whole number of pages, so a chunk is padded only to
     the least of ``admission_widths`` that holds its tokens, not to
-    ``prefill_chunk``), leave out
+    ``prefill_chunk``), ``ADMISSION_PICKS_FIRST`` (models/llama.py:
+    a single-slot chunk goes up as ONE packed int32 array --
+    ``pack_admission(tokens, width, slot, start, final, temperature)``
+    -- to ONE program, ``admit_packed(params, config, packed, cache,
+    firsts, key, top_k=)``, which runs the head at the chunk's last
+    real position alone, chooses a prompt's first token by the rule of
+    ``_sample`` and writes it into the first-token vector: no eager
+    scalar, slice, sample or ``_set_first`` around it), leave out
     ``prefill_into_slots`` (admission stays one slot a program) and
     return block statistics after ``decode_loop``'s twelve results,
     which its ``loop_stats`` turns into what the LLM element observes;
@@ -538,8 +545,9 @@ class ContinuousBatcher:
             (max_slots, self._pages.pps), dtype=np.int32)
         self._window_rows = None if not self._window_pages \
             else np.zeros_like(self._page_rows)
-        # Device calls (programs and explicit uploads) made by the
-        # fold-in and the page-table sync, counted where they are made.
+        # Device calls (programs and explicit uploads) made by
+        # admission, the fold-in and the page-table sync, counted where
+        # they are made.
         self._launches = 0
         # Single-slot chunks that did not start their prompt (a family
         # with a recurrent state was handed one by the chunk before).
@@ -700,18 +708,34 @@ class ContinuousBatcher:
                 self._prefilling.append(slot)   # pool pressure: wait
                 continue
             self._sync_page_table()
-            padded = np.zeros((1, width), dtype=np.int32)
-            padded[0, :len(chunk_tokens)] = chunk_tokens
-            logits, self.cache = self._launch(
-                "prefill_into_slot", self._family.prefill_into_slot,
-                self.params, self.config, jnp.asarray(padded),
-                self.cache, jnp.int32(slot), jnp.int32(start),
-                *self._admission_last(len(chunk_tokens)),
-                tokens=len(chunk_tokens), width=width)
+            logits = first = None
+            if getattr(self._family, "ADMISSION_PICKS_FIRST", False):
+                final = start + len(chunk_tokens) \
+                    >= self._admission_length(request)
+                packed = jax.device_put(self._family.pack_admission(
+                    chunk_tokens, width, slot, start, final,
+                    request.temperature))
+                self.cache, self._firsts, first, self._key = self._launch(
+                    "prefill_into_slot", self._family.admit_packed,
+                    self.params, self.config, packed, self.cache,
+                    self._firsts, self._key, top_k=self.sample_top_k,
+                    tokens=len(chunk_tokens), width=width)
+                self._launches += 2     # the upload, the program
+            else:
+                padded = np.zeros((1, width), dtype=np.int32)
+                padded[0, :len(chunk_tokens)] = chunk_tokens
+                last = self._admission_last(len(chunk_tokens))
+                logits, self.cache = self._launch(
+                    "prefill_into_slot", self._family.prefill_into_slot,
+                    self.params, self.config, jnp.asarray(padded),
+                    self.cache, jnp.int32(slot), jnp.int32(start), *last,
+                    tokens=len(chunk_tokens), width=width)
+                # the tokens, slot, start (and last) up; the program
+                self._launches += 4 + len(last)
             self._admission_fills.append(
                 100.0 * len(chunk_tokens) / width)
             self._admission_advance(slot, request, start,
-                                    len(chunk_tokens), logits)
+                                    len(chunk_tokens), logits, first)
             chunks += 1
             self._state_carried += start > 0
         return chunks
@@ -766,9 +790,11 @@ class ContinuousBatcher:
                                    dtype=np.int32)),
             tokens=sum(len(chunk[3]) for chunk in chunks[:n]),
             width=width)
+        self._launches += 4     # tokens, slots and starts up; the program
         for i, (slot, request, start, chunk_tokens) in enumerate(chunks[:n]):
             self._admission_fills.append(
                 100.0 * len(chunk_tokens) / width)
+            self._launches += 1                 # the row's slice
             self._admission_advance(slot, request, start,
                                     len(chunk_tokens), logits[i:i + 1])
         return n
@@ -804,25 +830,27 @@ class ContinuousBatcher:
                     if width >= tokens)
 
     def warm_admission(self) -> None:
-        """Build the admission program of every width in
+        """Build the served admission program of every width in
         ``admission_widths`` before any request, as serving calls it
-        (and the slice of the last position's logits a first token is
-        sampled from): a width that traffic first needs mid-serving
-        would be compiled inside a request's latency.  Each width runs
-        once on pad tokens at slot 0, position 0 -- a paged slot with
-        no pages writes the trash page, a dense row positions written
-        again before any length admits them.  Nothing to build for a family
-        that takes ``prefill_chunk``-wide chunks alone: its first
-        chunk builds its one width."""
-        if len(self.admission_widths) == 1 \
+        (``ADMISSION_PICKS_FIRST``), and nothing else: a width that
+        traffic first needs mid-serving would be compiled inside a
+        request's latency.  Each width runs once on a pad token at slot
+        0, position 0, as a chunk that does not end its prompt (no
+        head, no first token) -- a paged slot with no pages writes the
+        trash page, a dense row positions written again before any
+        length admits them.  Nothing to build for a family whose
+        admission takes other programs: the Llama family alone takes
+        chunks of several widths, and a family's first chunk builds its
+        one width."""
+        if not getattr(self._family, "ADMISSION_PICKS_FIRST", False) \
                 or any(request is not None for request in self.slots):
             return
         for width in self.admission_widths:
-            logits, self.cache = self._family.prefill_into_slot(
-                self.params, self.config,
-                jnp.asarray(np.zeros((1, width), dtype=np.int32)),
-                self.cache, jnp.int32(0), jnp.int32(0))
-            self._sample(logits[:, width - 1, :], 0.0)
+            self.cache, *_ = self._family.admit_packed(
+                self.params, self.config, jax.device_put(
+                    self._family.pack_admission((0,), width, 0, 0)),
+                self.cache, self._firsts, self._key,
+                top_k=self.sample_top_k)
 
     def _admission_length(self, request: Request) -> int:
         """How much of the prompt admission writes: all of it, or its
@@ -834,13 +862,15 @@ class ContinuousBatcher:
         return len(request.prompt_tokens)
 
     def _admission_advance(self, slot: int, request: Request,
-                           start: int, chunk_len: int, logits):
-        """Account one written chunk; on the FINAL chunk, sample the
-        first generated token from the last real prompt position's
-        logits ([1, S, vocab] row) and hand the slot to decode --
-        without fetching on the device loop (the device scalar folds
-        into the next block dispatch and emits when that block
-        retires)."""
+                           start: int, chunk_len: int, logits,
+                           first=None):
+        """Account one written chunk; on the FINAL chunk, take the
+        first generated token -- ``first`` ``[1]`` where the admission
+        program chose it and wrote it into the first-token vector,
+        else sampled here from the last real prompt position's logits
+        ([1, S, vocab] row) -- and hand the slot to decode without
+        fetching on the device loop (the device scalar folds into the
+        next block dispatch and emits when that block retires)."""
         prompt = request.prompt_tokens
         self.prefill_tokens += start + chunk_len - request.prefill_pos
         request.prefill_pos = start + chunk_len
@@ -864,10 +894,14 @@ class ContinuousBatcher:
             self.decoding[slot] = True
             self._pending_first[slot] = request
             return
-        # (a family that computes the sampled position alone hands
-        # back one position)
-        last = min(len(prompt) - start - 1, logits.shape[1] - 1)
-        first = self._sample(logits[:, last, :], request.temperature)
+        picked = first is not None
+        if not picked:
+            # (a family that computes the sampled position alone hands
+            # back one position)
+            last = min(len(prompt) - start - 1, logits.shape[1] - 1)
+            first = self._sample(logits[:, last, :], request.temperature)
+            # the slice; the argmax, or the key's split and the draw
+            self._launches += 3 if request.temperature > 0 else 2
         self.lengths[slot] = len(prompt)
         self._lengths_upper[slot] = len(prompt)
         self.decoding[slot] = True
@@ -875,8 +909,10 @@ class ContinuousBatcher:
             # No host copy here: the token stays on the device, in the
             # vector the next dispatch folds in and its block's retire
             # fetches whole.
-            self._firsts = _set_first(self._firsts,
-                                      self._slot_index[slot], first)
+            if not picked:
+                self._firsts = _set_first(self._firsts,
+                                          self._slot_index[slot], first)
+                self._launches += 1
             self._pending_first[slot] = request
         else:
             first_token = int(jax.device_get(first)[0])
@@ -917,10 +953,11 @@ class ContinuousBatcher:
         self._admit()
         if traced:
             self._phase("admit")
-        carried = self._state_carried
+        carried, launched = self._state_carried, self._launches
         chunks = self._prefill_tick()
         if traced:
-            info = {"chunks": chunks}
+            info = {"chunks": chunks,
+                    "launches": self._launches - launched}
             if getattr(self._family, "ADMISSION_CARRIES_STATE", False):
                 info["state_carried"] = self._state_carried - carried
             self._phase("prefill", info)

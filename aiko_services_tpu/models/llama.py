@@ -18,6 +18,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops import decode_backend, matmul_backend
 from ..ops.layers import (rms_norm, rope_frequencies, apply_rope,
@@ -31,8 +32,8 @@ from .quant import dequantize_kv, is_quantized, quantize_kv
 __all__ = ["LlamaConfig", "init_params", "partition_specs",
            "cache_specs", "init_cache", "cache_array", "cache_extent",
            "prefill", "prefill_with_aux", "prefill_into_slot",
-           "prefill_into_slots", "decode_step",
-           "decode_loop", "greedy_sample", "select_tokens",
+           "prefill_into_slots", "pack_admission", "admit_packed",
+           "decode_step", "decode_loop", "greedy_sample", "select_tokens",
            "resolve_decode_backend", "paged_decode_pages"]
 
 
@@ -667,9 +668,12 @@ def _scatter_chunks(cache: dict, k_chunks, v_chunks, slots,
 
 def _admit_chunks(params: dict, config: LlamaConfig, tokens: jax.Array,
                   cache: dict, slots, starts) -> tuple[jax.Array, dict]:
-    """Admission body shared by prefill_into_slot (N=1) and
-    prefill_into_slots: forward chunks ``tokens`` [N, S], row i at
-    cache offset ``starts[i]`` of batch row ``slots[i]``.
+    """Admission body shared by prefill_into_slot (N=1, served or with
+    every row's logits) and prefill_into_slots: forward chunks
+    ``tokens`` [N, S], row i at cache offset ``starts[i]`` of batch row
+    ``slots[i]``.  Returns the last layer's hidden states ``[N, S, D]``
+    -- the callers choose which rows reach the head (:func:`_finish`)
+    -- and the cache.
 
     The cache NEVER enters or leaves the layer scan as ``xs``/``ys``
     (the decode discipline, see _decode_step_impl): the scan carries
@@ -741,27 +745,82 @@ def _admit_chunks(params: dict, config: LlamaConfig, tokens: jax.Array,
     (hidden, _), (k_chunks, v_chunks) = jax.lax.scan(
         layer_step, (params["embed"][tokens], jnp.float32(0.0)),
         (params["layers"], jnp.arange(c.n_layers)))
-    return _finish(params, c, hidden), \
-        _scatter_chunks(cache, k_chunks, v_chunks, slots, starts)
+    return hidden, _scatter_chunks(cache, k_chunks, v_chunks, slots, starts)
 
 
-@partial(jax.jit, static_argnames=("config",), donate_argnames=("cache",))
+# A single-slot admission chunk reaches the device as ONE int32 row
+# (:func:`pack_admission`): its tokens padded to the program's width,
+# then this many columns -- the slot (also its index into the
+# first-token vector), the chunk's cache offset, its last real
+# position, whether it ends its prompt, and the request's temperature
+# as its bits.
+_ADMIT_COLUMNS = 5
+
+
+def pack_admission(tokens, width: int, slot: int, start: int,
+                   final: bool = False,
+                   temperature: float = 0.0) -> np.ndarray:
+    """One admission chunk as the host array :func:`admit_packed`
+    takes: ``[1, width + 5]`` int32, the chunk's tokens padded to
+    ``width`` and then the ``_ADMIT_COLUMNS`` scalars."""
+    packed = np.zeros((1, width + _ADMIT_COLUMNS), dtype=np.int32)
+    packed[0, :len(tokens)] = tokens
+    packed[0, width:] = (slot, start, len(tokens) - 1, final,
+                         np.float32(temperature).view(np.int32))
+    return packed
+
+
+def _first_token(logits, temperature, key, top_k: int):
+    """The batcher's rule for a first token (``ContinuousBatcher.
+    _sample``) on the device: the argmax of ``logits`` ``[1, vocab]``
+    at temperature 0; otherwise a draw from a split of ``key`` --
+    :func:`select_tokens` under ``top_k``, :func:`temperature_sample`
+    without.  Returns (token ``[1]``, the key the batcher goes on
+    with)."""
+    def greedy(key):
+        return greedy_sample(logits), key
+
+    def sample(key):
+        key, sub = jax.random.split(key)
+        if top_k:
+            return select_tokens(sub, logits, temperature[None],
+                                 top_k=top_k), key
+        return temperature_sample(sub, logits,
+                                  temperature.astype(logits.dtype)), key
+
+    return jax.lax.cond(temperature > 0, sample, greedy, key)
+
+
+@partial(jax.jit, static_argnames=("config", "pick", "top_k"),
+         donate_argnames=("cache",))
 def _prefill_into_slot_jit(params: dict, config: LlamaConfig,
-                           tokens: jax.Array, cache: dict,
-                           slot: jax.Array,
-                           start: jax.Array) -> tuple[jax.Array, dict]:
+                           packed: jax.Array, cache: dict,
+                           firsts: jax.Array | None = None,
+                           key: jax.Array | None = None, *,
+                           pick: bool = False, top_k: int = 0):
     """Process one prompt chunk for ONE sequence, writing its KV directly
     into batch row ``slot`` of the BATCHED cache (no scratch cache; the
     donated cache is written once, after the layer scan, and only where
     the chunk lands -- see :func:`_admit_chunks`; the continuous
     batcher's admission path).
 
-    tokens: [1, S] chunk (right-padding allowed; pad positions are
-    overwritten by decode before the length mask ever admits them);
-    slot: scalar batch index; start: scalar cache offset of the chunk.
-    Queries attend the slot's whole cache row, so chunk N sees chunks
-    0..N-1 written by earlier calls.  Returns (logits [1, S, vocab],
-    cache) with the cache donated for in-place update.
+    packed: ``[1, S + 5]`` (:func:`pack_admission`): the chunk's tokens
+    (right-padding allowed; pad positions are overwritten by decode
+    before the length mask ever admits them), its slot, its cache
+    offset ``start``, its last real position, whether it is its
+    prompt's last and the request's temperature.  Queries attend the
+    slot's whole cache row, so chunk N sees chunks 0..N-1 written by
+    earlier calls.  The cache is donated for in-place update.
+
+    Without ``pick``: (logits [1, S, vocab], cache), every row through
+    the head.  With ``pick`` (the served form): the head reads the
+    hidden row at the last real position alone, and only on a prompt's
+    last chunk, which chooses the first token by :func:`_first_token`
+    and writes it into ``firsts`` ``[max_slots]`` at the slot ->
+    (cache, firsts, token [1], key).  A chunk that does not end its
+    prompt runs no head and leaves ``firsts`` and ``key`` as they were.
+    (``firsts`` is not donated: a decode block still in flight holds
+    the vector it folded its joiners from, to fetch at its retire.)
 
     A PAGED cache (models/paged.py) is read and written through its
     page table: the chunk start must be page-aligned and S a whole
@@ -770,19 +829,54 @@ def _prefill_into_slot_jit(params: dict, config: LlamaConfig,
     covered page and the attention row is the slot's gathered page
     view with the chunk laid into it.
     """
-    return _admit_chunks(params, config, tokens, cache,
-                         jnp.reshape(slot, (1,)), jnp.reshape(start, (1,)))
+    width = packed.shape[1] - _ADMIT_COLUMNS
+    slot, start, last, final, bits = (packed[0, width + column]
+                                      for column in range(_ADMIT_COLUMNS))
+    hidden, cache = _admit_chunks(params, config, packed[:, :width], cache,
+                                  slot[None], start[None])
+    if not pick:
+        return _finish(params, config, hidden), cache
+    row = jax.lax.dynamic_slice_in_dim(hidden, last, 1, axis=1)
+    temperature = jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+    def choose(key):
+        return _first_token(_finish(params, config, row)[:, 0, :],
+                            temperature, key, top_k)
+
+    def skip(key):
+        return jnp.zeros((1,), jnp.int32), key
+
+    token, key = jax.lax.cond(final != 0, choose, skip, key)
+    firsts = firsts.at[slot].set(
+        jnp.where(final != 0, token[0], firsts[slot]))
+    return cache, firsts, token, key
 
 
 def prefill_into_slot(params: dict, config: LlamaConfig,
                       tokens: jax.Array, cache: dict, slot: jax.Array,
                       start: jax.Array) -> tuple[jax.Array, dict]:
-    """Single-slot admission (see _prefill_into_slot_jit); the matmul
-    kernel resolves eagerly on the concrete tree's sharding, as in
-    :func:`prefill`."""
+    """Single-slot admission with every row's logits: tokens [1, S],
+    slot and start scalars -> (logits [1, S, vocab], cache); the
+    served program's body with the whole chunk through the head (see
+    _prefill_into_slot_jit).  The matmul kernel resolves eagerly on the
+    concrete tree's sharding, as in :func:`prefill`."""
+    width = tokens.shape[1]
+    packed = jnp.concatenate(
+        [jnp.asarray(tokens, jnp.int32),
+         jnp.asarray([[slot, start, width - 1, 0, 0]], jnp.int32)], axis=1)
     return _prefill_into_slot_jit(
-        params, _matmul_safe_config(config, params), tokens, cache,
-        slot, start)
+        params, _matmul_safe_config(config, params), packed, cache)
+
+
+def admit_packed(params: dict, config: LlamaConfig, packed: jax.Array,
+                 cache: dict, firsts: jax.Array, key: jax.Array, *,
+                 top_k: int = 0):
+    """The served single-slot admission (``ADMISSION_PICKS_FIRST``):
+    one packed chunk (:func:`pack_admission`, uploaded once) -> (cache,
+    firsts, token [1], key); see _prefill_into_slot_jit with ``pick``."""
+    return _prefill_into_slot_jit(
+        params, _matmul_safe_config(config, params), packed, cache,
+        firsts, key, pick=True, top_k=top_k)
 
 
 
@@ -808,7 +902,9 @@ def _prefill_into_slots_jit(params: dict, config: LlamaConfig,
     if config.attention == "flash":
         raise ValueError("prefill_into_slots is dense-only; "
                          "flash admission uses prefill_into_slot")
-    return _admit_chunks(params, config, tokens, cache, slots, starts)
+    hidden, cache = _admit_chunks(params, config, tokens, cache, slots,
+                                  starts)
+    return _finish(params, config, hidden), cache
 
 
 def prefill_into_slots(params: dict, config: LlamaConfig,
@@ -828,6 +924,11 @@ def prefill_into_slots(params: dict, config: LlamaConfig,
 # chunk, and pad rows only write positions past the length mask.  (An
 # MoE layer's capacity buffer follows the program's rows.)
 ADMISSION_NARROW_CHUNKS = True
+
+# The batcher hands a single-slot chunk over as ONE packed upload
+# (:func:`pack_admission`) to ONE program that also chooses the first
+# token and writes it into the first-token vector (:func:`admit_packed`).
+ADMISSION_PICKS_FIRST = True
 
 
 

@@ -167,8 +167,11 @@ def test_admission_widths(tiny_f32, page, chunk, max_seq, widths):
 
 
 def test_every_width_is_built_before_traffic_and_none_after(tiny_f32):
-    """``warm_admission`` builds each width once, and serving prompts
-    of every length after it builds no admission program."""
+    """``warm_admission`` builds the served program (the one that
+    picks the first token) once a width and nothing else, and serving
+    prompts of every length after it builds no admission program --
+    none of the served program's, and no eager slice, sample or
+    first-token write around it."""
     config, params = tiny_f32
     config = dataclasses.replace(config, max_seq=200)  # shapes of its own
     builds = []
@@ -185,12 +188,18 @@ def test_every_width_is_built_before_traffic_and_none_after(tiny_f32):
             params, config, max_slots=1, max_seq=MAX_SEQ,
             prefill_chunk=CHUNK, kv_page_tokens=PAGE,
             decode_block_tokens=8)
+        cached = llama._prefill_into_slot_jit._cache_size
+        before, seen = cached(), len(builds)
         batcher.warm_admission()
         admission = [name for name in builds
                      if "prefill_into_slot" in name]
+        assert builds[seen:] == admission
         assert len(admission) == len(batcher.admission_widths) \
             <= math.log2(CHUNK / PAGE) + 1
+        warmed = cached()
+        assert warmed - before == len(admission)
         assert batcher._pages.free_pages == batcher._pages.total - 1
+        seen = len(builds)
         for length in (1, 7, 9, 20, 31, 33, 40):
             batcher.submit(Request(request_id=str(length),
                                    prompt_tokens=_prompt(length),
@@ -198,6 +207,11 @@ def test_every_width_is_built_before_traffic_and_none_after(tiny_f32):
         assert batcher.run_until_drained(max_steps=500) < 500
         assert [name for name in builds
                 if "prefill_into_slot" in name] == admission
+        # (the programs warm-up built are all that traffic called)
+        assert cached() == warmed
+        assert not [name for name in builds[seen:]
+                    if any(eager in name for eager in
+                           ("_set_first", "dynamic_slice", "argmax"))]
     finally:
         jax.monitoring.unregister_event_duration_listener(listen)
 

@@ -14,6 +14,7 @@ speculation contract is pinned in float32 where the math is exact.
 """
 
 import dataclasses
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -611,6 +612,7 @@ def _scans(jaxpr, length):
 
 
 @pytest.mark.parametrize("program", ["prefill_into_slot",
+                                     "prefill_into_slot_served",
                                      "prefill_into_slots"])
 @pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
 def test_admission_keeps_the_cache_out_of_the_layer_scan(tiny_f32, paged,
@@ -629,14 +631,18 @@ def test_admission_keeps_the_cache_out_of_the_layer_scan(tiny_f32, paged,
                              total_pages=8193) if paged \
         else llama.init_cache(config, 1031, extent)
     layer_shape = llama.cache_array(cache).shape[1:]
-    if program == "prefill_into_slot":
-        args = (jnp.zeros((1, chunk), jnp.int32), cache, jnp.int32(1),
-                jnp.int32(16))
+    jitted = getattr(llama, f"_{program.removesuffix('_served')}_jit")
+    # (the served form: the head at one position, a token chosen)
+    served = {"pick": True} if program.endswith("_served") else {}
+    if program != "prefill_into_slots":
+        args = (jnp.asarray(llama.pack_admission([3] * 5, chunk, 1, 16,
+                                                 final=True)), cache)
+        if program.endswith("_served"):
+            args += (jnp.zeros(8, jnp.int32), jax.random.PRNGKey(0))
     else:
         args = (jnp.zeros((4, chunk), jnp.int32), cache,
                 jnp.asarray([1, 2, 3, 1]), jnp.asarray([16, 0, 8, 16]))
-    jitted = getattr(llama, f"_{program}_jit")
-    closed = jax.make_jaxpr(jitted, static_argnums=1)(
+    closed = jax.make_jaxpr(partial(jitted, **served), static_argnums=1)(
         params, config, *args)
     (scan,) = list(_scans(closed.jaxpr, config.n_layers))
     consts, carry = scan.params["num_consts"], scan.params["num_carry"]
@@ -646,7 +652,7 @@ def test_admission_keeps_the_cache_out_of_the_layer_scan(tiny_f32, paged,
     assert layer_shape not in stacked, stacked
     assert tuple(llama.cache_array(cache).shape) in \
         [v.aval.shape for v in scan.invars[:consts + carry]]
-    stats = jitted.lower(params, config, *args).compile() \
+    stats = jitted.lower(params, config, *args, **served).compile() \
         .memory_analysis()
     if stats is not None:
         side_bytes = llama.cache_array(cache).nbytes
