@@ -347,13 +347,14 @@ ELEMENT_PARAMETERS: dict[tuple[str, str], dict[str, ParamSpec]] = {
             "model family the element builds from ``widths`` "
             "(models/families.py); absent = the ``model`` presets",
             choices=("llama", "deepseek_v3", "olmo_hybrid", "sdar_moe",
-                     "cohere2_moe")),
+                     "cohere2_moe", "falcon_h1")),
         "widths": ParamSpec(
             "published config.json keys of the family (a key it lacks "
             "is refused; one left out keeps the family's default; "
             "numbers, but for the list layer_types of olmo_hybrid and "
-            "cohere2_moe and olmo_hybrid's boolean "
-            "linear_allow_neg_eigval)",
+            "cohere2_moe, olmo_hybrid's boolean "
+            "linear_allow_neg_eigval and falcon_h1's lists of numbers "
+            "mlp_multipliers and ssm_multipliers)",
             kind="json"),
         "block_length": ParamSpec(
             "positions a block of the sdar_moe family's generation by "

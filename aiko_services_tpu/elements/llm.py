@@ -39,7 +39,8 @@ import time
 
 import jax
 
-from ..models import command_a, deepseek, llama, olmo_hybrid, sdar
+from ..models import (command_a, deepseek, falcon_h1, llama, olmo_hybrid,
+                      sdar)
 from ..models.batching import ContinuousBatcher, Request
 from ..models.checkpoint import maybe_restore as _restore
 from ..models.families import FAMILY_PARAMETERS, family_spec_error
@@ -228,11 +229,13 @@ class LLM(PipelineElement):
     MODEL FAMILIES: ``model`` picks a preset of the Llama family
     (``tiny`` | ``tiny-moe`` | ``llama3-1b`` | ``llama3-8b``);
     ``family`` (``llama`` | ``deepseek_v3`` | ``olmo_hybrid`` |
-    ``sdar_moe`` | ``cohere2_moe``) +
+    ``sdar_moe`` | ``cohere2_moe`` | ``falcon_h1``) +
     ``widths`` (the family's published ``config.json`` keys -> numbers;
     ``olmo_hybrid`` and ``cohere2_moe`` also take the list
     ``layer_types``, ``olmo_hybrid`` the boolean
-    ``linear_allow_neg_eigval``: models/families.py) build any config
+    ``linear_allow_neg_eigval``, ``falcon_h1`` the lists of numbers
+    ``mlp_multipliers`` and ``ssm_multipliers``: models/families.py)
+    build any config
     of a family -- a key the family lacks is refused, one left out
     keeps its default.  ``deepseek_v3``
     (models/deepseek.py) is latent attention over a paged latent pool,
@@ -281,7 +284,12 @@ class LLM(PipelineElement):
     row's window pages) and the two ``llm_moe_*`` histograms over the
     experts it holds, the recorder's ``llm_tick:demux`` info
     gets ``window_pages_released`` and its ``build:llm_cache`` info
-    ``window_pages_per_slot`` beside each pool's bytes.  An int8 model
+    ``window_pages_per_slot`` beside each pool's bytes.
+    ``falcon_h1`` (models/falcon_h1.py) runs in every layer attention
+    over K/V pages and a Mamba-2 mixer over a per-slot float32 state
+    side by side, under the published muP multipliers; it refuses the
+    same parameters by name and reports like ``olmo_hybrid``
+    (``llm_state_traffic_share``, ``build:llm_cache``).  An int8 model
     of the Llama family also gets
     ``build:llm_unembed``: the fused unembed's blocks at the decode
     width and ``padded_weight_bytes``, what each of its calls copies
@@ -532,6 +540,10 @@ class LLM(PipelineElement):
             command_a, command_a.CommandAConfig, "kernels",
             {"auto": "auto", "paged-kernel": "on", "reference": "off"},
             "its experts or its window pools"),
+        "falcon_h1": (
+            falcon_h1, falcon_h1.FalconH1Config, "kernels",
+            {"auto": "auto", "paged-kernel": "on", "reference": "off"},
+            "its state pool"),
     }
 
     def _ensure_one_chip_family(self, settings: dict, family: str):
@@ -544,7 +556,9 @@ class LLM(PipelineElement):
         over blocks, softmax-routed experts; models/sdar.py, with its
         own ``block_length`` and ``denoising_steps``) or ``cohere2_moe``
         (window and global layers, a chip's share of the experts;
-        models/command_a.py, with its own ``expert_shards``).  What the
+        models/command_a.py, with its own ``expert_shards``) or
+        ``falcon_h1`` (attention and a Mamba-2 mixer in every layer;
+        models/falcon_h1.py).  What the
         family cannot serve was refused above, by the parameter's
         name."""
         module, config_class, kernel_field, kernel_values, unsharded = \

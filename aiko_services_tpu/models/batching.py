@@ -65,7 +65,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import command_a, deepseek, llama, olmo_hybrid, sdar
+from . import command_a, deepseek, falcon_h1, llama, olmo_hybrid, sdar
 from .paged import PageAllocator, init_paged_cache, pages_per_slot
 from .quant import draft_params
 from ..utils.misc import next_power_of_two
@@ -249,6 +249,8 @@ def model_family(config):
         return command_a
     if isinstance(config, olmo_hybrid.OlmoHybridConfig):
         return olmo_hybrid
+    if isinstance(config, falcon_h1.FalconH1Config):
+        return falcon_h1
     if isinstance(config, sdar.SdarConfig):
         return sdar
     return llama
@@ -292,7 +294,7 @@ class ContinuousBatcher:
     def __init__(self, params,
                  config: llama.LlamaConfig | deepseek.DeepseekConfig
                  | olmo_hybrid.OlmoHybridConfig | sdar.SdarConfig
-                 | command_a.CommandAConfig,
+                 | command_a.CommandAConfig | falcon_h1.FalconH1Config,
                  max_slots: int = 8, max_seq: int | None = None,
                  prefill_chunk: int = 512, rng_seed: int = 0,
                  inflight: int = 2,

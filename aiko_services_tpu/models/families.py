@@ -3,7 +3,8 @@
 Imports nothing of jax, so the create-time parameter check
 (``analysis/params.py``) and the runtime (``elements/llm.py``,
 ``models/llama.py``, ``models/deepseek.py``, ``models/olmo_hybrid.py``,
-``models/sdar.py``, ``models/command_a.py``) read ONE table.
+``models/sdar.py``, ``models/command_a.py``, ``models/falcon_h1.py``)
+read ONE table.
 
 An LLM element names a family and hands it widths::
 
@@ -14,10 +15,12 @@ An LLM element names a family and hands it widths::
 
 A key the family lacks is an error; a key left out keeps the family's
 default (``LlamaConfig`` / ``DeepseekConfig`` / ``OlmoHybridConfig`` /
-``SdarConfig`` / ``CommandAConfig``).
+``SdarConfig`` / ``CommandAConfig`` / ``FalconH1Config``).
 Widths are numbers, but for the keys of ``LIST_WIDTHS`` (a list of
 names from the family's set: the ``olmo_hybrid`` and ``cohere2_moe``
-families' per-layer ``layer_types``) and ``BOOL_WIDTHS`` (a boolean).
+families' per-layer ``layer_types``), ``NUMBER_LIST_WIDTHS`` (a list of
+so many numbers: the ``falcon_h1`` family's ``mlp_multipliers`` and
+``ssm_multipliers``) and ``BOOL_WIDTHS`` (a boolean).
 A family may also take parameters of its own beside its widths
 (``FAMILY_PARAMETERS``: the ``sdar_moe`` family's ``block_length`` and
 ``denoising_steps``; the ``cohere2_moe`` family's ``expert_shards``,
@@ -90,6 +93,26 @@ FAMILY_WIDTHS: dict[str, dict[str, str]] = {
         "sliding_window": "window", "layer_types": "layer_types",
         "rope_theta": "rope_theta", "layer_norm_eps": "norm_eps",
         "logit_scale": "logit_scale"},
+    "falcon_h1": {
+        "vocab_size": "vocab_size", "hidden_size": "dim",
+        "num_hidden_layers": "n_layers",
+        "num_attention_heads": "n_heads",
+        "num_key_value_heads": "n_kv_heads", "head_dim": "head_dim",
+        "intermediate_size": "hidden_dim",
+        "mamba_d_ssm": "ssm_dim", "mamba_n_heads": "ssm_heads",
+        "mamba_d_head": "ssm_head_dim", "mamba_d_state": "ssm_state",
+        "mamba_n_groups": "ssm_groups", "mamba_d_conv": "conv_kernel",
+        "mamba_chunk_size": "ssm_chunk",
+        "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
+        "embedding_multiplier": "embedding_multiplier",
+        "lm_head_multiplier": "lm_head_multiplier",
+        "key_multiplier": "key_multiplier",
+        "attention_in_multiplier": "attention_in_multiplier",
+        "attention_out_multiplier": "attention_out_multiplier",
+        "ssm_in_multiplier": "ssm_in_multiplier",
+        "ssm_out_multiplier": "ssm_out_multiplier",
+        "mlp_multipliers": "mlp_multipliers",
+        "ssm_multipliers": "ssm_multipliers"},
 }
 
 #: family -> {element parameter: config dataclass field} for what a
@@ -108,6 +131,11 @@ LIST_WIDTHS: dict[str, dict[str, tuple]] = {
     "cohere2_moe": {"layer_types": ("sliding_attention",
                                     "full_attention")}}
 BOOL_WIDTHS = ("linear_allow_neg_eigval",)
+#: family -> {width key: how many numbers its list holds} (the
+#: ``falcon_h1`` family's muP multipliers: the MLP's gate and down
+#: projections, the mixer's five input segments)
+NUMBER_LIST_WIDTHS: dict[str, dict[str, int]] = {
+    "falcon_h1": {"mlp_multipliers": 2, "ssm_multipliers": 5}}
 
 #: family -> {parameter: why it is refused} for every parameter whose
 #: mere presence with a non-default value the family cannot serve
@@ -162,10 +190,24 @@ FAMILY_REFUSES: dict[str, dict[str, str]] = {
                         "not bit-equal under grouped expert matmuls",
         "model": "a family is built from widths, not from a preset",
     },
+    "falcon_h1": {
+        "quantize": "the family serves bfloat16 weights, bfloat16 K/V "
+                    "pages and a float32 recurrent state (no int8)",
+        "speculative": "a rejected draft would have to roll the "
+                       "recurrent state back; no snapshot of it is kept",
+        "spec_tokens": "speculation is not served",
+        "spec_window": "speculation is not served",
+        "prefix_cache": "a shared prefix is a snapshot of the recurrent "
+                        "state as well as pages; none is kept",
+        "model": "a family is built from widths, not from a preset",
+    },
 }
 
 _FLOAT_FIELDS = ("rope_theta", "norm_eps", "routed_scaling_factor",
-                 "logit_scale")
+                 "logit_scale", "embedding_multiplier",
+                 "lm_head_multiplier", "key_multiplier",
+                 "attention_in_multiplier", "attention_out_multiplier",
+                 "ssm_in_multiplier", "ssm_out_multiplier")
 
 
 def config_fields(family: str, widths: dict) -> dict:
@@ -187,6 +229,8 @@ def _field_value(family: str, key: str, field: str, value):
         return tuple(str(item) for item in value)
     if key in BOOL_WIDTHS:
         return bool(value)
+    if key in NUMBER_LIST_WIDTHS.get(family, {}):
+        return tuple(float(item) for item in value)
     return float(value) if field in _FLOAT_FIELDS else int(value)
 
 
@@ -201,6 +245,14 @@ def _width_error(family: str, key: str, value) -> str | None:
     if key in BOOL_WIDTHS:
         return None if isinstance(value, bool) \
             else f"widths: {key} must be true or false"
+    length = NUMBER_LIST_WIDTHS.get(family, {}).get(key)
+    if length is not None:
+        if not isinstance(value, (list, tuple)) or len(value) != length \
+                or any(isinstance(item, bool)
+                       or not isinstance(item, (int, float))
+                       for item in value):
+            return f"widths: {key} must be a list of {length} numbers"
+        return None
     if isinstance(value, bool) or not isinstance(value, (int, float)):
         return f"widths: ['{key}'] must be numbers"
     return None
@@ -219,8 +271,9 @@ def family_spec_error(parameters: dict) -> str | None:
     """What is wrong with an LLM element's ``family`` / ``widths`` pair
     and the parameters beside it, or None: an unknown family, widths
     without a family (or not a mapping of numbers -- a list of layer
-    names or a boolean where ``LIST_WIDTHS`` / ``BOOL_WIDTHS`` say so), a width the family
-    lacks, a parameter the family refuses -- and ``decode_block``,
+    names, a list of so many numbers or a boolean where ``LIST_WIDTHS``
+    / ``NUMBER_LIST_WIDTHS`` / ``BOOL_WIDTHS`` say so), a width the
+    family lacks, a parameter the family refuses -- and ``decode_block``,
     which no family serves any more (an unknown parameter is ignored,
     and this one would then decode by the per-token tick)."""
     if "decode_block" in parameters:

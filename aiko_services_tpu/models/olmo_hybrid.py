@@ -313,7 +313,7 @@ def init_cache(config, batch, max_seq=None):
 
 
 def cache_array(cache: dict):
-    """The key pool ``[L_full, P, page_tokens, K * hd]``."""
+    """The key pool ``[L_paged, P, page_tokens, K * hd]``."""
     return cache["k"]
 
 
@@ -352,7 +352,7 @@ def kernels_on(c: OlmoHybridConfig) -> bool:
 
 def paged_decode_pages(c: OlmoHybridConfig, cache: dict) -> int | None:
     """Pages a grid step of the paged decode kernel where it serves
-    the full-attention layers' decode, else None (``llama``'s twin)."""
+    the paged layers' decode, else None (``llama``'s twin)."""
     if not kernels_on(c):
         return None
     from ..ops.pallas_decode import _split_paged, paged_pages_per_step
@@ -683,25 +683,24 @@ def kv_bytes_per_token(c: OlmoHybridConfig) -> int:
         * jnp.dtype(c.kv_dtype).itemsize
 
 
-@partial(jax.jit, static_argnames=("config", "ring", "top_k"),
-         donate_argnames=("cache",))
-def _decode_loop_jit(params: dict, config: OlmoHybridConfig,
-                     tokens: jax.Array, cache: dict, lengths: jax.Array,
-                     active: jax.Array, budget: jax.Array,
-                     temperatures: jax.Array, eos: jax.Array,
-                     history: jax.Array, key: jax.Array, *, ring: int,
-                     top_k: int = 0):
-    """The device-resident serving loop, this family's plain body (see
-    ``llama._decode_loop_jit`` for the contract: carries, stop
-    detection, the emitted ring).  Over its steps the block also sums,
+def run_decode_loop(step_impl, row_bytes: float, token_bytes: float,
+                    params: dict, config, tokens: jax.Array, cache: dict,
+                    lengths: jax.Array, active: jax.Array,
+                    budget: jax.Array, temperatures: jax.Array,
+                    eos: jax.Array, history: jax.Array, key: jax.Array, *,
+                    ring: int, top_k: int = 0):
+    """The device-resident serving loop of a family with a recurrent
+    state beside its K/V pages, traced inside that family's jitted
+    ``_decode_loop_jit`` (see ``llama._decode_loop_jit`` for the
+    contract: carries, stop detection, the emitted ring).  ``step_impl``
+    is the family's decode step.  Over its steps the block also sums,
     from the loop's own lengths, the bytes of recurrent state its live
-    rows moved and the bytes of live K/V rows they read: ``stats`` rides
-    the block's one host fetch (:func:`loop_stats`)."""
+    rows moved (``row_bytes`` a row and step) and the bytes of live K/V
+    rows they read (``token_bytes`` a cached token): ``stats`` rides the
+    block's one host fetch (:func:`loop_stats`)."""
     b = tokens.shape[0]
     extent = cache_extent(cache)
     trash = extent - 1
-    row_bytes = float(state_bytes_per_row(config))
-    token_bytes = float(kv_bytes_per_token(config))
 
     def cond(carry):
         i, _, _, _, active, _, _, _, counts, _ = carry
@@ -712,8 +711,8 @@ def _decode_loop_jit(params: dict, config: OlmoHybridConfig,
         (i, tokens, cache, lengths, active, budget, key, emitted, counts,
          stats) = carry
         positions = jnp.where(active, jnp.minimum(lengths, trash), trash)
-        logits, cache = _decode_step_impl(params, config, tokens, cache,
-                                          positions, active)
+        logits, cache = step_impl(params, config, tokens, cache, positions,
+                                  active)
         key, sub = jax.random.split(key)
         sampled = select_tokens(sub, logits, temperatures,
                                 top_k=top_k).astype(jnp.int32)
@@ -743,6 +742,22 @@ def _decode_loop_jit(params: dict, config: OlmoHybridConfig,
     none = jnp.zeros((b,), dtype=jnp.int32)
     return (emitted[:, :ring], counts, tokens, lengths, active, budget,
             history, key, none, none, steps, cache, stats)
+
+
+@partial(jax.jit, static_argnames=("config", "ring", "top_k"),
+         donate_argnames=("cache",))
+def _decode_loop_jit(params: dict, config: OlmoHybridConfig,
+                     tokens: jax.Array, cache: dict, lengths: jax.Array,
+                     active: jax.Array, budget: jax.Array,
+                     temperatures: jax.Array, eos: jax.Array,
+                     history: jax.Array, key: jax.Array, *, ring: int,
+                     top_k: int = 0):
+    """This family's device loop (:func:`run_decode_loop`)."""
+    return run_decode_loop(
+        _decode_step_impl, float(state_bytes_per_row(config)),
+        float(kv_bytes_per_token(config)), params, config, tokens, cache,
+        lengths, active, budget, temperatures, eos, history, key,
+        ring=ring, top_k=top_k)
 
 
 def decode_loop(params: dict, config: OlmoHybridConfig, tokens: jax.Array,

@@ -14,10 +14,12 @@ KV in fixed-size **pages** instead:
   the same table and allocator; a config that says which of its layers
   own pages (``n_paged_layers``: models/olmo_hybrid.py, whose recurrent
   layers own none) gets pools that deep, and what it keeps PER SLOT
-  instead (``slot_state``: the recurrent state and the convolution's
+  beside them (``slot_state``: the recurrent state and the convolution's
   tail, ``[L_lin, slots, ...]``, addressed by the slot and by no page
-  table) rides the same cache dict -- one ``init_paged_cache``, one
-  allocator, one ``recover()``; a config whose layers of one kind
+  table; models/falcon_h1.py, whose every layer owns pages AND a
+  state, lays both over all its layers) rides the same cache dict --
+  one ``init_paged_cache``, one allocator, one ``recover()``; a config
+  whose layers of one kind
   attend a sliding WINDOW (``n_window_layers`` of ``window`` tokens:
   models/command_a.py) gets a second pair of pools for them,
   ``window_k`` / ``window_v`` ``[L_win, P_win, page_tokens, K*hd]``,
@@ -102,13 +104,14 @@ def init_paged_cache(config, batch: int, max_seq: int | None = None,
     payload a token, no heads, no k/v pair), ``{"latent": pool,
     "page_table"}`` with the pool ``[L, P, latent_width, page_tokens]``
     behind the same table and allocator.  A config with
-    ``n_paged_layers`` (layers of two kinds: only some own pages) gets
+    ``n_paged_layers`` (the layers that own pages: some or all) gets
     k/v pools that deep, and its ``slot_state`` (``name -> (leading
     shape, shape a slot, dtype)``) laid out ``[*leading, batch, ...]``
     under each name beside them, zero: what a slot holds that no page
-    addresses.  A latent page is stored
-    TOKENS-MINOR: a width that is no multiple of the 128 lanes (576)
-    makes that the v5e's own default layout of the array, and a pool
+    addresses (the two are independent: a layer may own both).  A
+    latent page is stored TOKENS-MINOR: a width that is no multiple of
+    the 128 lanes (576) makes that the v5e's own default layout of the
+    array, and a pool
     declared rows-minor was transposed -- three passes over 2.4 GB --
     at the entry of every program that took it (PERF.md, PR 29).
 

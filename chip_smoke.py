@@ -674,6 +674,42 @@ def kernels(settings: dict, rehearse: bool, facts: dict) -> None:
                   *step_inputs),
           atol=1e-5, rtol=1e-5)
 
+    # 10-11. the Mamba-2 SSD (ops/pallas_ssd.py) at Falcon-H1-34B's
+    # widths -- 32 heads of 128, a state of 256, 2 groups, sub-chunks of
+    # 128 -- against the jax.numpy form each falls back to: one 512-token
+    # admission chunk from a state that is not zero, and one decode step
+    # over the stored pool with rows that sit out.  float32 both sides.
+    from aiko_services_tpu.ops.pallas_ssd import (ssd_chunk_scan,
+                                                  ssd_decode_step)
+    s_heads, s_dim, s_state, s_groups, s_sub = (4, 8, 16, 2, 8) \
+        if rehearse else (32, 128, 256, 2, 128)
+
+    def ssd_inputs(tokens):
+        return (normal((tokens, s_heads, s_dim), f32),
+                jnp.exp(normal((tokens, s_heads), f32, 1.5) - 4.0),
+                -jnp.exp(normal((s_heads,), f32)),
+                normal((tokens, s_groups, s_state), f32),
+                normal((tokens, s_groups, s_state), f32),
+                jnp.ones((s_heads,), f32))
+
+    chunk_inputs = ssd_inputs(g_chunk)
+    state = normal((s_heads, s_dim, s_state), f32)
+    close("ssd_chunk_scan",
+          lambda: ssd_chunk_scan(*chunk_inputs, state, chunk=s_sub,
+                                 kernel=True, interpret=interpret),
+          lambda: jax.jit(lambda *inputs: ssd_chunk_scan(
+              *inputs, chunk=s_sub))(*chunk_inputs, state),
+          atol=1e-3, rtol=1e-4)
+    pool = normal((3, slots, s_heads, s_dim, s_state), f32)
+    step_inputs = ssd_inputs(slots)
+    close("ssd_decode_step",
+          lambda: ssd_decode_step(*step_inputs, pool, jnp.int32(1),
+                                  decoding, kernel=True,
+                                  interpret=interpret),
+          lambda: jax.jit(lambda *inputs: ssd_decode_step(
+              *inputs, pool, jnp.int32(1), decoding))(*step_inputs),
+          atol=1e-5, rtol=1e-5)
+
 
 def _verify_reference(k_rows, v_rows, q, k_new, v_new, starts, positions,
                       block_mask=False):
