@@ -47,6 +47,7 @@ from ..models.families import FAMILY_PARAMETERS, family_spec_error
 from ..models.paged import is_paged, pool_page_tokens
 from ..models.quant import is_quantized
 from ..models.tokenizer import ByteTokenizer, load_tokenizer
+from ..ops.pallas_gdn import SUB_CHUNK
 from ..pipeline import PipelineElement, StreamEvent
 from ..services import Actor
 from ..utils import generate, get_logger, parse_bool, parse_number
@@ -259,7 +260,10 @@ class LLM(PipelineElement):
     of the state), its retired decode blocks observe
     ``llm_state_traffic_share``, and the recorder gets one
     ``build:llm_cache`` event whose info is the bytes of each cache
-    pool.  ``sdar_moe`` (models/sdar.py) generates by DIFFUSION OVER
+    pool, and one ``build:llm_chunk_scan``: where admission's chunk
+    scan runs (``path``: ``fused-kernel`` or ``xla``), its
+    ``sub_chunk``, ``heads``, ``d_k`` and ``d_v``.  ``sdar_moe``
+    (models/sdar.py) generates by DIFFUSION OVER
     BLOCKS over drop-less softmax-routed experts: ``block_length``
     (default 4) positions a block under a block-causal mask,
     ``denoising_steps`` (1..``block_length``, default
@@ -647,6 +651,18 @@ class LLM(PipelineElement):
                      if is_paged(cache) else None,
                      "pages_per_step": llama.paged_decode_pages(
                          served, cache)})
+            if isinstance(config, olmo_hybrid.OlmoHybridConfig):
+                # Where admission's chunk scan runs: the Pallas kernel
+                # that builds the WY form in VMEM, or XLA's einsums.
+                served = self._batcher.config
+                recorder.record(
+                    "build", None, None, "llm_chunk_scan", 0.0,
+                    {"path": "fused-kernel"
+                     if olmo_hybrid.kernels_on(served) else "xla",
+                     "sub_chunk": SUB_CHUNK,
+                     "heads": served.linear_heads,
+                     "d_k": served.linear_key_dim,
+                     "d_v": served.linear_value_dim})
             unembed = params.get("unembed")
             if is_quantized(unembed) and unembed["int8"].ndim == 2:
                 # How the fused int8 unembed (ops/pallas_matmul.py)

@@ -30,11 +30,14 @@ so nothing overflows however fast a head forgets.  ``(I + A)^-1`` of
 the unit lower-triangular 64 x 64 is built exactly, by blocks: the
 8 x 8 diagonal blocks by ``(I - D)(I + D^2)(I + D^4)`` (``D^8 = 0``),
 then three merges ``T <- T - T L T`` (``L`` the part of ``A`` between
-the two halves of each doubled block).  All of that is batched over
-heads and sub-chunks and runs as XLA einsums in float32 at the highest
-precision; what is sequential -- the state handed from sub-chunk to
-sub-chunk -- is the kernel (grid heads x sub-chunks, the state in VMEM
-scratch), or a ``lax.scan`` in the ``jax.numpy`` form.
+the two halves of each doubled block).  Every product is float32 at
+the highest precision.  The kernel (grid: groups of heads x
+sub-chunks, the states in VMEM scratch) builds a sub-chunk's WY form
+in VMEM from ``q``, ``k``, ``v`` and the running sum of ``g`` --
+nothing of it goes through HBM -- two heads' inverses side by side (a
+pass of the MXU's 128-deep array contracts both), then applies the
+states; the ``jax.numpy`` form builds the same form for every
+sub-chunk at once in XLA and hands the state on in a ``lax.scan``.
 
 **The decode step** (:func:`gated_delta_decode_step`) reads every live
 row's state once and writes it once, in place
@@ -72,6 +75,10 @@ KERNEL_EQUIVALENCE_TESTS = {
 }
 
 SUB_CHUNK = 64
+# Heads a grid step of the chunk scan takes at most, in pairs: the
+# products batched over them keep the MXU fed (on a v5e the kernel
+# took 1.6 times as long at one pair a step as at three).
+_STEP_HEADS = 6
 _HIGHEST = jax.lax.Precision.HIGHEST
 _DECODE_VMEM_LIMIT = 48 << 20
 
@@ -133,86 +140,167 @@ def gated_delta_recurrence(q, k, v, g, beta, state):
 
 # -- admission: the chunk scan ------------------------------------------------
 
+def _dot(x, y, x_axis: int, y_axis: int):
+    """``x`` times ``y`` summed over ``x``'s axis ``x_axis`` and ``y``'s
+    ``y_axis`` (each -1 or -2), batched over the axes before the last
+    two: float32 at the highest precision."""
+    batch = tuple(range(x.ndim - 2))
+    return jax.lax.dot_general(
+        x, y, (((x.ndim + x_axis,), (y.ndim + y_axis,)), (batch, batch)),
+        preferred_element_type=jnp.float32, precision=_HIGHEST)
+
+
+def _pairs(x):
+    """``[G, ...]`` -> (heads ``0, 2, ..``, heads ``1, 3, ..``), each
+    ``[G / 2, ...]``."""
+    paired = x.reshape(x.shape[0] // 2, 2, *x.shape[1:])
+    return paired[:, 0], paired[:, 1]
+
+
+def _side_by_side(x):
+    """``[G, ..., r, w]`` -> ``[G / 2, ..., r, 2w]``: each pair of heads
+    along the lanes."""
+    return jnp.concatenate(_pairs(x), axis=-1)
+
+
+def _lanes(x, width: int):
+    """``[G, ..., r, 1]`` -> ``[G / 2, ..., r, 2 width]``: each pair's
+    two columns, each over its own half."""
+    first, second = _pairs(x)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 2 * width), 1)
+    return jnp.where(lane < width, first, second)
+
+
+def _block_diagonal(y):
+    """``[..., r, 2w]``, two heads side by side -> ``[..., 2r, 2w]``
+    with the first head's half in the top rows and the second's below:
+    a right operand that contracts each head of a pair with its own."""
+    left = jax.lax.broadcasted_iota(jnp.int32, y.shape[-2:], 1) \
+        < y.shape[-1] // 2
+    return jnp.concatenate([jnp.where(left, y, 0.0),
+                            jnp.where(left, 0.0, y)], axis=-2)
+
+
 def _unit_lower_inverse(a):
     """``(I + A)^-1`` for ``A [..., c, c]`` strictly lower triangular,
     ``c`` a multiple of 8: exact block algebra, no series beyond the
-    8 x 8 diagonal blocks (module docstring)."""
-    c = a.shape[-1]
-    row = jnp.arange(c)[:, None]
-    col = jnp.arange(c)[None, :]
-    eye = jnp.eye(c, dtype=a.dtype)
+    8 x 8 diagonal blocks (module docstring).  ``A [..., c, 2c]`` is a
+    pair of heads side by side, and so is what comes back: a product
+    then contracts both in one pass of the MXU's 128-deep array, where
+    one head fills half of it."""
+    c, width = a.shape[-2:]
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, width), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (c, width), 1)
+    col = jnp.where(lane >= c, lane - c, lane)      # within each head
+    eye = (row == col).astype(a.dtype)
 
     def mm(x, y):
-        return jnp.einsum("...ij,...jk->...ik", x, y, precision=_HIGHEST)
+        return _dot(x, y if width == c else _block_diagonal(y), -1, -2)
 
-    diagonal = jnp.where(row // 8 == col // 8, a, 0.0)
+    diagonal = jnp.where(row >> 3 == col >> 3, a, 0.0)
     square = mm(diagonal, diagonal)
     inverse = mm(mm(eye - diagonal, eye + square),
                  eye + mm(square, square))
-    size = 8
-    while size < c:
-        between = jnp.where((row // (2 * size) == col // (2 * size))
-                            & (row // size != col // size), a, 0.0)
+    shift = 3
+    while 1 << shift < c:
+        between = jnp.where((row >> shift + 1 == col >> shift + 1)
+                            & (row >> shift != col >> shift), a, 0.0)
         inverse = inverse - mm(inverse, mm(between, inverse))
-        size *= 2
+        shift += 1
     return inverse
+
+
+def _wy_parts(q, k, gamma_row, beta_row):
+    """What of sub-chunks' WY form needs neither the state nor ``V``,
+    batched over the axes before the last two: ``q, k [..., c, d_k]``,
+    ``gamma_row`` (the running sum of ``g``) and ``beta_row [..., 1,
+    c]`` laid along a row -> (``A [..., c, c]``, ``P = (Q K^T)
+    Gamma_i / Gamma_j (j <= i)``; ``Gamma Q``, ``K Gamma_c / Gamma``,
+    ``beta Gamma K [..., c, d_k]``; ``beta [..., c, 1]``; ``Gamma_c
+    [..., 1, 1]``), float32.  The kernel body and the ``jax.numpy``
+    form both call it."""
+    c = q.shape[-2]
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+
+    def column(x):                      # [.., 1, c] -> [.., c, 1], exact
+        return jnp.sum(jnp.where(row == col, x, 0.0), axis=-1,
+                       keepdims=True)
+
+    gamma, beta = column(gamma_row), column(beta_row)
+    # exp of a difference that is <= 0 wherever it is kept
+    ratio = jnp.exp(jnp.where(row >= col, gamma - gamma_row, -jnp.inf))
+    a = jnp.where(row > col, beta * ratio * _dot(k, k, -1, -1), 0.0)
+    decay = jnp.exp(gamma)
+    last = gamma_row[..., c - 1:]
+    return (a, ratio * _dot(q, k, -1, -1), q * decay,
+            k * jnp.exp(last - gamma), beta * decay * k, beta,
+            jnp.exp(last))
+
+
+def _sub_chunks(x, c: int):
+    """``[T, H, ...]`` -> ``[H, T / c, c, d]``: each head's tokens in
+    sub-chunks of ``c``."""
+    t, h = x.shape[:2]
+    return jnp.moveaxis(x, 1, 0).reshape(h, t // c, c, -1)
+
+
+def _rows(x, c: int):
+    """``[T, H]`` -> ``[H, T / c, 1, c]`` float32: each head's
+    sub-chunks laid along rows."""
+    t, h = x.shape
+    return jnp.moveaxis(x.astype(jnp.float32), 1, 0).reshape(
+        h, t // c, 1, c)
 
 
 def _chunk_prepare(q, k, v, g, beta, c: int):
     """Everything of the WY form that does not need the state, batched
     over heads and sub-chunks: (w ``[H, N, c, d_k]``, u0 ``[H, N, c,
     d_v]``, qg, p ``[H, N, c, c]``, kg, g_end ``[H, N]``), float32."""
-    t, h = g.shape
-    n = t // c
-    f32 = jnp.float32
-
-    def split(x):                       # [T, H, d] -> [H, N, c, d]
-        return jnp.moveaxis(x.astype(f32), 1, 0).reshape(h, n, c, -1)
-
-    q, k, v = split(q), split(k), split(v)
-    g = jnp.moveaxis(g.astype(f32), 1, 0).reshape(h, n, c)
-    beta = jnp.moveaxis(beta.astype(f32), 1, 0).reshape(h, n, c)
-    gamma = jnp.cumsum(g, axis=-1)
-    row = jnp.arange(c)[:, None]
-    col = jnp.arange(c)[None, :]
-    # exp of a difference that is <= 0 wherever it is kept
-    ratio = jnp.exp(jnp.where(
-        row >= col, gamma[..., :, None] - gamma[..., None, :], -jnp.inf))
-    kk = jnp.einsum("hnid,hnjd->hnij", k, k, precision=_HIGHEST)
-    a = jnp.where(row > col, beta[..., :, None] * ratio * kk, 0.0)
+    q, k, v = (_sub_chunks(x.astype(jnp.float32), c) for x in (q, k, v))
+    a, p, qg, kg, bk, beta, end = _wy_parts(
+        q, k, jnp.cumsum(_rows(g, c), axis=-1), _rows(beta, c))
     inverse = _unit_lower_inverse(a)
-    decay = jnp.exp(gamma)
-    w = jnp.einsum("hnij,hnjd->hnid", inverse,
-                   (beta * decay)[..., None] * k, precision=_HIGHEST)
-    u0 = jnp.einsum("hnij,hnjd->hnid", inverse, beta[..., None] * v,
-                    precision=_HIGHEST)
-    p = ratio * jnp.einsum("hnid,hnjd->hnij", q, k, precision=_HIGHEST)
-    qg = q * decay[..., None]
-    kg = k * jnp.exp(gamma[..., -1:] - gamma)[..., None]
-    return w, u0, qg, p, kg, decay[..., -1]
+    return (_dot(inverse, bk, -1, -2), _dot(inverse, beta * v, -1, -2),
+            qg, p, kg, end[..., 0, 0])
 
 
-def _chunk_kernel(w_ref, u0_ref, qg_ref, p_ref, kg_ref, end_ref, s_ref,
-                  o_ref, s_out_ref, state):
-    """One (head, sub-chunk) of the scan; the state rides the
-    sub-chunk axis in VMEM scratch."""
+def _chunk_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref, s_ref, o_ref,
+                  s_out_ref, state):
+    """One sub-chunk of a few pairs of heads: their WY form built in
+    VMEM, then their states applied; the states ride the sub-chunk axis
+    in VMEM scratch.  ``v``, ``o`` and the states hold each pair side
+    by side along the lanes (:func:`pack_state`'s layout), so the
+    products that contract over the sub-chunk take a pair a pass."""
     @pl.when(pl.program_id(1) == 0)
     def _():
-        state[...] = s_ref[0]
+        state[...] = s_ref[...]
 
-    def mm(x, y):
-        return jnp.dot(x, y, preferred_element_type=jnp.float32,
-                       precision=_HIGHEST)
-
-    s = state[...]
-    u = u0_ref[0, 0] - mm(w_ref[0, 0], s)
-    o_ref[0, 0] = mm(qg_ref[0, 0], s) + mm(p_ref[0, 0], u)
-    kg = kg_ref[0, 0]
-    s = s * end_ref[0, 0][:1] + jax.lax.dot_general(
-        kg, u, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32, precision=_HIGHEST)
-    state[...] = s
-    s_out_ref[0] = s
+    f32 = jnp.float32
+    heads = q_ref.shape[0]
+    dv = v_ref.shape[-1] // heads
+    a, p, qg, kg, bk, beta, end = _wy_parts(
+        q_ref[:, 0].astype(f32), k_ref[:, 0].astype(f32),
+        gamma_ref[:, 0], beta_ref[:, 0])
+    inverse = _unit_lower_inverse(_side_by_side(a))      # [G/2, c, 2c]
+    pairs = state[...]                                   # [G/2, dk, 2dv]
+    # each head's state apart, and each pair's values side by side
+    s = jnp.stack([pairs[..., :dv], pairs[..., dv:]], axis=1).reshape(
+        heads, pairs.shape[1], dv)
+    v = jnp.stack([v_ref[:, pair * 2 * dv:(pair + 1) * 2 * dv]
+                   for pair in range(heads // 2)]).astype(f32)
+    # U = (I + A)^-1 beta (V - Gamma K S), block-diagonal for the
+    # products that take it
+    u = _block_diagonal(_dot(inverse, _block_diagonal(
+        _lanes(beta, dv) * v - _side_by_side(_dot(bk, s, -1, -2))), -1, -2))
+    out = _side_by_side(_dot(qg, s, -1, -2)) \
+        + _dot(_side_by_side(p), u, -1, -2)
+    for pair in range(heads // 2):
+        o_ref[:, pair * 2 * dv:(pair + 1) * 2 * dv] = out[pair]
+    pairs = pairs * _lanes(end, dv) + _dot(
+        jnp.concatenate(_pairs(kg), axis=-2), u, -2, -2)
+    state[...] = pairs
+    s_out_ref[...] = pairs
 
 
 def _chunk_sequential_scan(w, u0, qg, p, kg, g_end, state):
@@ -247,41 +335,48 @@ def gated_delta_chunk_scan(q, k, v, g, beta, state, *,
         q, k, v, g, beta = (jnp.pad(x, ((0, pad),) + ((0, 0),)
                                     * (x.ndim - 1))
                             for x in (q, k, v, g, beta))
-    prepared = _chunk_prepare(q, k, v, g, beta, c)
     state = state.astype(jnp.float32)
     if not kernel:
-        out, state = _chunk_sequential_scan(*prepared, state)
-    else:
-        w, u0, qg, p, kg, g_end = prepared
-        n, dk, dv = w.shape[1], w.shape[3], u0.shape[3]
-        # the sub-chunk's last decay as rows of the state's width (the
-        # kernel broadcasts one along the sublanes)
-        end = jnp.broadcast_to(g_end[..., None, None], (h, n, 8, dv))
+        out, state = _chunk_sequential_scan(
+            *_chunk_prepare(q, k, v, g, beta, c), state)
+        return jnp.moveaxis(out.reshape(h, t + pad, -1), 0, 1)[:t], state
+    # an odd head count gets a head of zeros: the kernel takes pairs
+    odd = h % 2
+    if odd:
+        q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, 1))
+                                    + ((0, 0),) * (x.ndim - 2))
+                            for x in (q, k, v, g, beta))
+        state = jnp.pad(state, ((0, 1), (0, 0), (0, 0)))
+    length, heads = t + pad, h + odd
+    dk, dv = q.shape[2], v.shape[2]
+    step = max(size for size in range(2, _STEP_HEADS + 1, 2)
+               if heads % size == 0)
+    f32 = jnp.float32
 
-        def per_chunk(width):
-            return pl.BlockSpec((1, 1, c, width),
-                                lambda i, j: (i, j, 0, 0))
+    def per_chunk(rows, width):
+        return pl.BlockSpec((step, 1, rows, width),
+                            lambda i, j: (i, j, 0, 0))
 
-        per_head = pl.BlockSpec((1, dk, dv), lambda i, j: (i, 0, 0))
-        out, state = pl.pallas_call(
-            _chunk_kernel,
-            grid=(h, n),
-            in_specs=[per_chunk(dk), per_chunk(dv), per_chunk(dk),
-                      per_chunk(c), per_chunk(dk),
-                      pl.BlockSpec((1, 1, 8, dv),
-                                   lambda i, j: (i, j, 0, 0)),
-                      per_head],
-            out_specs=[per_chunk(dv), per_head],
-            out_shape=[jax.ShapeDtypeStruct((h, n, c, dv), jnp.float32),
-                       jax.ShapeDtypeStruct((h, dk, dv), jnp.float32)],
-            scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
-            interpret=interpret_off_chip(interpret),
-            name="gated_delta_chunk_scan",
-        )(w, u0, qg, p, kg, end, state)
-    out = jnp.moveaxis(out.reshape(h, t + pad, -1), 0, 1)
-    return out[:t], state
+    lanes = pl.BlockSpec((c, step * dv), lambda i, j: (j, i))
+    per_pair = pl.BlockSpec((step // 2, dk, 2 * dv), lambda i, j: (i, 0, 0))
+    out, state = pl.pallas_call(
+        _chunk_kernel,
+        grid=(heads // step, length // c),
+        in_specs=[per_chunk(c, dk), per_chunk(c, dk), lanes,
+                  per_chunk(1, c), per_chunk(1, c), per_pair],
+        out_specs=[lanes, per_pair],
+        out_shape=[jax.ShapeDtypeStruct((length, heads * dv), f32),
+                   jax.ShapeDtypeStruct((heads // 2, dk, 2 * dv), f32)],
+        scratch_shapes=[pltpu.VMEM((step // 2, dk, 2 * dv), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret_off_chip(interpret),
+        name="gated_delta_chunk_scan",
+    )(_sub_chunks(q, c), _sub_chunks(k, c), v.reshape(length, heads * dv),
+      jnp.cumsum(_rows(g, c), axis=-1), _rows(beta, c),
+      pack_state(state, 2))
+    return (out.reshape(length, heads, dv)[:t, :h],
+            unpack_state(state, 2)[:h])
 
 
 # -- decode: one token a row --------------------------------------------------

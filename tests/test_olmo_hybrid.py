@@ -7,6 +7,7 @@ through the same ContinuousBatcher and LLM element as the other two
 families (ISSUE 33)."""
 
 import dataclasses
+import functools
 import queue
 
 import jax
@@ -106,13 +107,25 @@ def _recurrence_inputs(tokens, heads=6, dk=24, dv=64, seed=0):
     return q, k, v, g, beta, state
 
 
+# Olmo-Hybrid-7B's per-head widths, at two heads
+SERVED_WIDTHS = {"heads": 2, "dk": 96, "dv": 192}
+
+
 @pytest.mark.parametrize("kernel", [False, True])
-@pytest.mark.parametrize("tokens,sub_chunk", [
-    (128, 64), (100, 64), (64, 16), (37, 8), (130, 32), (5, 64)])
-def test_chunk_scan_matches_recurrence(tokens, sub_chunk, kernel):
+@pytest.mark.parametrize("tokens,sub_chunk,widths", [
+    pytest.param(tokens, sub_chunk, {}, id=f"{tokens}-{sub_chunk}")
+    for tokens, sub_chunk in ((128, 64), (100, 64), (64, 16), (37, 8),
+                              (130, 32), (5, 64))] + [
+    pytest.param(200, 64, SERVED_WIDTHS, id="200-64-served"),
+    pytest.param(128, 64, SERVED_WIDTHS, id="128-64-served"),
+    pytest.param(70, 32, {"heads": 5}, id="70-32-odd-heads")])
+def test_chunk_scan_matches_recurrence(tokens, sub_chunk, widths, kernel):
     """Chunk sizes and lengths that do not divide, from a state that is
-    not zero: float32 against float32, 2e-5."""
-    inputs = _recurrence_inputs(tokens)
+    not zero, at toy widths and at the served ``d_k`` 96 / ``d_v`` 192
+    (the sub-chunk of 64 the model runs, a pad tail); pairs of heads
+    invert side by side, and an odd count one by one: float32 against
+    float32, 2e-5."""
+    inputs = _recurrence_inputs(tokens, **widths)
     want_out, want_state = pallas_gdn.gated_delta_recurrence(*inputs)
     out, state = pallas_gdn.gated_delta_chunk_scan(
         *inputs, kernel=kernel, sub_chunk=sub_chunk)
@@ -131,6 +144,42 @@ def test_chunk_scan_masks_a_pad_tail():
     _, want = pallas_gdn.gated_delta_recurrence(
         q[:41], k[:41], v[:41], g[:41], beta[:41], state)
     np.testing.assert_allclose(masked, want, atol=2e-5)
+
+
+def _primitives(jaxpr, inside=None, found=None):
+    """``[(primitive, the pallas_call it sits in or None)]`` of a
+    jaxpr and every jaxpr nested in it; a kernel's body is named by
+    the ``pallas_call``'s ``name``."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        found.append((name, inside))
+        within = eqn.params["name"] if name == "pallas_call" else inside
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) \
+                    else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _primitives(sub, within, found)
+    return found
+
+
+def test_chunk_scan_kernel_path_has_no_product_outside_the_kernel():
+    """The kernel path builds the WY form inside the kernel: every
+    ``dot_general`` of the scan sits in the ``pallas_call`` named
+    ``gated_delta_chunk_scan``, and none runs in XLA around it (the
+    ``jax.numpy`` form, by contrast, is all XLA products)."""
+    inputs = _recurrence_inputs(200, **SERVED_WIDTHS)
+
+    def products(kernel):
+        jaxpr = jax.make_jaxpr(functools.partial(
+            pallas_gdn.gated_delta_chunk_scan, kernel=kernel))(*inputs)
+        return [inside for name, inside in _primitives(jaxpr.jaxpr)
+                if name == "dot_general"]
+
+    fused = products(True)
+    assert fused and set(fused) == {"gated_delta_chunk_scan"}
+    assert set(products(False)) == {None}
 
 
 @pytest.mark.parametrize("active", [
@@ -526,6 +575,31 @@ def test_llm_element_serves_the_hybrid_family(runtime):
                and event[6] and event[6]["chunks"]]
     assert carried and set(carried) == {0}      # one-chunk prompts
     pipeline.stop()
+
+
+@pytest.mark.parametrize("decode_kernel,path", [
+    ("paged-kernel", "fused-kernel"), ("reference", "xla")])
+def test_llm_element_stamps_the_chunk_scan_path(runtime, decode_kernel,
+                                                path):
+    """A model build says once where admission's chunk scan runs
+    (``build:llm_chunk_scan``, beside ``build:llm_cache``): the kernel
+    that builds the WY form in VMEM under ``kernels: on``, XLA's
+    einsums under ``off``."""
+    from aiko_services_tpu.pipeline import Pipeline
+    responses = queue.Queue()
+    pipeline = Pipeline(_definition("hybrid_scan", {
+        "family": "olmo_hybrid", "widths": TINY_WIDTHS,
+        "decode_kernel": decode_kernel, "max_new_tokens": 2}),
+        runtime=runtime)
+    stream = pipeline.create_stream_local("1", queue_response=responses)
+    pipeline.create_frame_local(stream, {"text": "hi"})
+    assert run_until(runtime, lambda: responses.qsize() >= 1,
+                     timeout=180.0)
+    stamps = [event[6] for event in pipeline.recorder.snapshot()
+              if event[1] == "build" and event[4] == "llm_chunk_scan"]
+    pipeline.stop()
+    assert stamps == [{"path": path, "sub_chunk": 64, "heads": 6,
+                       "d_k": 24, "d_v": 64}]
 
 
 def test_llm_element_refuses_at_create_time():
